@@ -29,7 +29,7 @@ print(f"emulated ⊞-MAC matmul median rel err vs float: {rel:.3f}")
 Zk = decode(lns_matmul_kernel(encode(A, fmt), encode(B, fmt), fmt=fmt,
                               spec=DELTA_DEFAULT, block_m=8, block_n=8,
                               block_k=16), fmt)
-print(f"Pallas kernel (interpret mode) matches emulation structurally; "
+print(f"Pallas kernel matches emulation structurally; "
       f"median rel err: {np.median(np.abs(Zk - A @ B) / np.abs(A @ B)):.3f}")
 
 print("\n=== 3. One spec, every numerics axis (NumericsSpec → LNSRuntime) ===")

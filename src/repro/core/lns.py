@@ -162,6 +162,18 @@ def quantization_bound(fmt: LNSFormat) -> float:
 #: ``"pallas"`` the blocked TPU kernels — bit-exact to each other.
 MATMUL_BACKENDS = ("emulate", "pallas")
 
+
+def resolve_interpret(interpret: "bool | None") -> bool:
+    """Whether a Pallas kernel launch runs in interpret mode.
+
+    The one place the choice is made: an explicit flag wins, and ``None``
+    means compiled on a TPU and interpreted on any other platform — so no
+    launch falls back to the interpreter on the chip unless asked to.
+    """
+    if interpret is not None:
+        return bool(interpret)
+    return jax.default_backend() != "tpu"
+
 # Engine cache keyed by the full (DeltaSpec, LNSFormat) pair — both are
 # frozen/hashable dataclasses.  The key must include the *format*: the same
 # Δ spec yields different integer tables under lns16 (qf=10) and lns12
@@ -231,9 +243,7 @@ class LNSMatmulBackend:
                 f"core.spec.resolve_blocks_arg before construction)")
 
     def _interp(self) -> bool:
-        if self.interpret is not None:
-            return self.interpret
-        return jax.default_backend() != "tpu"
+        return resolve_interpret(self.interpret)
 
     def _op_blocks(self, op: str, r: int, c: int, ct: int):
         """Effective (block_r, block_c, block_ct) for one kernel launch.

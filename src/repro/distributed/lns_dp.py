@@ -36,7 +36,6 @@ import warnings
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.plan import NumericsPlan
@@ -211,7 +210,9 @@ class LNSDataParallelMLP:
                                          param_fmts=inner.param_fmts,
                                          param_layer=PARAM_LAYER)
 
-        def local_fn(params, xb_l, yb_l):
+        collect = _obs.enabled()
+
+        def local_fn(params, momentum, xb_l, yb_l):
             grads, loss = inner.per_segment_grads(params, xb_l, yb_l,
                                                   segs_local)
             if fplan is not None:
@@ -238,30 +239,42 @@ class LNSDataParallelMLP:
                 else:
                     red[k] = float_psum_allreduce(g, axis_name=axis,
                                                   eng=eng)
-            return red, jax.lax.pmean(loss, axis)
+            # The update runs after the combine, on the replicated
+            # gradients, identically on every device: a Pallas kernel
+            # outside shard_map cannot be partitioned over the mesh.  Its
+            # taps leave the body as replicated outputs.
+            with phase_scope("update"):
+                if collect:
+                    with _obs.collecting() as col:
+                        new_p, new_m = inner.apply_updates(params, red,
+                                                           momentum)
+                    taps = col.taps()
+                else:
+                    new_p, new_m = inner.apply_updates(params, red,
+                                                       momentum)
+                    taps = {}
+            return new_p, new_m, red, jax.lax.pmean(loss, axis), taps
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             local_fn, mesh=self.mesh,
-            in_specs=(P(), P(axis), P(axis)),
-            out_specs=(P(), P()),
-            check_rep=False)
+            in_specs=(P(), P(), P(axis), P(axis)),
+            out_specs=(P(), P(), P(), P(), P()),
+            check_vma=False)
         # Taps must not fire inside the shard_map body (the per-device
         # trace's values would leak onto the Python-side collector), so
         # collection is suspended across the mapped call; the combined
         # gradients are observed below on the replicated values — the DP
         # canonical-reduce schedule itself is untouched.
         with phase_scope("reduce"), _obs.suspended(), _inj.suspended():
-            grads, loss = mapped(params, xb, yb)
-        if _obs.enabled():
-            from ..paper.mlp import PARAM_LAYER
+            new_params, momentum, grads, loss, taps = mapped(
+                params, momentum, xb, yb)
+        if collect:
             for k, g in grads.items():
                 layer = PARAM_LAYER[k]
                 if inner.metrics_levels[layer] != "off":
                     _obs.observe_codes(g, inner.param_fmts[k], layer=layer,
                                        op=f"dp_grad.{k}")
-        with phase_scope("update"):
-            new_params, momentum = inner.apply_updates(params, grads,
-                                                       momentum)
+            _obs.add_taps(taps)
         if momentum is None:
             return new_params, loss
         return new_params, momentum, loss
@@ -383,7 +396,7 @@ def run_device_count_invariance_check(device_counts=(1, 2, 4), *,
     # plan re-derives the canonical segmentation from ``plan``.
     cfg = MLPConfig(n_in=n_in, n_hidden=n_hidden, n_out=n_out,
                     spec=plan.with_(**{"reduce.grad_segments": 0}),
-                    momentum=momentum, fused=fused, matmul_block=8)
+                    momentum=momentum, fused=fused)
 
     inner = LNSMLP(cfg)
     ref_params = inner.init(jax.random.PRNGKey(seed))

@@ -59,7 +59,8 @@ def gather_partials(p: LNSArray, axis_name: str) -> LNSArray:
 
 
 def dp_combine_blocks(n_elements: int, segments: int, eng: DeltaEngine, *,
-                      blocks: str = "default", interpret: bool = True):
+                      blocks: str = "default",
+                      interpret: bool | None = None):
     """The (block_m, block_k) tiles :func:`combine_partials` launches.
 
     Resolves the DP combine's fold shape exactly like the kernel path
@@ -85,7 +86,7 @@ def dp_combine_blocks(n_elements: int, segments: int, eng: DeltaEngine, *,
 def combine_partials(parts: LNSArray, eng: DeltaEngine, *,
                      schedule: str = "sequential",
                      use_kernel: bool = False,
-                     interpret: bool = True,
+                     interpret: bool | None = None,
                      blocks: str = "default") -> LNSArray:
     """⊞-combine (S, ...) stacked partials along axis 0, fixed schedule.
 
@@ -120,7 +121,7 @@ def deterministic_boxplus_allreduce(p: LNSArray, axis_name: str,
                                     eng: DeltaEngine, *,
                                     schedule: str = "sequential",
                                     use_kernel: bool = False,
-                                    interpret: bool = True,
+                                    interpret: bool | None = None,
                                     blocks: str = "default") -> LNSArray:
     """The ⊞-allreduce: gather partials, combine with the fixed schedule.
 

@@ -3,11 +3,12 @@
 ``lns_matmul`` — blocked multiplication-free matmul (+ fused flush-time
 epilogues: bias ⊞ / llrelu / requantize in the forward kernel, the ⊞-SGD
 update in the dW kernel, and the standalone fused-update kernel);
-``lns_boxsum`` — the soft-max Σ⊞ reduction (eq. 14), fine LUT in VMEM
-(max + Δ-LUT / bit-shift accumulation on the VPU, Δ tables in VMEM);
+``lns_boxsum`` — the soft-max Σ⊞ reduction (eq. 14) and the DP combine
+(max + Δ-LUT / bit-shift accumulation on the VPU);
 ``autotune``   — the per-(spec, op, shape) block-size autotuner behind
-the ``blocks=auto`` spec axis.  Validated bit-exactly against ``ref.py``
-in interpret mode; ``interpret=False`` targets real TPUs.
+the ``blocks=auto`` spec axis.  Validated bit-exactly against ``ref.py``,
+interpreted on the CPU and compiled on a TPU (``core.lns.resolve_interpret``
+picks from the platform).
 """
 from . import autotune
 from .lns_boxsum import lns_boxsum_kernel, lns_boxsum_ref

@@ -55,10 +55,13 @@ import time
 import warnings
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..core.delta import DeltaSpec
 from ..core.formats import LNSFormat
+from ..core.lns import resolve_interpret
+from .lns_matmul.lns_matmul import tile, tiling
 
 OPS = ("fwd", "dx", "dw", "dw_partials", "boxsum")
 
@@ -98,16 +101,22 @@ def vmem_bytes(op: str, blocks) -> int:
     return 4 * (2 * br * bct + 2 * bct * bc + out_planes * br * bc)
 
 
-def _axis_candidates(dim: int):
-    cands = {v for v in _AXIS_CANDIDATES if v < dim}
-    cands.add(dim)
-    return sorted(cands)
+def _axis_candidates(dim: int, align: int):
+    """Block edges for one axis that its tiling rule admits, as fitted
+    tiles (:func:`~repro.kernels.lns_matmul.lns_matmul.tile`)."""
+    return sorted({tile(v, dim, align)
+                   for v in _AXIS_CANDIDATES + (dim,)
+                   if v >= dim or v % align == 0})
 
 
-def candidate_blocks(op: str, shape, *, vmem_budget: int =
-                     DEFAULT_VMEM_BUDGET, max_candidates: int = 8):
+def candidate_blocks(op: str, shape, *, interpret: "bool | None" = None,
+                     vmem_budget: int = DEFAULT_VMEM_BUDGET,
+                     max_candidates: int = 8):
     """VMEM-budget-pruned, ranked ``(block_r, block_c, block_ct)`` grid.
 
+    Compiled launches (``interpret`` resolving False) only get tiles the
+    chip's (8, 128) rule admits: output rows and columns in multiples of
+    128, the contraction in multiples of 8 (or whole axes, padded).
     Ranking is a static cost proxy — fewer grid steps first (per-step
     launch/index overhead dominates small problems), then less padding
     waste, then larger contraction blocks (longer in-VMEM MAC runs) —
@@ -118,11 +127,12 @@ def candidate_blocks(op: str, shape, *, vmem_budget: int =
     if op not in OPS:
         raise ValueError(f"unknown autotune op {op!r}; expected one of "
                          f"{OPS}")
+    sub, lane = tiling(resolve_interpret(interpret))
     r, c, ct = shape
-    col_cands = [1] if c <= 1 else _axis_candidates(c)
-    ct_cands = [ct] if op == "dw_partials" else _axis_candidates(ct)
+    col_cands = [1] if c <= 1 else _axis_candidates(c, lane)
+    ct_cands = [ct] if op == "dw_partials" else _axis_candidates(ct, sub)
     scored = []
-    for br in _axis_candidates(r):
+    for br in _axis_candidates(r, lane):
         for bc in col_cands:
             for bct in ct_cands:
                 if vmem_bytes(op, (br, bc, bct)) > vmem_budget:
@@ -264,29 +274,12 @@ def clear_caches() -> None:
 # Measurement
 # ------------------------------------------------------------------------
 
-_WARNED_NO_TRACE_PROBE = False
-
-
 def _can_measure() -> bool:
-    global _WARNED_NO_TRACE_PROBE
+    """Timing is meaningful only in eager code: inside a jit trace even a
+    fresh constant is a tracer."""
     if os.environ.get("LNS_AUTOTUNE_DISABLE"):
         return False
-    try:
-        return jax.core.trace_state_clean()
-    except Exception:
-        # Without the probe we cannot tell traces from eager code, and
-        # timing inside a trace is meaningless — fall back to the
-        # heuristic, but never silently: the degradation must be visible.
-        if not _WARNED_NO_TRACE_PROBE:
-            _WARNED_NO_TRACE_PROBE = True
-            import warnings
-            warnings.warn(
-                "jax.core.trace_state_clean is unavailable in this jax "
-                "version; the block-size autotuner cannot detect jit "
-                "traces and will use the deterministic heuristic instead "
-                "of measuring.  Pass measure=True to lookup()/tune() "
-                "from eager code to tune explicitly.", RuntimeWarning)
-        return False
+    return not isinstance(jnp.zeros(()), jax.core.Tracer)
 
 
 def _measure_ms(fn, reps: int = 3) -> float:
@@ -357,17 +350,19 @@ def _bench_launcher(op: str, shape, blocks, fmt: LNSFormat,
 
 
 def tune(op: str, shape, *, fmt: LNSFormat, spec: DeltaSpec,
-         interpret: bool = True, vmem_budget: int = DEFAULT_VMEM_BUDGET,
-         max_candidates: int = 8, reps: int = 3, measure_fn=None,
-         verbose: bool = False):
+         interpret: "bool | None" = None,
+         vmem_budget: int = DEFAULT_VMEM_BUDGET, max_candidates: int = 8,
+         reps: int = 3, measure_fn=None, verbose: bool = False):
     """Measured search; returns ``(best_blocks, {blocks: ms})``.
 
     ``measure_fn(op, shape, blocks) -> ms`` overrides the real timing
     (tests inject deterministic stubs).  Does not consult or write any
     cache — :func:`lookup` wraps this with the cache discipline.
     """
+    interpret = resolve_interpret(interpret)
     results = {}
-    for blocks in candidate_blocks(op, shape, vmem_budget=vmem_budget,
+    for blocks in candidate_blocks(op, shape, interpret=interpret,
+                                   vmem_budget=vmem_budget,
                                    max_candidates=max_candidates):
         if measure_fn is not None:
             ms = float(measure_fn(op, shape, blocks))
@@ -384,7 +379,7 @@ def tune(op: str, shape, *, fmt: LNSFormat, spec: DeltaSpec,
 
 
 def lookup(op: str, shape, *, fmt: LNSFormat, spec: DeltaSpec,
-           interpret: bool = True, measure: "bool | None" = None,
+           interpret: "bool | None" = None, measure: "bool | None" = None,
            measure_fn=None, vmem_budget: int = DEFAULT_VMEM_BUDGET,
            max_candidates: int = 8, reps: int = 3, verbose: bool = False):
     """The blocks ``blocks=auto`` resolves to for one kernel launch.
@@ -402,6 +397,7 @@ def lookup(op: str, shape, *, fmt: LNSFormat, spec: DeltaSpec,
     bench search would have chosen.  (When measurement is impossible, a
     shallow measured entry still beats the heuristic.)
     """
+    interpret = resolve_interpret(interpret)
     key = entry_key(op, shape, fmt, spec, interpret)
     cached = _MEM.get(key)
     if cached is not None and cached[1] >= max_candidates \
@@ -425,7 +421,8 @@ def lookup(op: str, shape, *, fmt: LNSFormat, spec: DeltaSpec,
             return cached[0]
         if entry is not None:
             return tuple(entry["blocks"])
-        return heuristic_blocks(op, shape, vmem_budget=vmem_budget,
+        return heuristic_blocks(op, shape, interpret=interpret,
+                                vmem_budget=vmem_budget,
                                 max_candidates=max_candidates)
     best, results = tune(op, shape, fmt=fmt, spec=spec,
                          interpret=interpret, vmem_budget=vmem_budget,
@@ -439,7 +436,8 @@ def lookup(op: str, shape, *, fmt: LNSFormat, spec: DeltaSpec,
 
 
 def prime_matmul(m: int, k: int, n: int, *, fmt: LNSFormat,
-                 spec: DeltaSpec, interpret: bool = True, **tune_kw):
+                 spec: DeltaSpec, interpret: "bool | None" = None,
+                 **tune_kw):
     """Eagerly tune the three ⊞-MAC products of one (M, K) × (K, N) layer.
 
     Call this *outside* jit (model setup, bench warmup) so the jitted

@@ -1,17 +1,20 @@
 """Pallas TPU kernel for the ⊞-reduction (signed log-sum) along an axis.
 
 This is the hardware hot-spot of the paper's soft-max block (eq. 14):
-``Z = ⊞_j (codes_j, signs_j)`` with the fine LUT (d_max=10, r=1/64, 640
-entries in VMEM).  The row dimension is tiled over the grid; the reduce
-dimension is walked sequentially in-kernel (matching the paper's MAC
-ordering, bit-exact vs core.arithmetic.boxsum(order="sequential")).
+``Z = ⊞_j (codes_j, signs_j)``, and the combine of the data-parallel
+⊞-allreduce.  The reduce dimension is walked sequentially in-kernel
+(matching the paper's MAC ordering, bit-exact vs
+core.arithmetic.boxsum(order="sequential")).
 
-Layout: rows × K codes/signs as int32 planes; one (bm,) accumulator pair
-in VMEM scratch; K revisits via the innermost grid axis.
+Layout: the (M, K) planes are transposed to (K, M), so each reduce step
+reads one row at a dynamic sublane offset and the M rows being reduced
+ride the lanes; one (1, bm) accumulator pair in VMEM scratch; K revisits
+via the innermost grid axis.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -19,15 +22,15 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.delta import DeltaEngine, DeltaSpec
+from ...core.delta import DeltaSpec
 from ...core.formats import LNSFormat
-from ..lns_matmul.lns_matmul import (_boxplus_codes, _delta_bitshift,
-                                     _delta_exact, _delta_from_tables)
+from ...core.lns import resolve_interpret
+from ..lns_matmul.lns_matmul import (_boxplus_codes, make_delta_fn,
+                                     tile, tiling)
 
 
-def _kernel(tabp_ref, tabm_ref, c_ref, s_ref, out_c_ref, out_s_ref,
-            acc_c, acc_s, *, fmt: LNSFormat, spec: DeltaSpec, nk: int,
-            bk: int, r_code: int, underflow: int):
+def _kernel(c_ref, s_ref, out_c_ref, out_s_ref, acc_c, acc_s, *,
+            fmt: LNSFormat, spec: DeltaSpec, nk: int, bk: int):
     k_step = pl.program_id(1)
 
     @pl.when(k_step == 0)
@@ -35,26 +38,12 @@ def _kernel(tabp_ref, tabm_ref, c_ref, s_ref, out_c_ref, out_s_ref,
         acc_c[...] = jnp.full_like(acc_c, np.int32(fmt.zero_code))
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    if spec.kind == "bitshift":
-        def delta(d, same):
-            return _delta_bitshift(d, same, qf=fmt.qf,
-                                   underflow=np.int32(underflow))
-    elif spec.kind == "exact":
-        def delta(d, same):
-            return _delta_exact(d, same, scale=fmt.scale,
-                                underflow=np.int32(underflow))
-    else:
-        def delta(d, same):
-            return _delta_from_tables(
-                d, tabp_ref[...], tabm_ref[...], same, r_code=r_code,
-                n_tab=spec.table_size, underflow=np.int32(underflow))
-
-    codes = c_ref[...]
-    signs = s_ref[...]
+    delta = make_delta_fn(spec, fmt)
 
     def body(i, carry):
         ac, asn = carry
-        return _boxplus_codes(ac, asn, codes[:, i], signs[:, i], delta, fmt)
+        return _boxplus_codes(ac, asn, c_ref[pl.ds(i, 1), :],
+                              s_ref[pl.ds(i, 1), :], delta, fmt)
 
     ac, asn = jax.lax.fori_loop(0, bk, body, (acc_c[...], acc_s[...]))
     acc_c[...] = ac
@@ -68,46 +57,33 @@ def _kernel(tabp_ref, tabm_ref, c_ref, s_ref, out_c_ref, out_s_ref,
 
 def lns_boxsum_pallas(codes, signs, *, fmt: LNSFormat, spec: DeltaSpec,
                       block_m: int = 128, block_k: int = 128,
-                      interpret: bool = True):
+                      interpret: Optional[bool] = None):
     """⊞-reduce (M, K) int32 code/sign planes over axis 1 → (M,)."""
+    interpret = resolve_interpret(interpret)
     m, k = codes.shape
-    eng = DeltaEngine(spec, fmt)
-    if spec.kind == "lut":
-        tabp = jnp.asarray(eng._tab_plus, jnp.int32)
-        tabm = jnp.asarray(eng._tab_minus, jnp.int32)
-        r_code = eng.r_code
-    else:
-        tabp = jnp.zeros((1,), jnp.int32)
-        tabm = jnp.zeros((1,), jnp.int32)
-        r_code = 1
+    sub, lane = tiling(interpret)
+    block_m = tile(block_m, m, lane)
+    block_k = tile(block_k, k, sub)
     zc = np.int32(fmt.zero_code)
     pad_m = (-m) % block_m
     pad_k = (-k) % block_k
-    if pad_m or pad_k:
-        codes = jnp.pad(codes, ((0, pad_m), (0, pad_k)), constant_values=zc)
-        signs = jnp.pad(signs, ((0, pad_m), (0, pad_k)))
-    mp, kp = codes.shape
+    codes = jnp.pad(codes.T, ((0, pad_k), (0, pad_m)), constant_values=zc)
+    signs = jnp.pad(signs.T, ((0, pad_k), (0, pad_m)))
+    kp, mp = codes.shape
     grid = (mp // block_m, kp // block_k)
-    kernel = functools.partial(
-        _kernel, fmt=fmt, spec=spec, nk=grid[1], bk=block_k,
-        r_code=r_code, underflow=int(eng.underflow))
-    tab_spec = pl.BlockSpec(tabp.shape, lambda i, kk: (0,))
+    kernel = functools.partial(_kernel, fmt=fmt, spec=spec, nk=grid[1],
+                               bk=block_k)
+    in_spec = pl.BlockSpec((block_k, block_m), lambda i, kk: (kk, i))
+    out_spec = pl.BlockSpec((1, block_m), lambda i, kk: (0, i))
     out_c, out_s = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            tab_spec, tab_spec,
-            pl.BlockSpec((block_m, block_k), lambda i, kk: (i, kk)),
-            pl.BlockSpec((block_m, block_k), lambda i, kk: (i, kk)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_m,), lambda i, kk: (i,)),
-            pl.BlockSpec((block_m,), lambda i, kk: (i,)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((mp,), jnp.int32),
-                   jax.ShapeDtypeStruct((mp,), jnp.int32)],
-        scratch_shapes=[pltpu.VMEM((block_m,), jnp.int32),
-                        pltpu.VMEM((block_m,), jnp.int32)],
+        in_specs=[in_spec, in_spec],
+        out_specs=[out_spec, out_spec],
+        out_shape=[jax.ShapeDtypeStruct((1, mp), jnp.int32),
+                   jax.ShapeDtypeStruct((1, mp), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((1, block_m), jnp.int32),
+                        pltpu.VMEM((1, block_m), jnp.int32)],
         interpret=interpret,
-    )(tabp, tabm, codes, signs)
-    return out_c[:m], out_s[:m]
+    )(codes, signs)
+    return out_c[0, :m], out_s[0, :m]

@@ -7,7 +7,7 @@ import jax
 
 from ...core.delta import DeltaSpec
 from ...core.formats import LNSFormat
-from ...core.lns import LNSArray
+from ...core.lns import LNSArray, resolve_interpret
 from .lns_boxsum import lns_boxsum_pallas
 
 
@@ -31,8 +31,8 @@ def lns_boxsum_kernel(x: LNSArray, *, fmt: LNSFormat | None = None,
     :class:`~repro.core.plan.NumericsPlan` (or a parseable spec/plan
     string) — with a plan, ``layer`` picks which layer path's resolved
     spec applies (default: the plan's default spec); explicit pieces win.
-    ``interpret`` defaults to ``True`` (CPU validation) when neither
-    supplies it.
+    ``interpret`` left unset by both resolves from the platform
+    (``core.lns.resolve_interpret``: compiled on a TPU only).
 
     ``blocks`` is the spec's tiling axis: ``"auto"`` resolves
     (block_m, block_k) through the autotuner cache per shape
@@ -46,7 +46,7 @@ def lns_boxsum_kernel(x: LNSArray, *, fmt: LNSFormat | None = None,
         numerics, fmt=fmt, spec=spec, interpret=interpret,
         blocks=(None if blocks == "default" else blocks),
         op="lns_boxsum_kernel", layer=layer)
-    interpret = True if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     if spec_blocks == "auto":
         from .. import autotune
         block_m, _, block_k = autotune.lookup(

@@ -1,16 +1,16 @@
 """Pallas TPU kernels for the LNS ⊞-MAC matmul and its backward pass.
 
-TPU adaptation of the paper's multiplication-free MAC (DESIGN.md §3):
-the MXU cannot be used (there is no multiply to feed it); instead the
-max+Δ accumulation is vectorized on the VPU over output tiles held in
-VMEM, with the Δ± LUTs resident in VMEM (20–640 int32 entries).  The
+TPU adaptation of the paper's multiplication-free MAC: the MXU cannot be
+used (there is no multiply to feed it); instead the max+Δ accumulation is
+vectorized on the VPU over output tiles held in VMEM.  The LUT Δ± is a
+compare-select over the table's breakpoints with the values baked in as
+constants (``make_delta_fn``), bit-identical to ``DeltaEngine``.  The
 contraction dimension is walked *sequentially* — the innermost grid axis
 revisits the output tile, carrying the accumulator in VMEM scratch — which
 reproduces the paper's sequential MAC ordering bit-exactly (see ref.py).
 
 The entry points share one kernel body (``_mac_kernel``), parameterized
-by which axis of each operand is contracted and by an optional
-*flush-time epilogue*:
+by an optional *flush-time epilogue*:
 
 * ``lns_matmul_pallas``     Z[m,n]  = ⊞_k X[m,k] ⊡ W[k,n]   (forward, eq. 10)
 * ``lns_matmul_dx_pallas``  dX[m,k] = ⊞_n dY[m,n] ⊡ W[k,n]  (= dY ⊞ Wᵀ)
@@ -22,20 +22,19 @@ by which axis of each operand is contracted and by an optional
   (:class:`~repro.core.sgd.UpdateEpilogue`; see also ``update.py`` for
   the standalone elementwise variant the DP reduce applies post-combine)
 
-The backward kernels realize the transposed MACs of eqs. (10)-(14) without
-materializing a transpose: the BlockSpec index maps read W / X blocks in
-their stored layout and the in-kernel loop slices the contraction axis
-directly.  This is the hardware-shaped training path of Hamad et al.
-("Bitwidth-Specific Logarithmic Arithmetic for ... Training"): forward and
-backward matmuls run the same shifter/LUT datapath.
+The kernel takes both operands contraction-major, so every MAC step reads
+one row of each block at a dynamic sublane offset.  The entry points pass
+X (forward), dY and W (dX) transposed; dW's operands are stored that way
+already.  Forward and backward matmuls run the same shifter/LUT datapath,
+the hardware-shaped training path of Hamad et al. ("Bitwidth-Specific
+Logarithmic Arithmetic for ... Training").
 
-Block shapes are VPU/VMEM-aligned (multiples of (8, 128) for int32 tiles)
-on real TPUs; interpret mode accepts any blocking.  VMEM footprint per step
-≈ 2·(b_r·b_c + b_r·b_ct + b_ct·b_c)·4 B; the default (128, 128, 128) uses
-≈ 0.5 MiB — far below the ~16 MiB/core budget, leaving room for
-double-buffered HBM→VMEM pipelining by the Mosaic compiler.  The backward
-tiles use the same budget (the dX kernel holds (b_m·b_n)+(b_k·b_n) inputs
-plus 2·(b_m·b_k) accumulator planes).
+Compiled launches fit blocks to the chip's (8, 128) int32 tiling
+(:func:`tile`): output rows and columns in multiples of 128, the
+contraction in multiples of 8; interpret mode accepts any blocking.  VMEM
+footprint per step ≈ 2·(b_r·b_c + b_r·b_ct + b_ct·b_c)·4 B; the default
+(128, 128, 128) uses ≈ 0.5 MiB, leaving room for double-buffered
+HBM→VMEM pipelining by the Mosaic compiler.
 
 Signs are carried as int32 planes (0 = positive, 1 = negative): narrow int8
 lanes buy nothing on the VPU and complicate tiling.
@@ -54,17 +53,74 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...core.delta import DeltaEngine, DeltaSpec
 from ...core.formats import LNSFormat
+from ...core.lns import resolve_interpret
 from ...core.sgd import UpdateEpilogue
 
 
-def _delta_from_tables(d, tab_plus, tab_minus, same_sign, *, r_code, n_tab,
-                       underflow):
-    """Nearest-sample LUT evaluation of Δ± on integer d-codes."""
-    idx = (d + r_code // 2) // r_code
-    oob = idx >= n_tab
-    idx_c = jnp.clip(idx, 0, n_tab - 1)
-    dp = jnp.where(oob, 0, jnp.take(tab_plus, idx_c))
-    dm = jnp.where(oob, 0, jnp.take(tab_minus, idx_c))
+#: The TPU's native 32-bit tile, (sublanes, lanes).  A compiled kernel's
+#: blocks have last two dims that are multiples of these, or whole axes.
+SUBLANE, LANE = 8, 128
+
+
+def tiling(interpret: bool) -> tuple:
+    """``(sublane, lane)`` block alignment of a launch: the chip's tile
+    when compiled, none in interpret mode."""
+    return (1, 1) if interpret else (SUBLANE, LANE)
+
+
+def tile(block: int, dim: int, align: int) -> int:
+    """Tile edge along one axis of length ``dim``.
+
+    A ``block`` at least as long as the axis covers it in one tile, padded
+    up to ``align``; a shorter block must itself be a multiple of
+    ``align`` (one of :func:`tiling`).  Padding uses
+    the zero code, the ⊞ identity, so the tile never changes results.
+    """
+    if block >= dim:
+        return -(-dim // align) * align
+    if block % align:
+        raise ValueError(
+            f"block {block} over an axis of {dim} breaks the TPU tiling "
+            f"rule: use a multiple of {align}, or a block that covers the "
+            f"axis (interpret mode takes any block)")
+    return block
+
+
+def _lut_steps(eng: DeltaEngine):
+    """The LUT Δ± as step functions of the integer d-code.
+
+    ``DeltaEngine``'s nearest-sample lookup ``tab[(d + r//2) // r]`` (0
+    past the table) is constant between the breakpoints ``j·r - r//2``.
+    Returns ``(Δ+(0), Δ-(0), ((breakpoint, Δ+ or None, Δ- or None), ...))``
+    keeping only the breakpoints where a value changes.
+    """
+    r = eng.r_code
+    plus = [int(v) for v in eng._tab_plus] + [0]
+    minus = [int(v) for v in eng._tab_minus] + [0]
+    steps = []
+    for j in range(1, len(plus)):
+        p = plus[j] if plus[j] != plus[j - 1] else None
+        m = minus[j] if minus[j] != minus[j - 1] else None
+        if p is not None or m is not None:
+            steps.append((j * r - r // 2, p, m))
+    return plus[0], minus[0], tuple(steps)
+
+
+def _delta_lut(d, same_sign, steps, underflow):
+    """LUT Δ± by compare-select over the table's breakpoints.
+
+    Bit-identical to ``DeltaEngine.plus`` / ``minus``; needs no gather,
+    which the chip's compiler refuses on a 1-D table.
+    """
+    p0, m0, steps = steps
+    dp = jnp.full(d.shape, p0, jnp.int32)
+    dm = jnp.full(d.shape, m0, jnp.int32)
+    for t, p, m in steps:
+        ge = d >= t
+        if p is not None:
+            dp = jnp.where(ge, np.int32(p), dp)
+        if m is not None:
+            dm = jnp.where(ge, np.int32(m), dm)
     dm = jnp.where(d == 0, underflow, dm)
     return jnp.where(same_sign, dp, dm)
 
@@ -110,17 +166,22 @@ def _boxplus_codes(ac, asn, bc, bsn, delta_fn, fmt: LNSFormat):
     return code, sign
 
 
-def _make_delta_fn(tabp_ref, tabm_ref, *, fmt: LNSFormat, spec: DeltaSpec,
-                   r_code: int, underflow: int):
+def make_delta_fn(spec: DeltaSpec, fmt: LNSFormat):
+    """In-kernel Δ±(d, same_sign) on integer d-codes for one (Δ, format).
+
+    Every kind is plain elementwise integer (or float, for ``exact``) work
+    with its constants baked in at trace time: no table operand.
+    """
+    eng = DeltaEngine(spec, fmt)
+    underflow = np.int32(eng.underflow)
     if spec.kind == "bitshift":
-        return lambda d, same: _delta_bitshift(
-            d, same, qf=fmt.qf, underflow=np.int32(underflow))
+        return lambda d, same: _delta_bitshift(d, same, qf=fmt.qf,
+                                               underflow=underflow)
     if spec.kind == "exact":
-        return lambda d, same: _delta_exact(
-            d, same, scale=fmt.scale, underflow=np.int32(underflow))
-    return lambda d, same: _delta_from_tables(
-        d, tabp_ref[...], tabm_ref[...], same, r_code=r_code,
-        n_tab=spec.table_size, underflow=np.int32(underflow))
+        return lambda d, same: _delta_exact(d, same, scale=fmt.scale,
+                                            underflow=underflow)
+    steps = _lut_steps(eng)
+    return lambda d, same: _delta_lut(d, same, steps, underflow)
 
 
 # ------------------------------------------------------------------------
@@ -234,16 +295,16 @@ def _apply_update_epilogue(w_c, w_s, m_c, m_s, g_c, g_s,
 
 
 def _mac_kernel(*refs, fmt: LNSFormat, spec: DeltaSpec, n_ct: int, b_ct: int,
-                r_code: int, underflow: int,
-                a_contract_axis: int, b_contract_axis: int,
                 partial_flush: bool = False,
                 fwd_epilogue: Optional[FwdEpilogue] = None,
                 update_epilogue: Optional[UpdateEpilogue] = None):
     """Generic sequential ⊞-MAC over one contraction tile.
 
-    The output tile is the outer product of A's non-contracted axis (rows)
-    and B's non-contracted axis (columns); ``*_contract_axis`` selects which
-    axis of each VMEM-resident operand block the fori_loop walks.
+    Both operands arrive contraction-major: A as a (b_ct, b_r) block, B as
+    (b_ct, b_c).  Step ``i`` of the fori_loop reads row ``i`` of each
+    through its ref (a dynamic sublane offset, which the chip's compiler
+    accepts where a dynamic lane slice is refused), turns A's row into a
+    (b_r, 1) column and ⊞-accumulates the (b_r, b_c) outer product.
 
     ``partial_flush=True`` turns the kernel into a *segment-partial* MAC:
     the accumulator is re-initialized at every contraction block and each
@@ -262,12 +323,12 @@ def _mac_kernel(*refs, fmt: LNSFormat, spec: DeltaSpec, n_ct: int, b_ct: int,
     epilogue is the standalone fused-update kernel).
 
     The ref layout (built by ``_launch_mac``) is:
-    ``tab+, tab-, A, B, [bias], [w], [m], out, [z_sign], [m_out], acc``
+    ``A, B, [bias], [w], [m], out, [z_sign], [m_out], acc``
     with each logical operand a (code, sign) pair of refs.
     """
     refs = list(refs)
-    tabp_ref, tabm_ref, ac_ref, as_ref, bc_ref, bs_ref = refs[:6]
-    pos = 6
+    ac_ref, as_ref, bc_ref, bs_ref = refs[:4]
+    pos = 4
     has_bias = fwd_epilogue is not None and fwd_epilogue.bias
     emit_z_sign = fwd_epilogue is not None and fwd_epilogue.emit_z_sign
     has_update = update_epilogue is not None
@@ -308,31 +369,22 @@ def _mac_kernel(*refs, fmt: LNSFormat, spec: DeltaSpec, n_ct: int, b_ct: int,
             accs_ref[...] = jnp.zeros_like(accs_ref)
 
     zero = np.int32(fmt.zero_code)
-    delta = _make_delta_fn(tabp_ref, tabm_ref, fmt=fmt, spec=spec,
-                           r_code=r_code, underflow=underflow)
-
-    acode = ac_ref[...]
-    asign = as_ref[...]
-    bcode = bc_ref[...]
-    bsign = bs_ref[...]
+    delta = make_delta_fn(spec, fmt)
+    b_r = ac_ref.shape[1]
 
     def body(i, carry):
         acc_c, acc_s = carry
-        # Contraction slice i of this tile: (b_r, 1) ⊡ (1, b_c).
-        if a_contract_axis == 1:
-            a_c, a_s = acode[:, i], asign[:, i]
-        else:
-            a_c, a_s = acode[i, :], asign[i, :]
-        if b_contract_axis == 0:
-            b_c, b_s = bcode[i, :], bsign[i, :]
-        else:
-            b_c, b_s = bcode[:, i], bsign[:, i]
-        pc = a_c[:, None] + b_c[None, :]
-        pz = (a_c[:, None] == zero) | (b_c[None, :] == zero)
+        # Contraction step i of this tile: (b_r, 1) ⊡ (1, b_c).
+        a_c = ac_ref[pl.ds(i, 1), :].reshape(b_r, 1)
+        a_s = as_ref[pl.ds(i, 1), :].reshape(b_r, 1)
+        b_c = bc_ref[pl.ds(i, 1), :]
+        b_s = bs_ref[pl.ds(i, 1), :]
+        pc = a_c + b_c
+        pz = (a_c == zero) | (b_c == zero)
         pc = jnp.minimum(pc, fmt.code_max)
         pc = jnp.where(pc < fmt.min_nonzero_code, zero, pc)
         pc = jnp.where(pz, zero, pc)
-        ps = jnp.where(pz, 0, a_s[:, None] ^ b_s[None, :])
+        ps = jnp.where(pz, 0, a_s ^ b_s)
         return _boxplus_codes(acc_c, acc_s, pc, ps, delta, fmt)
 
     acc_c, acc_s = jax.lax.fori_loop(
@@ -375,20 +427,20 @@ def _pad2(code, sign, pad_r, pad_c, zero):
     return code, sign
 
 
-def _launch_mac(a_code, a_sign, b_code, b_sign, *, fmt: LNSFormat,
-                spec: DeltaSpec, a_contract_axis: int, b_contract_axis: int,
-                block_r: int, block_c: int, block_ct: int, interpret: bool,
-                partial_flush: bool = False,
+def _launch_mac(at_code, at_sign, b_code, b_sign, *, fmt: LNSFormat,
+                spec: DeltaSpec, block_r: int, block_c: int, block_ct: int,
+                interpret: bool, partial_flush: bool = False,
                 fwd_epilogue: Optional[FwdEpilogue] = None,
                 bias_code=None, bias_sign=None,
                 update_epilogue: Optional[UpdateEpilogue] = None,
                 w_code=None, w_sign=None, m_code=None, m_sign=None):
     """Shared pallas_call launcher for the three ⊞-MAC kernels.
 
-    ``a``'s non-contracted axis produces output rows (R), ``b``'s produces
-    output columns (C); the contraction length (CT) must agree.  R/C/CT need
-    not be multiples of the block sizes (inputs are padded with the zero
-    code, which is the ⊞ identity).
+    Both operands are contraction-major: ``at`` is (CT, R) and produces
+    the output rows, ``b`` is (CT, C) and produces the output columns.
+    R/C/CT need not be multiples of the block sizes (inputs are padded with
+    the zero code, which is the ⊞ identity); compiled launches fit the
+    blocks to the chip's (8, 128) tiling with :func:`tile`.
 
     With ``partial_flush=True`` the contraction is *not* carried across CT
     blocks: the call returns ``(n_ct, R, C)`` per-segment partials, one slot
@@ -408,54 +460,31 @@ def _launch_mac(a_code, a_sign, b_code, b_sign, *, fmt: LNSFormat,
             "after the combine (kernels/lns_matmul/update.py)")
     if fwd_epilogue is not None and update_epilogue is not None:
         raise ValueError("at most one flush epilogue per kernel launch")
-    a_r_axis = 1 - a_contract_axis
-    b_c_axis = 1 - b_contract_axis
-    r, ct = a_code.shape[a_r_axis], a_code.shape[a_contract_axis]
-    c, ct2 = b_code.shape[b_c_axis], b_code.shape[b_contract_axis]
-    assert ct == ct2, (a_code.shape, b_code.shape)
-    eng = DeltaEngine(spec, fmt)  # builds/validates tables
-    if spec.kind == "lut":
-        tabp = jnp.asarray(eng._tab_plus, jnp.int32)
-        tabm = jnp.asarray(eng._tab_minus, jnp.int32)
-        r_code = eng.r_code
-    else:
-        tabp = jnp.zeros((1,), jnp.int32)
-        tabm = jnp.zeros((1,), jnp.int32)
-        r_code = 1
-    underflow = int(eng.underflow)
+    ct, r = at_code.shape
+    ct2, c = b_code.shape
+    assert ct == ct2, (at_code.shape, b_code.shape)
+    sub, lane = tiling(interpret)
+    block_r = tile(block_r, r, lane)
+    block_c = tile(block_c, c, lane)
+    block_ct = tile(block_ct, ct, sub)
 
     zc = np.int32(fmt.zero_code)
     pad_r = (-r) % block_r
     pad_c = (-c) % block_c
     pad_ct = (-ct) % block_ct
-    if a_contract_axis == 1:
-        a_code, a_sign = _pad2(a_code, a_sign, pad_r, pad_ct, zc)
-        a_block = (block_r, block_ct)
-        a_index = lambda i, j, s: (i, s)
-    else:
-        a_code, a_sign = _pad2(a_code, a_sign, pad_ct, pad_r, zc)
-        a_block = (block_ct, block_r)
-        a_index = lambda i, j, s: (s, i)
-    if b_contract_axis == 0:
-        b_code, b_sign = _pad2(b_code, b_sign, pad_ct, pad_c, zc)
-        b_block = (block_ct, block_c)
-        b_index = lambda i, j, s: (s, j)
-    else:
-        b_code, b_sign = _pad2(b_code, b_sign, pad_c, pad_ct, zc)
-        b_block = (block_c, block_ct)
-        b_index = lambda i, j, s: (j, s)
+    at_code, at_sign = _pad2(at_code, at_sign, pad_ct, pad_r, zc)
+    b_code, b_sign = _pad2(b_code, b_sign, pad_ct, pad_c, zc)
+    a_spec = pl.BlockSpec((block_ct, block_r), lambda i, j, s: (s, i))
+    b_spec = pl.BlockSpec((block_ct, block_c), lambda i, j, s: (s, j))
 
     rp, cp, ctp = r + pad_r, c + pad_c, ct + pad_ct
     grid = (rp // block_r, cp // block_c, ctp // block_ct)
 
     kernel = functools.partial(
         _mac_kernel, fmt=fmt, spec=spec, n_ct=grid[2], b_ct=block_ct,
-        r_code=r_code, underflow=underflow,
-        a_contract_axis=a_contract_axis, b_contract_axis=b_contract_axis,
         partial_flush=partial_flush, fwd_epilogue=fwd_epilogue,
         update_epilogue=update_epilogue)
 
-    tab_spec = pl.BlockSpec(tabp.shape, lambda i, j, s: (0,))
     out_block = pl.BlockSpec((block_r, block_c), lambda i, j, s: (i, j))
 
     extra_in, extra_in_specs = [], []
@@ -505,13 +534,7 @@ def _launch_mac(a_code, a_sign, b_code, b_sign, *, fmt: LNSFormat,
     outs = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            tab_spec, tab_spec,
-            pl.BlockSpec(a_block, a_index),
-            pl.BlockSpec(a_block, a_index),
-            pl.BlockSpec(b_block, b_index),
-            pl.BlockSpec(b_block, b_index),
-        ] + extra_in_specs,
+        in_specs=[a_spec, a_spec, b_spec, b_spec] + extra_in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
@@ -519,7 +542,7 @@ def _launch_mac(a_code, a_sign, b_code, b_sign, *, fmt: LNSFormat,
             pltpu.VMEM((block_r, block_c), jnp.int32),
         ],
         interpret=interpret,
-    )(tabp, tabm, a_code, a_sign, b_code, b_sign, *extra_in)
+    )(at_code, at_sign, b_code, b_sign, *extra_in)
     if partial_flush:
         return tuple(o[:, :r, :c] for o in outs)
     return tuple(o[:r, :c] for o in outs)
@@ -528,51 +551,61 @@ def _launch_mac(a_code, a_sign, b_code, b_sign, *, fmt: LNSFormat,
 def lns_matmul_pallas(x_code, x_sign, w_code, w_sign, *,
                       fmt: LNSFormat, spec: DeltaSpec,
                       block_m: int = 128, block_n: int = 128,
-                      block_k: int = 128, interpret: bool = True):
+                      block_k: int = 128, interpret: Optional[bool] = None):
     """Forward: x (M, K) ⊞-MAC w (K, N) → (M, N), sequential over K."""
-    return _launch_mac(x_code, x_sign, w_code, w_sign, fmt=fmt, spec=spec,
-                       a_contract_axis=1, b_contract_axis=0,
-                       block_r=block_m, block_c=block_n, block_ct=block_k,
-                       interpret=interpret)
+    return _launch_mac(x_code.T, x_sign.T, w_code, w_sign, fmt=fmt,
+                       spec=spec, block_r=block_m, block_c=block_n,
+                       block_ct=block_k,
+                       interpret=resolve_interpret(interpret))
 
 
 def lns_matmul_dx_pallas(dy_code, dy_sign, w_code, w_sign, *,
                          fmt: LNSFormat, spec: DeltaSpec,
                          block_m: int = 128, block_k: int = 128,
-                         block_n: int = 128, interpret: bool = True):
+                         block_n: int = 128,
+                         interpret: Optional[bool] = None):
     """Backward wrt activations: dY (M, N) ⊞-MAC Wᵀ → dX (M, K).
 
-    W is read in its stored (K, N) layout; the contraction walks N
-    sequentially (ascending), matching ``lns_matmul(dY, Wᵀ)`` with
-    ``order="sequential"`` bit-exactly.
+    The contraction walks N sequentially (ascending), matching
+    ``lns_matmul(dY, Wᵀ)`` with ``order="sequential"`` bit-exactly.
     """
-    return _launch_mac(dy_code, dy_sign, w_code, w_sign, fmt=fmt, spec=spec,
-                       a_contract_axis=1, b_contract_axis=1,
-                       block_r=block_m, block_c=block_k, block_ct=block_n,
-                       interpret=interpret)
+    return _launch_mac(dy_code.T, dy_sign.T, w_code.T, w_sign.T, fmt=fmt,
+                       spec=spec, block_r=block_m, block_c=block_k,
+                       block_ct=block_n,
+                       interpret=resolve_interpret(interpret))
 
 
 def lns_matmul_dw_pallas(x_code, x_sign, dy_code, dy_sign, *,
                          fmt: LNSFormat, spec: DeltaSpec,
                          block_k: int = 128, block_n: int = 128,
-                         block_m: int = 128, interpret: bool = True):
+                         block_m: int = 128,
+                         interpret: Optional[bool] = None):
     """Backward wrt weights: Xᵀ ⊞-MAC dY (M, N) → dW (K, N).
 
-    X is read in its stored (M, K) layout; the contraction walks the batch
-    dimension M sequentially (ascending), matching ``lns_matmul(Xᵀ, dY)``
-    with ``order="sequential"`` bit-exactly.
+    X and dY are read in their stored (M, ·) layout, already
+    contraction-major; the contraction walks the batch dimension M
+    sequentially (ascending), matching ``lns_matmul(Xᵀ, dY)`` with
+    ``order="sequential"`` bit-exactly.
     """
     return _launch_mac(x_code, x_sign, dy_code, dy_sign, fmt=fmt, spec=spec,
-                       a_contract_axis=0, b_contract_axis=0,
                        block_r=block_k, block_c=block_n, block_ct=block_m,
-                       interpret=interpret)
+                       interpret=resolve_interpret(interpret))
+
+
+def _pad_segments(a, num_segments: int, pad: int, fill):
+    """(S·seg, N) → (S·(seg + pad), N), ``pad`` fill rows after each
+    segment."""
+    seg = a.shape[0] // num_segments
+    a = a.reshape(num_segments, seg, -1)
+    a = jnp.pad(a, ((0, 0), (0, pad), (0, 0)), constant_values=fill)
+    return a.reshape(num_segments * (seg + pad), -1)
 
 
 def lns_matmul_dw_partials_pallas(x_code, x_sign, dy_code, dy_sign, *,
                                   num_segments: int, fmt: LNSFormat,
                                   spec: DeltaSpec, block_k: int = 128,
                                   block_n: int = 128,
-                                  interpret: bool = True):
+                                  interpret: Optional[bool] = None):
     """Backward-weight kernel with per-segment partial-code flush.
 
     The batch M is split into ``num_segments`` equal contiguous segments
@@ -584,15 +617,27 @@ def lns_matmul_dw_partials_pallas(x_code, x_sign, dy_code, dy_sign, *,
     combines in canonical segment order — combining them sequentially
     reproduces the single-device sequential MAC schedule over the canonical
     segmentation regardless of how segments are assigned to devices.
+
+    Each segment is padded with zero-code rows (the ⊞ identity, after
+    the segment's own rows) to a multiple of 8 rows, so any segment size
+    tiles on the chip.
     """
     m = x_code.shape[0]
     if num_segments < 1 or m % num_segments:
         raise ValueError(
             f"batch {m} not divisible into {num_segments} equal segments")
+    seg = m // num_segments
+    pad = (-seg) % SUBLANE
+    if pad:
+        zc = np.int32(fmt.zero_code)
+        x_code = _pad_segments(x_code, num_segments, pad, zc)
+        x_sign = _pad_segments(x_sign, num_segments, pad, 0)
+        dy_code = _pad_segments(dy_code, num_segments, pad, zc)
+        dy_sign = _pad_segments(dy_sign, num_segments, pad, 0)
     return _launch_mac(x_code, x_sign, dy_code, dy_sign, fmt=fmt, spec=spec,
-                       a_contract_axis=0, b_contract_axis=0,
                        block_r=block_k, block_c=block_n,
-                       block_ct=m // num_segments, interpret=interpret,
+                       block_ct=seg + pad,
+                       interpret=resolve_interpret(interpret),
                        partial_flush=True)
 
 
@@ -601,7 +646,8 @@ def lns_matmul_fused_pallas(x_code, x_sign, w_code, w_sign, *,
                             epilogue: FwdEpilogue,
                             bias_code=None, bias_sign=None,
                             block_m: int = 128, block_n: int = 128,
-                            block_k: int = 128, interpret: bool = True):
+                            block_k: int = 128,
+                            interpret: Optional[bool] = None):
     """Forward ⊞-MAC with the flush-time epilogue (bias ⊞ / llrelu /
     requantize) applied to the final accumulator — one pass instead of
     matmul + three separate elementwise passes.
@@ -612,11 +658,12 @@ def lns_matmul_fused_pallas(x_code, x_sign, w_code, w_sign, *,
     format's grid.  Bit-exact against ``ref.lns_matmul_fused_ref``, the
     unfused composition.
     """
-    return _launch_mac(x_code, x_sign, w_code, w_sign, fmt=fmt, spec=spec,
-                       a_contract_axis=1, b_contract_axis=0,
-                       block_r=block_m, block_c=block_n, block_ct=block_k,
-                       interpret=interpret, fwd_epilogue=epilogue,
-                       bias_code=bias_code, bias_sign=bias_sign)
+    return _launch_mac(x_code.T, x_sign.T, w_code, w_sign, fmt=fmt,
+                       spec=spec, block_r=block_m, block_c=block_n,
+                       block_ct=block_k,
+                       interpret=resolve_interpret(interpret),
+                       fwd_epilogue=epilogue, bias_code=bias_code,
+                       bias_sign=bias_sign)
 
 
 def lns_matmul_dw_update_pallas(x_code, x_sign, dy_code, dy_sign, *,
@@ -624,7 +671,8 @@ def lns_matmul_dw_update_pallas(x_code, x_sign, dy_code, dy_sign, *,
                                 fmt: LNSFormat, spec: DeltaSpec,
                                 m_code=None, m_sign=None,
                                 block_k: int = 128, block_n: int = 128,
-                                block_m: int = 128, interpret: bool = True):
+                                block_m: int = 128,
+                                interpret: Optional[bool] = None):
     """Backward-weight ⊞-MAC with the fused ⊞-SGD update at flush.
 
     Computes ``dW = Xᵀ ⊞-MAC dY`` and, at the final accumulator flush,
@@ -635,8 +683,7 @@ def lns_matmul_dw_update_pallas(x_code, x_sign, dy_code, dy_sign, *,
     memory.  Bit-exact against ``matmul_dw`` + ``apply_update_codes``.
     """
     return _launch_mac(x_code, x_sign, dy_code, dy_sign, fmt=fmt, spec=spec,
-                       a_contract_axis=0, b_contract_axis=0,
                        block_r=block_k, block_c=block_n, block_ct=block_m,
-                       interpret=interpret, update_epilogue=epilogue,
-                       w_code=w_code, w_sign=w_sign,
-                       m_code=m_code, m_sign=m_sign)
+                       interpret=resolve_interpret(interpret),
+                       update_epilogue=epilogue, w_code=w_code,
+                       w_sign=w_sign, m_code=m_code, m_sign=m_sign)

@@ -43,11 +43,12 @@ def _call(kind, a_code, a_sign, b_code, b_sign, fmt, spec,
 def lns_matmul_kernel(x: LNSArray, w: LNSArray, *, fmt: LNSFormat,
                       spec: DeltaSpec, block_m: int = 128,
                       block_n: int = 128, block_k: int = 128,
-                      interpret: bool = True) -> LNSArray:
+                      interpret: bool | None = None) -> LNSArray:
     """(M, K) ⊞-MAC (K, N) → (M, N) via the Pallas kernel.
 
-    ``interpret=True`` (default here) runs the kernel body on CPU for
-    validation; on real TPU hardware pass ``interpret=False``.
+    ``interpret=None`` compiles the kernel on a TPU and runs the Pallas
+    interpreter on any other platform (``core.lns.resolve_interpret``);
+    ``True`` / ``False`` force either.
     """
     code, sign = _call("fwd", x.code, x.sign, w.code, w.sign, fmt, spec,
                        block_m, block_n, block_k, interpret)
@@ -57,7 +58,7 @@ def lns_matmul_kernel(x: LNSArray, w: LNSArray, *, fmt: LNSFormat,
 def lns_matmul_dx_kernel(dy: LNSArray, w: LNSArray, *, fmt: LNSFormat,
                          spec: DeltaSpec, block_m: int = 128,
                          block_k: int = 128, block_n: int = 128,
-                         interpret: bool = True) -> LNSArray:
+                         interpret: bool | None = None) -> LNSArray:
     """Backward-activation kernel: dY (M, N) ⊞-MAC Wᵀ → dX (M, K)."""
     code, sign = _call("dx", dy.code, dy.sign, w.code, w.sign, fmt, spec,
                        block_m, block_k, block_n, interpret)
@@ -67,7 +68,7 @@ def lns_matmul_dx_kernel(dy: LNSArray, w: LNSArray, *, fmt: LNSFormat,
 def lns_matmul_dw_kernel(x: LNSArray, dy: LNSArray, *, fmt: LNSFormat,
                          spec: DeltaSpec, block_k: int = 128,
                          block_n: int = 128, block_m: int = 128,
-                         interpret: bool = True) -> LNSArray:
+                         interpret: bool | None = None) -> LNSArray:
     """Backward-weight kernel: Xᵀ ⊞-MAC dY (M, N) → dW (K, N)."""
     code, sign = _call("dw", x.code, x.sign, dy.code, dy.sign, fmt, spec,
                        block_k, block_n, block_m, interpret)
@@ -88,7 +89,7 @@ def lns_matmul_dw_partials_kernel(x: LNSArray, dy: LNSArray, *,
                                   num_segments: int, fmt: LNSFormat,
                                   spec: DeltaSpec, block_k: int = 128,
                                   block_n: int = 128,
-                                  interpret: bool = True) -> LNSArray:
+                                  interpret: bool | None = None) -> LNSArray:
     """Segmented backward-weight kernel: (S, K, N) per-segment dW partials.
 
     The batch M is cut into ``num_segments`` contiguous equal segments; slot
@@ -123,7 +124,8 @@ def lns_matmul_fused_kernel(x: LNSArray, w: LNSArray, *,
                             bias: "LNSArray | None" = None,
                             fmt: LNSFormat, spec: DeltaSpec,
                             block_m: int = 128, block_n: int = 128,
-                            block_k: int = 128, interpret: bool = True):
+                            block_k: int = 128,
+                            interpret: bool | None = None):
     """Forward ⊞-MAC with the flush-time epilogue — one kernel pass.
 
     Returns the epilogued product (in ``epilogue.dst_fmt`` when set), or
@@ -164,7 +166,8 @@ def lns_matmul_dw_update_kernel(x: LNSArray, dy: LNSArray, *, w: LNSArray,
                                 fmt: LNSFormat, spec: DeltaSpec,
                                 m: "LNSArray | None" = None,
                                 block_k: int = 128, block_n: int = 128,
-                                block_m: int = 128, interpret: bool = True):
+                                block_m: int = 128,
+                                interpret: bool | None = None):
     """Backward-weight ⊞-MAC with the ⊞-SGD update fused into the flush.
 
     ``dW = Xᵀ ⊞-MAC dY`` never leaves VMEM: the final accumulator is
@@ -202,7 +205,8 @@ def _call_fused_update(w_code, w_sign, g_code, g_sign, m_code, m_sign,
 def lns_fused_update_kernel(w: LNSArray, g: LNSArray, *,
                             epilogue: UpdateEpilogue, fmt: LNSFormat,
                             spec: DeltaSpec, m: "LNSArray | None" = None,
-                            block: int = 8192, interpret: bool = True):
+                            block: int = 8192,
+                            interpret: bool | None = None):
     """One-pass fused ⊞-SGD update: ``(w, m, g) → (w', m')``.
 
     The post-⊞-combine epilogue of the DP deterministic reduce (reused by

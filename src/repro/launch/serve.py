@@ -11,6 +11,7 @@ import jax
 import numpy as np
 
 from ..configs import get_config, reduced
+from .compile_cache import enable_compile_cache
 from ..nn import init_params
 from ..serve import ServeConfig, ServingEngine
 
@@ -31,6 +32,7 @@ def main(argv=None):
     ap.add_argument("--chunk", type=int, default=8,
                     help="prompt tokens spliced per prefill chunk")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = reduced(get_config(args.arch)).with_(numerics=args.numerics,
                                                param_dtype="float32",

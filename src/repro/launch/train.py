@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs import get_config, reduced
+from .compile_cache import enable_compile_cache
 from ..ckpt import CheckpointManager
 from ..core.plan import NumericsPlan
 from ..data import DataConfig, SyntheticLMDataset
@@ -80,6 +81,7 @@ def main(argv=None):
                     "plan differs from --numerics (deliberate format "
                     "migration; LNS codes are NOT re-encoded)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

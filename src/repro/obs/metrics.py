@@ -138,6 +138,14 @@ def _label(counter: str, layer, op) -> str:
     return f"{layer}/{op}/{counter}"
 
 
+def add_taps(taps: dict) -> None:
+    """Merge ``label → value`` taps collected in an inner trace (and
+    returned from it as outputs) into the live collector."""
+    if enabled():
+        for label, value in taps.items():
+            _COLLECTORS[-1].add(label, value)
+
+
 def tap(counter: str, value, *, layer=None, op=None) -> None:
     """Record one labeled int32 value (no-op unless collection is on)."""
     if enabled():

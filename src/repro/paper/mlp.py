@@ -20,8 +20,8 @@ runtime — bit-identical to the pre-plan single-runtime path); a plan like
 lns12 while the softmax-critical output layer stays lns16, with exact
 integer barrel-shift conversions (:func:`~repro.core.lns.convert_format`)
 at the layer boundaries.  ``backend="emulate"`` runs the pure-jnp
-sequential MAC, ``"pallas"`` the blocked TPU kernels (interpret mode on
-CPU); the two backends are bit-exact down to the last weight code — also
+sequential MAC, ``"pallas"`` the blocked TPU kernels (interpret mode off the
+TPU); the two backends are bit-exact down to the last weight code — also
 under mixed-format plans.  The legacy loose knobs (``matmul_backend=`` /
 ``reduce_mode=`` / ``grad_segments=``) still construct, with a
 ``DeprecationWarning`` pointing at the spec field they fold into.
@@ -85,7 +85,10 @@ class MLPConfig:
                                     # from bits/approx (end-to-end train
                                     # spec, emulate).  Normalized to a
                                     # NumericsPlan in __post_init__.
-    matmul_block: int = 32          # kernel tile edge; ≥128 on real TPUs
+    matmul_block: int = 128         # kernel tile edge (compiled
+                                    # launches fit it to the chip's
+                                    # (8, 128) tiling; CPU tests pass
+                                    # small tiles explicitly)
     fused: bool = True              # lns only: flush-time kernel epilogues
                                     # (bias/llrelu/requantize in the fwd
                                     # kernel, ⊞-SGD in the dW flush) —

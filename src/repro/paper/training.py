@@ -26,6 +26,9 @@ class RunResult:
     val_curve: list
     test_acc: float
     seconds: float
+    losses: list = dataclasses.field(default_factory=list)  # per step
+    params: Any = None        # the trained parameters
+    lanes: dict = dataclasses.field(default_factory=dict)  # layer → lane
 
     def row(self):
         return dict(backend=self.backend, dataset=self.dataset,
@@ -107,6 +110,7 @@ def run_experiment(backend: str, dataset: str, *, bits: int = 16,
     t0 = time.time()
     curve = []
     gstep = 0
+    losses = []
     for _ in range(epochs):
         order = rng.permutation(len(x_tr))
         steps = len(order) // batch_size
@@ -115,18 +119,22 @@ def run_experiment(backend: str, dataset: str, *, bits: int = 16,
         for s in range(steps):
             sl = order[s * batch_size:(s + 1) * batch_size]
             if stochastic_round and backend == "fxp":
-                params, _ = model.train_step(
+                params, loss = model.train_step(
                     params, x_tr[sl], y_tr[sl],
                     jax.random.PRNGKey(seed * 1_000_003 + gstep))
             elif mom is not None:
-                params, mom, _ = model.train_step(params, x_tr[sl],
-                                                  y_tr[sl], mom)
+                params, mom, loss = model.train_step(params, x_tr[sl],
+                                                     y_tr[sl], mom)
             else:
-                params, _ = model.train_step(params, x_tr[sl], y_tr[sl])
+                params, loss = model.train_step(params, x_tr[sl], y_tr[sl])
+            losses.append(loss)
             gstep += 1
             if hasattr(model, "apply_decay") and wd and (s + 1) % 16 == 0:
                 params = model.apply_decay(params, 16)
         curve.append(evaluate(model, params, x_val, y_val))
     test = evaluate(model, params, x_te, y_te)
+    inner = getattr(model, "inner", model)
+    lanes = inner.lanes() if hasattr(inner, "lanes") else {}
     return RunResult(backend, dataset, bits, approx, curve, test,
-                     time.time() - t0)
+                     time.time() - t0, [float(v) for v in losses], params,
+                     lanes)

@@ -284,7 +284,7 @@ class ServingEngine:
         toks[0, :nv] = chunk
         logits, self.caches = self._prefill(
             self.params, jnp.asarray(toks), self.caches,
-            jnp.asarray(self.bt[req.slot]), jnp.int32(req.prefill_pos),
+            jnp.array(self.bt[req.slot]), jnp.int32(req.prefill_pos),
             jnp.int32(nv))
         req.prefill_pos += nv
         self.stats["prefill_chunks"] += 1
@@ -318,9 +318,11 @@ class ServingEngine:
                     act[slot] = False
         if not act.any():
             return
+        # Copies: the host mutates tok/pos/bt while this step may still be
+        # pending, and on the CPU jnp.asarray can alias numpy memory.
         logits, self.caches = self._decode(
-            self.params, jnp.asarray(self.tok), self.caches,
-            jnp.asarray(self.bt), jnp.asarray(self.pos), jnp.asarray(act))
+            self.params, jnp.array(self.tok), self.caches,
+            jnp.array(self.bt), jnp.array(self.pos), jnp.asarray(act))
         self.stats["decode_steps"] += 1
         self.stats["occupancy_sum"] += int(act.sum())
         for slot in range(self.sc.max_batch):

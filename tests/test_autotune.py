@@ -39,6 +39,19 @@ def test_candidates_ranked_and_bounded():
     assert cands[0] == (64, 100, 784)
 
 
+def test_compiled_lane_candidates_meet_tpu_tiling():
+    """Compiled launches only get tiles the chip's (8, 128) rule admits:
+    row and column tiles in multiples of 128, the contraction in
+    multiples of 8 (whole axes padded up)."""
+    for op, shape in (("fwd", (64, 100, 784)), ("dx", (512, 2048, 6144)),
+                      ("dw", (784, 100, 64)), ("boxsum", (78400, 1, 4))):
+        cands = autotune.candidate_blocks(op, shape, interpret=False)
+        assert cands
+        for br, bc, bct in cands:
+            assert br % 128 == 0 and bct % 8 == 0
+            assert bc == 1 if op == "boxsum" else bc % 128 == 0
+
+
 def test_candidates_dw_partials_pin_contraction():
     """Segment length is part of the DP determinism contract — the
     contraction block is not tunable for the partials kernel."""
@@ -276,9 +289,9 @@ def test_cache_key_partitioned_by_interpret_lane(tuner_dir):
     compiled-lane lookup (and vice versa): the lanes time differently,
     so sharing entries would pin interpreter-shaped tiles on hardware."""
     shape = (64, 100, 784)
-    heuristic = autotune.heuristic_blocks("fwd", shape)
+    heuristic = autotune.heuristic_blocks("fwd", shape, interpret=True)
     # the stub prefers a candidate the heuristic would NOT pick
-    cands = autotune.candidate_blocks("fwd", shape)
+    cands = autotune.candidate_blocks("fwd", shape, interpret=True)
     seeded = next(c for c in cands if c != heuristic)
 
     def stub(op, shape, blocks):
@@ -288,19 +301,24 @@ def test_cache_key_partitioned_by_interpret_lane(tuner_dir):
                           interpret=True, measure=True, measure_fn=stub)
     assert got == seeded
     # compiled-lane lookup: no measurement allowed -> must fall back to
-    # the heuristic, NOT the interpret-tuned entry
+    # the (chip-tiled) heuristic, NOT the interpret-tuned entry
     assert autotune.lookup("fwd", shape, fmt=LNS16, spec=DELTA_DEFAULT,
-                           interpret=False, measure=False) == heuristic
+                           interpret=False, measure=False) \
+        == autotune.heuristic_blocks("fwd", shape, interpret=False)
     # ... and the other direction: tune compiled, look up interpret
+    hw_heuristic = autotune.heuristic_blocks("dx", shape, interpret=False)
+    seeded_hw = next(c for c in autotune.candidate_blocks(
+        "dx", shape, interpret=False) if c != hw_heuristic)
+
     def stub2(op, shape, blocks):
-        return 1.0 if blocks == seeded else 2.0
+        return 1.0 if blocks == seeded_hw else 2.0
     autotune.clear_caches()
     got2 = autotune.lookup("dx", shape, fmt=LNS16, spec=DELTA_DEFAULT,
                            interpret=False, measure=True, measure_fn=stub2)
-    assert got2 == seeded
+    assert got2 == seeded_hw
     assert autotune.lookup("dx", shape, fmt=LNS16, spec=DELTA_DEFAULT,
                            interpret=True, measure=False) \
-        == autotune.heuristic_blocks("dx", shape)
+        == autotune.heuristic_blocks("dx", shape, interpret=True)
     # the partition is visible in the key itself
     k_i = autotune.entry_key("fwd", shape, LNS16, DELTA_DEFAULT, True)
     k_c = autotune.entry_key("fwd", shape, LNS16, DELTA_DEFAULT, False)

@@ -163,3 +163,15 @@ def test_convert_format_value_roundtrip_via_floats():
     # round-half-up on the code grid vs round-nearest through log2 can
     # differ by at most one ulp of the narrow grid
     assert np.abs(np.asarray(b.code) - np.asarray(direct.code)).max() <= 1
+
+
+def test_resolve_interpret_explicit_flag_wins_else_platform():
+    """Interpret mode is decided in one place: an explicit flag wins, and
+    None means compiled exactly when the platform is a TPU."""
+    import jax
+    from repro.core.lns import LNSMatmulBackend, resolve_interpret
+    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False
+    assert resolve_interpret(None) is (jax.default_backend() != "tpu")
+    be = LNSMatmulBackend(fmt=LNS16, spec=None, backend="pallas")
+    assert be._interp() is resolve_interpret(None)

@@ -52,3 +52,9 @@ def test_boxsum_block_invariance(rng):
     z2 = lns_boxsum_kernel(x, fmt=LNS16, spec=DELTA_DEFAULT,
                            block_m=16, block_k=32)
     np.testing.assert_array_equal(np.asarray(z1.code), np.asarray(z2.code))
+
+
+def test_boxsum_chip_tiles(rng):
+    """The DP combine's fold at compiled-launch tiles (128 lanes of
+    elements, an 8-row multiple of segments), zero-code padded."""
+    _run(rng, 300, 4, LNS16, DELTA_DEFAULT, bm=128, bk=8)

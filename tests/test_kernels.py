@@ -3,6 +3,7 @@
 The kernel preserves the paper's sequential MAC ordering, so comparisons to
 ref.py are **bit-exact** across shapes, block shapes, formats and Δ specs.
 """
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -82,3 +83,40 @@ def test_kernel_mixed_scale(rng):
     """Wide dynamic range exercises saturation paths identically."""
     _run(rng, 8, 12, 8, LNS12, DELTA_DEFAULT, scale=5.0)
     _run(rng, 8, 12, 8, LNS12, DELTA_DEFAULT, scale=0.01)
+
+
+@pytest.mark.parametrize("spec", [DELTA_DEFAULT, DELTA_SOFTMAX],
+                         ids=["lut20", "lut640"])
+@pytest.mark.parametrize("fmt", [LNS16, LNS12], ids=["lns16", "lns12"])
+def test_in_kernel_lut_delta_matches_engine_everywhere(fmt, spec):
+    """The gather-free compare-select LUT equals DeltaEngine's table
+    lookup at every d-code the format can produce, for both signs."""
+    from repro.core.delta import DeltaEngine
+    from repro.kernels.lns_matmul.lns_matmul import make_delta_fn
+    eng = DeltaEngine(spec, fmt)
+    d = jnp.arange(0, fmt.code_max - fmt.code_min + 1, dtype=jnp.int32)
+    delta = make_delta_fn(spec, fmt)
+    np.testing.assert_array_equal(np.asarray(delta(d, True)),
+                                  np.asarray(eng.plus(d)))
+    np.testing.assert_array_equal(np.asarray(delta(d, False)),
+                                  np.asarray(eng.minus(d)))
+
+
+def test_tile_rule():
+    """A block covering the axis pads it to the alignment; a shorter one
+    must itself be aligned."""
+    from repro.kernels.lns_matmul.lns_matmul import tile
+    assert tile(128, 100, 128) == 128
+    assert tile(512, 784, 8) == 512
+    assert tile(1024, 784, 8) == 784
+    assert tile(128, 6, 8) == 8
+    assert tile(8, 100, 1) == 8
+    with pytest.raises(ValueError, match="tiling rule"):
+        tile(32, 100, 128)
+
+
+@pytest.mark.parametrize("m,k,n", [(20, 50, 12), (64, 100, 10)])
+def test_kernel_bitexact_chip_tiles(rng, m, k, n):
+    """The tiles a compiled launch uses — 128-wide rows and columns, an
+    8-row multiple of contraction — padded with the zero code, bit-exact."""
+    _run(rng, m, k, n, LNS16, DELTA_DEFAULT, bm=128, bn=128, bk=24)

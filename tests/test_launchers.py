@@ -65,3 +65,60 @@ def test_serve_cli_batched(capsys):
                            "--temperature", "0"])
     assert len(outs) == 3
     assert all(len(o) >= 1 for o in outs)
+
+
+def test_compile_cache_env_dir_wins(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, compiled programs land there
+    and the helper sets no other directory."""
+    import os
+    import subprocess
+    import sys
+    code = ("from repro.launch.compile_cache import enable_compile_cache\n"
+            "import jax, jax.numpy as jnp\n"
+            "d = enable_compile_cache()\n"
+            "assert jax.config.jax_compilation_cache_dir == d, d\n"
+            "jax.jit(lambda x: jnp.sin(x) * 2)(jnp.ones(3))"
+            ".block_until_ready()\n"
+            "print(d)\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == str(tmp_path)
+    assert any(p.name.endswith("-cache") for p in tmp_path.iterdir())
+
+
+def test_compile_cache_defaults_to_checkout(monkeypatch):
+    """Without the variable the cache goes to a fixed .jax_cache/ at the
+    checkout root."""
+    import os
+    import jax
+    from repro.launch import compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        d = compile_cache.enable_compile_cache()
+        assert d == os.path.join(compile_cache.CHECKOUT_ROOT, ".jax_cache")
+        assert os.path.isfile(os.path.join(compile_cache.CHECKOUT_ROOT,
+                                           "pyproject.toml"))
+        assert jax.config.jax_compilation_cache_dir == d
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_chip_smoke_refuses_cpu():
+    """chip_smoke.py on anything but a TPU exits nonzero, names the
+    device it found, and prints no result line."""
+    import os
+    import subprocess
+    import sys
+    from repro.launch.compile_cache import CHECKOUT_ROOT
+    out = subprocess.run(
+        [sys.executable, os.path.join(CHECKOUT_ROOT, "chip_smoke.py")],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=300)
+    assert out.returncode != 0
+    assert "cpu" in out.stderr and "needs a TPU" in out.stderr
+    assert '"ok"' not in out.stdout

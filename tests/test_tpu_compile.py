@@ -1,0 +1,130 @@
+"""Ahead-of-time compiles of the ⊞-MAC kernels for a TPU v5e.
+
+Nothing runs: each test lowers a kernel with ``interpret=False`` for one
+chip of a described ``v5e:2x2`` topology and compiles it, which raises
+what the chip's compiler would raise (unsupported primitives, blocks that
+break the (8, 128) tiling rule, VMEM overflow).  Results and times come
+only from a run on the chip (``chip_smoke.py``).
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and pytest-xdist workers
+each import every test file.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import (DELTA_BITSHIFT, DELTA_DEFAULT, DELTA_SOFTMAX, LNS16,
+                        LogSGDConfig, UpdateEpilogue, beta_code)
+from repro.kernels.lns_boxsum.lns_boxsum import lns_boxsum_pallas
+from repro.kernels.lns_matmul.lns_matmul import (
+    FwdEpilogue, lns_matmul_dw_partials_pallas, lns_matmul_dw_pallas,
+    lns_matmul_dw_update_pallas, lns_matmul_dx_pallas,
+    lns_matmul_fused_pallas, lns_matmul_pallas)
+from repro.kernels.lns_matmul.update import lns_fused_update_pallas
+
+#: Every Δ kind the compiled lane takes.  lut640 unrolls ~400 breakpoints
+#: of compare-select per ⊞ (lut20: 20), the largest kernel body there is.
+SPECS = {"lut20": DELTA_DEFAULT, "lut640": DELTA_SOFTMAX,
+         "bitshift": DELTA_BITSHIFT}
+
+#: (M, K, N): the paper MLP's hidden layer at batch 64, and one qwen3-1.7b
+#: MLP projection (d_model 2048 → d_ff 6144) at 256 tokens.
+SHAPES = {"mlp": (64, 784, 100), "qwen3": (256, 2048, 6144)}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler installed, library held, ...
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described chip, with the persistent compile cache off: a
+    compile for a device that is not attached is written there but can
+    never be read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _kernel_case(kind, spec, m, k, n):
+    """(fn of int32 planes, their shapes) for one kernel launch."""
+    kw = dict(fmt=LNS16, spec=spec, interpret=False)
+    sgd = UpdateEpilogue.from_sgd(
+        LogSGDConfig(lr=0.01, momentum=0.9, weight_decay=0.01), LNS16)
+    if kind == "fwd":
+        return (lambda xc, xs, wc, ws: lns_matmul_pallas(xc, xs, wc, ws, **kw),
+                [(m, k), (m, k), (k, n), (k, n)])
+    if kind == "dx":
+        return (lambda dc, ds, wc, ws: lns_matmul_dx_pallas(dc, ds, wc, ws,
+                                                            **kw),
+                [(m, n), (m, n), (k, n), (k, n)])
+    if kind == "dw":
+        return (lambda xc, xs, dc, ds: lns_matmul_dw_pallas(xc, xs, dc, ds,
+                                                            **kw),
+                [(m, k), (m, k), (m, n), (m, n)])
+    if kind == "fused_fwd":
+        ep = FwdEpilogue(bias=True, llrelu_beta=beta_code(0.01, LNS16),
+                         emit_z_sign=True)
+        return (lambda xc, xs, wc, ws, bc, bs: lns_matmul_fused_pallas(
+                    xc, xs, wc, ws, epilogue=ep, bias_code=bc, bias_sign=bs,
+                    **kw),
+                [(m, k), (m, k), (k, n), (k, n), (n,), (n,)])
+    if kind == "dw_update":
+        return (lambda xc, xs, dc, ds, wc, ws, mc, ms:
+                lns_matmul_dw_update_pallas(
+                    xc, xs, dc, ds, w_code=wc, w_sign=ws, m_code=mc,
+                    m_sign=ms, epilogue=sgd, **kw),
+                [(m, k), (m, k), (m, n), (m, n)] + [(k, n)] * 4)
+    if kind == "dw_partials":
+        return (lambda xc, xs, dc, ds: lns_matmul_dw_partials_pallas(
+                    xc, xs, dc, ds, num_segments=4, **kw),
+                [(m, k), (m, k), (m, n), (m, n)])
+    if kind == "boxsum":
+        # The DP combine's fold: every weight entry over 4 segment slots.
+        return (lambda c, s: lns_boxsum_pallas(c, s, **kw),
+                [(k * n, 4)] * 2)
+    if kind == "fused_update":
+        return (lambda wc, ws, gc, gs, mc, ms: lns_fused_update_pallas(
+                    wc, ws, gc, gs, m_code=mc, m_sign=ms,
+                    epilogue=sgd, **kw),
+                [(k, n)] * 6)
+    raise ValueError(kind)
+
+
+KINDS = ("fwd", "dx", "dw", "fused_fwd", "dw_update", "dw_partials",
+         "boxsum", "fused_update")
+
+
+@pytest.mark.parametrize("shape", list(SHAPES))
+@pytest.mark.parametrize("spec", list(SPECS))
+@pytest.mark.parametrize("kind", KINDS)
+def test_kernel_compiles_for_v5e(one_chip, kind, spec, shape):
+    fn, shapes = _kernel_case(kind, SPECS[spec], *SHAPES[shape])
+    args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+            for s in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_dw_partials_one_sample_segments_compile(one_chip):
+    """grad_segments == batch: one-row segments are padded per segment
+    with the zero code to the chip's 8-row tile."""
+    fn = functools.partial(lns_matmul_dw_partials_pallas, num_segments=64,
+                           fmt=LNS16, spec=DELTA_DEFAULT, interpret=False)
+    args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+            for s in [(64, 784), (64, 784), (64, 100), (64, 100)]]
+    jax.jit(fn).lower(*args).compile()
