@@ -1,0 +1,6 @@
+"""lm.mfu: The whole step's model operations per second over the chips' bf16 peak, in %."""
+import readers
+
+
+def read(ctx):
+    return readers.mfu(ctx)
