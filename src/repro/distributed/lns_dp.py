@@ -41,7 +41,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..core.plan import NumericsPlan
 from ..core.spec import ReduceSpec
 from ..obs import metrics as _obs
-from ..obs.trace import phase_scope
+from ..obs.trace import host_span, phase_scope
 from ..resil import inject as _inj
 from .lns_reduce import (combine_partials, deterministic_boxplus_allreduce,
                          float_psum_allreduce)
@@ -279,6 +279,7 @@ class LNSDataParallelMLP:
             return new_params, loss
         return new_params, momentum, loss
 
+    @host_span("repro.train_step")
     @functools.partial(jax.jit, static_argnums=0)
     def train_step(self, params, xb, yb, momentum=None):
         """Plain DP step — no collector, telemetry gates statically off,

@@ -40,6 +40,7 @@ from ..core.arithmetic import boxsum_partials
 from ..core.delta import DeltaEngine
 from ..core.lns import LNSArray, decode, encode
 from ..core.spec import REDUCE_MODES, REDUCE_SCHEDULES  # noqa: F401
+from ..obs.trace import phase_scope
 # (re-exported: the valid values live in core.spec, next to ReduceSpec —
 # the serializable descriptor these semantics are selected by.)
 
@@ -130,9 +131,12 @@ def deterministic_boxplus_allreduce(p: LNSArray, axis_name: str,
     tiles the kernel combine (``"auto"`` = autotuned fold shapes) and
     never changes the combined codes.
     """
-    return combine_partials(gather_partials(p, axis_name), eng,
-                            schedule=schedule, use_kernel=use_kernel,
-                            interpret=interpret, blocks=blocks)
+    with phase_scope("reduce/gather"):
+        parts = gather_partials(p, axis_name)
+    with phase_scope("reduce/fold"):
+        return combine_partials(parts, eng, schedule=schedule,
+                                use_kernel=use_kernel, interpret=interpret,
+                                blocks=blocks)
 
 
 def float_psum_allreduce(p: LNSArray, axis_name: str,

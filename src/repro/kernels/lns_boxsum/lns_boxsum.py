@@ -85,5 +85,6 @@ def lns_boxsum_pallas(codes, signs, *, fmt: LNSFormat, spec: DeltaSpec,
         scratch_shapes=[pltpu.VMEM((1, block_m), jnp.int32),
                         pltpu.VMEM((1, block_m), jnp.int32)],
         interpret=interpret,
+        metadata={"kind": "boxsum"},
     )(codes, signs)
     return out_c[0, :m], out_s[0, :m]

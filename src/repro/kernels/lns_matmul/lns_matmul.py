@@ -427,9 +427,9 @@ def _pad2(code, sign, pad_r, pad_c, zero):
     return code, sign
 
 
-def _launch_mac(at_code, at_sign, b_code, b_sign, *, fmt: LNSFormat,
-                spec: DeltaSpec, block_r: int, block_c: int, block_ct: int,
-                interpret: bool, partial_flush: bool = False,
+def _launch_mac(at_code, at_sign, b_code, b_sign, *, kind: str,
+                fmt: LNSFormat, spec: DeltaSpec, block_r: int, block_c: int,
+                block_ct: int, interpret: bool, partial_flush: bool = False,
                 fwd_epilogue: Optional[FwdEpilogue] = None,
                 bias_code=None, bias_sign=None,
                 update_epilogue: Optional[UpdateEpilogue] = None,
@@ -451,6 +451,12 @@ def _launch_mac(at_code, at_sign, b_code, b_sign, *, fmt: LNSFormat,
     planes) select the flush-time epilogue; outputs grow accordingly
     (z_sign plane / updated-momentum planes) and the return is a tuple of
     all cropped output planes in kernel order.
+
+    ``kind`` names the launch in the profiler's trace: the custom call
+    carries ``kernel_metadata`` with it and the launch's extents, output
+    rows ``r``, columns ``c`` and contraction depth ``ct`` as given and
+    ``rp``/``cp``/``ctp`` as padded for the grid, so a trace reader can
+    tell forward from backward and useful ⊞-MACs from padding.
     """
     if partial_flush and (fwd_epilogue is not None
                           or update_epilogue is not None):
@@ -542,6 +548,8 @@ def _launch_mac(at_code, at_sign, b_code, b_sign, *, fmt: LNSFormat,
             pltpu.VMEM((block_r, block_c), jnp.int32),
         ],
         interpret=interpret,
+        metadata={"kind": kind, **{k: str(v) for k, v in dict(
+            r=r, c=c, ct=ct, rp=rp, cp=cp, ctp=ctp).items()}},
     )(at_code, at_sign, b_code, b_sign, *extra_in)
     if partial_flush:
         return tuple(o[:, :r, :c] for o in outs)
@@ -553,8 +561,8 @@ def lns_matmul_pallas(x_code, x_sign, w_code, w_sign, *,
                       block_m: int = 128, block_n: int = 128,
                       block_k: int = 128, interpret: Optional[bool] = None):
     """Forward: x (M, K) ⊞-MAC w (K, N) → (M, N), sequential over K."""
-    return _launch_mac(x_code.T, x_sign.T, w_code, w_sign, fmt=fmt,
-                       spec=spec, block_r=block_m, block_c=block_n,
+    return _launch_mac(x_code.T, x_sign.T, w_code, w_sign, kind="fwd",
+                       fmt=fmt, spec=spec, block_r=block_m, block_c=block_n,
                        block_ct=block_k,
                        interpret=resolve_interpret(interpret))
 
@@ -569,8 +577,8 @@ def lns_matmul_dx_pallas(dy_code, dy_sign, w_code, w_sign, *,
     The contraction walks N sequentially (ascending), matching
     ``lns_matmul(dY, Wᵀ)`` with ``order="sequential"`` bit-exactly.
     """
-    return _launch_mac(dy_code.T, dy_sign.T, w_code.T, w_sign.T, fmt=fmt,
-                       spec=spec, block_r=block_m, block_c=block_k,
+    return _launch_mac(dy_code.T, dy_sign.T, w_code.T, w_sign.T, kind="dx",
+                       fmt=fmt, spec=spec, block_r=block_m, block_c=block_k,
                        block_ct=block_n,
                        interpret=resolve_interpret(interpret))
 
@@ -587,8 +595,9 @@ def lns_matmul_dw_pallas(x_code, x_sign, dy_code, dy_sign, *,
     sequentially (ascending), matching ``lns_matmul(Xᵀ, dY)`` with
     ``order="sequential"`` bit-exactly.
     """
-    return _launch_mac(x_code, x_sign, dy_code, dy_sign, fmt=fmt, spec=spec,
-                       block_r=block_k, block_c=block_n, block_ct=block_m,
+    return _launch_mac(x_code, x_sign, dy_code, dy_sign, kind="dw",
+                       fmt=fmt, spec=spec, block_r=block_k, block_c=block_n,
+                       block_ct=block_m,
                        interpret=resolve_interpret(interpret))
 
 
@@ -634,8 +643,8 @@ def lns_matmul_dw_partials_pallas(x_code, x_sign, dy_code, dy_sign, *,
         x_sign = _pad_segments(x_sign, num_segments, pad, 0)
         dy_code = _pad_segments(dy_code, num_segments, pad, zc)
         dy_sign = _pad_segments(dy_sign, num_segments, pad, 0)
-    return _launch_mac(x_code, x_sign, dy_code, dy_sign, fmt=fmt, spec=spec,
-                       block_r=block_k, block_c=block_n,
+    return _launch_mac(x_code, x_sign, dy_code, dy_sign, kind="dw_partials",
+                       fmt=fmt, spec=spec, block_r=block_k, block_c=block_n,
                        block_ct=seg + pad,
                        interpret=resolve_interpret(interpret),
                        partial_flush=True)
@@ -658,8 +667,8 @@ def lns_matmul_fused_pallas(x_code, x_sign, w_code, w_sign, *,
     format's grid.  Bit-exact against ``ref.lns_matmul_fused_ref``, the
     unfused composition.
     """
-    return _launch_mac(x_code.T, x_sign.T, w_code, w_sign, fmt=fmt,
-                       spec=spec, block_r=block_m, block_c=block_n,
+    return _launch_mac(x_code.T, x_sign.T, w_code, w_sign, kind="fused_fwd",
+                       fmt=fmt, spec=spec, block_r=block_m, block_c=block_n,
                        block_ct=block_k,
                        interpret=resolve_interpret(interpret),
                        fwd_epilogue=epilogue, bias_code=bias_code,
@@ -682,8 +691,9 @@ def lns_matmul_dw_update_pallas(x_code, x_sign, dy_code, dy_sign, *,
     epilogue has momentum) — the gradient never round-trips through
     memory.  Bit-exact against ``matmul_dw`` + ``apply_update_codes``.
     """
-    return _launch_mac(x_code, x_sign, dy_code, dy_sign, fmt=fmt, spec=spec,
-                       block_r=block_k, block_c=block_n, block_ct=block_m,
+    return _launch_mac(x_code, x_sign, dy_code, dy_sign, kind="dw_update",
+                       fmt=fmt, spec=spec, block_r=block_k, block_c=block_n,
+                       block_ct=block_m,
                        interpret=resolve_interpret(interpret),
                        update_epilogue=epilogue, w_code=w_code,
                        w_sign=w_sign, m_code=m_code, m_sign=m_sign)

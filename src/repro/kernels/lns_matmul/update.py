@@ -111,5 +111,6 @@ def lns_fused_update_pallas(w_code, w_sign, g_code, g_sign, *,
         out_shape=[jax.ShapeDtypeStruct((rows_p, LANE), jnp.int32)
                    for _ in range(n_out)],
         interpret=interpret,
+        metadata={"kind": "fused_update"},
     )(*ins)
     return tuple(o.reshape(-1)[:n].reshape(shape) for o in outs)

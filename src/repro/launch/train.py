@@ -74,7 +74,7 @@ def main(argv=None):
                     "run without --metrics")
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
                     help="dump a jax.profiler trace of the training loop "
-                    "there (also honours $REPRO_TRACE_DIR)")
+                    "there; each iteration is a 'repro.train' step in it")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--allow-numerics-mismatch", action="store_true",
                     help="restore a checkpoint whose stamped numerics "
@@ -201,28 +201,30 @@ def main(argv=None):
     losses = []
     with maybe_profile(args.profile_dir):
         for step in range(start, args.steps):
-            batch = {k: jnp.asarray(v)
-                     for k, v in ds.batch_at(step).items()}
-            if batch_sharding is not None:
-                batch = jax.device_put(batch, batch_sharding)
-            with timer.span("train.step"):
+            with jax.profiler.StepTraceAnnotation("repro.train",
+                                                  step_num=step):
+                batch = {k: jnp.asarray(v)
+                         for k, v in ds.batch_at(step).items()}
+                if batch_sharding is not None:
+                    batch = jax.device_put(batch, batch_sharding)
+                with timer.span("train.step"):
+                    if sink is not None:
+                        state, metrics, taps = step_fn(state, batch)
+                    else:
+                        state, metrics = step_fn(state, batch)
+                    losses.append(float(metrics["loss"]))  # blocks on device
                 if sink is not None:
-                    state, metrics, taps = step_fn(state, batch)
-                else:
-                    state, metrics = step_fn(state, batch)
-                losses.append(float(metrics["loss"]))  # blocks on device
-            if sink is not None:
-                registry.merge_numerics_taps(
-                    jax.device_get(taps), lanes=lanes)
-                sink.write(registry.rows(reset=True), step=step + 1,
-                           loss=losses[-1],
-                           step_time_ms=timer.last("train.step"))
-            if (step + 1) % args.log_every == 0 or step == args.steps - 1:
-                dt = (time.time() - t0) / max(len(losses), 1)
-                print(f"[train] step {step + 1}/{args.steps} "
-                      f"loss {losses[-1]:.4f} ({dt * 1e3:.0f} ms/step)")
-            if mgr is not None and (step + 1) % args.ckpt_every == 0:
-                mgr.save(step + 1, state, blocking=False)
+                    registry.merge_numerics_taps(
+                        jax.device_get(taps), lanes=lanes)
+                    sink.write(registry.rows(reset=True), step=step + 1,
+                               loss=losses[-1],
+                               step_time_ms=timer.last("train.step"))
+                if (step + 1) % args.log_every == 0 or step == args.steps - 1:
+                    dt = (time.time() - t0) / max(len(losses), 1)
+                    print(f"[train] step {step + 1}/{args.steps} "
+                          f"loss {losses[-1]:.4f} ({dt * 1e3:.0f} ms/step)")
+                if mgr is not None and (step + 1) % args.ckpt_every == 0:
+                    mgr.save(step + 1, state, blocking=False)
     if mgr is not None:
         mgr.save(args.steps, state, blocking=True)
     if sink is not None:

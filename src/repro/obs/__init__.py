@@ -23,10 +23,9 @@ from .registry import MetricsRegistry
 from .sink import JsonlSink, read_jsonl, read_jsonl_tolerant
 from .trace import (
     StepTimer,
-    TRACE_DIR_ENV,
+    host_span,
     maybe_profile,
     phase_scope,
-    profiler_session,
 )
 
 __all__ = [
@@ -49,8 +48,7 @@ __all__ = [
     "read_jsonl",
     "read_jsonl_tolerant",
     "StepTimer",
-    "TRACE_DIR_ENV",
+    "host_span",
     "maybe_profile",
     "phase_scope",
-    "profiler_session",
 ]

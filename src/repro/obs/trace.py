@@ -1,30 +1,50 @@
-"""Step tracing: profiler named scopes + host-side monotonic timers.
+"""Step tracing: what the program names in a profiler trace, and host timers.
 
-Two complementary clocks:
+What is tagged, and where:
 
-* ``phase_scope(name)`` — a ``jax.named_scope`` wrapper, safe inside
-  jitted bodies: it annotates HLO ops for the profiler UI and changes no
-  results.  The train/serve steps tag their phases (``fwd`` / ``dx`` /
-  ``dw`` / ``reduce`` / ``update``, ``prefill`` / ``decode``) with it.
-* ``StepTimer`` — host-side ``perf_counter`` wall times around dispatch
-  boundaries (the number a user actually waits for).  Callers must
-  ``block_until_ready`` (or read a host value) before ``record`` if they
-  want device time included; the launch CLI does.
+* **Kernel launches** — every ``pl.pallas_call`` passes
+  ``metadata={"kind": ...}``, which lands in the custom call's
+  ``frontend_attributes={kernel_metadata={...}}`` and so in the name of
+  the launch's event on the device's ``XLA Ops`` line.  ``kind`` is one of
+  ``fwd`` / ``dx`` / ``dw`` / ``fused_fwd`` / ``dw_update`` /
+  ``dw_partials`` (``kernels/lns_matmul/lns_matmul.py: _launch_mac``,
+  which adds the launch's logical extents ``r`` / ``c`` / ``ct`` and the
+  padded ones ``rp`` / ``cp`` / ``ctp``), ``fused_update``
+  (``kernels/lns_matmul/update.py``) and ``boxsum``
+  (``kernels/lns_boxsum/lns_boxsum.py``).
+* **Train-step entry points** — :func:`host_span` opens the host span
+  ``repro.train_step`` around the jitted call of
+  ``paper/mlp.py: LNSMLP.train_step`` and
+  ``distributed/lns_dp.py: LNSDataParallelMLP.train_step``: argument
+  handling, the batch's host-to-device copies, output allocation and the
+  launch nest under it on the ``/host:CPU`` plane, on the device trace's
+  clock.
+* **Launch loops** — ``launch/train.py`` and ``paper/training.py:
+  run_experiment`` wrap each iteration in
+  ``StepTraceAnnotation("repro.train", step_num=...)`` (xprof's per-step
+  view), and :class:`StepTimer` spans open a host span of their own name,
+  so its ``perf_counter`` times and the trace mark the same boundaries.
+* **Phases inside a jitted step** — :func:`phase_scope` is a
+  ``jax.named_scope``: ``fwd`` / ``dx`` / ``dw`` / ``update`` in
+  ``paper/mlp.py``, ``reduce`` in ``distributed/lns_dp.py`` with
+  ``reduce/gather`` and ``reduce/fold`` in ``distributed/lns_reduce.py``,
+  ``grad`` / ``update`` in ``train/step.py``.  Named scopes reach the HLO
+  ops' ``op_name`` and xprof's op profile; they are not in the event stats
+  that ``jax.profiler.ProfileData`` reads back, so a trace reader cannot
+  split device time by them.
 
-``profiler_session`` / ``maybe_profile`` wrap ``jax.profiler`` trace
-dumps behind a directory argument or the ``REPRO_TRACE_DIR`` env var.
+None of this changes results.  With no profiler session a host span costs
+a C++ enabled-check and one Python frame.  :func:`maybe_profile` is the
+one switch that records a trace (``launch/train.py --profile-dir``).
 """
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import time
 
 import jax
-
-#: Env var that, when set to a directory, makes ``maybe_profile`` dump a
-#: jax.profiler trace there even without an explicit CLI flag.
-TRACE_DIR_ENV = "REPRO_TRACE_DIR"
 
 
 def phase_scope(name):
@@ -32,8 +52,29 @@ def phase_scope(name):
     return jax.named_scope(name)
 
 
+def host_span(name):
+    """Decorator: run the function inside the host span ``name``.
+
+    Meant for jitted entry points: the span covers the whole dispatch of
+    one call.  The name is fixed when decorating, so an untraced call
+    formats nothing and reads no clock."""
+    annotation = jax.profiler.TraceAnnotation
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def spanned(*args, **kwargs):
+            with annotation(name):
+                return fn(*args, **kwargs)
+        return spanned
+    return wrap
+
+
 class StepTimer:
-    """Named host-side monotonic timers with simple summaries.
+    """Named host-side monotonic timers with simple summaries; each span
+    is also a host span of the same name in a profiler trace.  A span
+    includes device time only if the caller waits for the device inside
+    it (``block_until_ready`` or reading a host value), as the launch CLI
+    does.
 
     >>> t = StepTimer()
     >>> with t.span("train.step"):
@@ -49,11 +90,12 @@ class StepTimer:
 
     @contextlib.contextmanager
     def span(self, name):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.record(name, (time.perf_counter() - t0) * 1e3)
+        with jax.profiler.TraceAnnotation(name):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.record(name, (time.perf_counter() - t0) * 1e3)
 
     def last(self, name):
         s = self._samples.get(name)
@@ -80,23 +122,15 @@ class StepTimer:
 
 
 @contextlib.contextmanager
-def profiler_session(trace_dir):
-    """Dump a jax.profiler trace of the enclosed region to trace_dir."""
-    os.makedirs(trace_dir, exist_ok=True)
-    jax.profiler.start_trace(trace_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
 def maybe_profile(trace_dir=None):
-    """``profiler_session`` if a directory is given via argument or
-    ``$REPRO_TRACE_DIR``; otherwise a no-op context."""
-    trace_dir = trace_dir or os.environ.get(TRACE_DIR_ENV)
+    """Dump a jax.profiler trace of the enclosed region to ``trace_dir``;
+    with no directory, a no-op context."""
     if not trace_dir:
         yield None
         return
-    with profiler_session(trace_dir):
+    os.makedirs(trace_dir, exist_ok=True)
+    jax.profiler.start_trace(trace_dir)
+    try:
         yield trace_dir
+    finally:
+        jax.profiler.stop_trace()

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import metrics as _obs
-from ..obs.trace import phase_scope
+from ..obs.trace import host_span, phase_scope
 from ..resil import inject as _inj
 
 from ..core import (DELTA_BITSHIFT, DELTA_DEFAULT, DELTA_EXACT,
@@ -665,6 +665,7 @@ class LNSMLP:
             return new_p, loss
         return new_p, new_m, loss
 
+    @host_span("repro.train_step")
     @functools.partial(jax.jit, static_argnums=0)
     def train_step(self, params, xb, yb, momentum=None):
         """One step; returns (params, loss), or (params, momentum, loss)

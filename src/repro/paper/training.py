@@ -117,20 +117,22 @@ def run_experiment(backend: str, dataset: str, *, bits: int = 16,
         if max_steps_per_epoch is not None:
             steps = min(steps, max_steps_per_epoch)
         for s in range(steps):
-            sl = order[s * batch_size:(s + 1) * batch_size]
-            if stochastic_round and backend == "fxp":
-                params, loss = model.train_step(
-                    params, x_tr[sl], y_tr[sl],
-                    jax.random.PRNGKey(seed * 1_000_003 + gstep))
-            elif mom is not None:
-                params, mom, loss = model.train_step(params, x_tr[sl],
-                                                     y_tr[sl], mom)
-            else:
-                params, loss = model.train_step(params, x_tr[sl], y_tr[sl])
-            losses.append(loss)
-            gstep += 1
-            if hasattr(model, "apply_decay") and wd and (s + 1) % 16 == 0:
-                params = model.apply_decay(params, 16)
+            with jax.profiler.StepTraceAnnotation("repro.train",
+                                                  step_num=gstep):
+                sl = order[s * batch_size:(s + 1) * batch_size]
+                if stochastic_round and backend == "fxp":
+                    params, loss = model.train_step(
+                        params, x_tr[sl], y_tr[sl],
+                        jax.random.PRNGKey(seed * 1_000_003 + gstep))
+                elif mom is not None:
+                    params, mom, loss = model.train_step(params, x_tr[sl],
+                                                         y_tr[sl], mom)
+                else:
+                    params, loss = model.train_step(params, x_tr[sl], y_tr[sl])
+                losses.append(loss)
+                gstep += 1
+                if hasattr(model, "apply_decay") and wd and (s + 1) % 16 == 0:
+                    params = model.apply_decay(params, 16)
         curve.append(evaluate(model, params, x_val, y_val))
     test = evaluate(model, params, x_te, y_te)
     inner = getattr(model, "inner", model)

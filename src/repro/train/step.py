@@ -19,6 +19,7 @@ from ..core.plan import NumericsPlan
 from ..core.spec import NumericsSpec
 from ..nn import Runtime, loss_fn
 from ..nn.config import ModelConfig
+from ..obs.trace import phase_scope
 from ..optim import fake_compress_roundtrip, make_optimizer
 from ..optim.optimizers import OptimizerConfig
 
@@ -170,20 +171,24 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig,
             zero = (jnp.zeros((), jnp.float32),
                     jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
                                  params))
-            (loss, grads), _ = jax.lax.scan(acc_fn, zero, stacked)
+            with phase_scope("grad"):
+                (loss, grads), _ = jax.lax.scan(acc_fn, zero, stacked)
             inv = 1.0 / tc.microbatches
             loss = loss * inv
             grads = jax.tree.map(lambda g: g * inv, grads)
         else:
-            loss, grads = grads_of(params, batch)
+            # One value_and_grad: forward and backward share this scope.
+            with phase_scope("grad"):
+                loss, grads = grads_of(params, batch)
         metrics = {"loss": loss}
         if tc.grad_clip:
             grads, gn = _clip(grads, tc.grad_clip)
             metrics["grad_norm"] = gn
         if tc.compress_grads:
             grads, res = fake_compress_roundtrip(grads, state["residual"])
-        new_params, new_opt = opt_update(params, grads, state["opt"],
-                                         state["step"])
+        with phase_scope("update"):
+            new_params, new_opt = opt_update(params, grads, state["opt"],
+                                             state["step"])
         if tc.nan_guard:
             # A nonfinite loss or gradient poisons params/opt state
             # irreversibly (momentum carries the NaN forward); drop the

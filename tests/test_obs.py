@@ -147,6 +147,51 @@ def test_plain_step_graph_has_no_telemetry():
     assert _obs._COLLECTORS == [] and _obs._SCOPES == []
 
 
+# ------------------------------------------------------ profiler spans ---
+def _host_events(trace_dir, name):
+    import glob
+    from jax.profiler import ProfileData
+    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(files) == 1, files
+    pd = ProfileData.from_file(files[0])
+    return [ev for plane in pd.planes if plane.name.startswith("/host")
+            for line in plane.lines for ev in line.events
+            if ev.name == name]
+
+
+def test_train_step_opens_one_host_span_per_call(tmp_path):
+    """Each call of the train-step entry point is one ``repro.train_step``
+    host span in a profiler trace, and tracing changes no result."""
+    spec = "lns16-train-pallas"
+    mlp = _mlp(spec)
+    p_off, m_off, l_off, _ = _train(mlp, with_metrics=False)
+    with jax.profiler.trace(str(tmp_path)):
+        p_on, m_on, l_on, _ = _train(_mlp(spec), with_metrics=False)
+    assert len(_host_events(str(tmp_path), "repro.train_step")) == 3
+    _assert_codes_equal(p_off, p_on)
+    _assert_codes_equal(m_off, m_on)
+    assert l_off == l_on
+
+
+def test_step_timer_spans_are_host_spans_in_the_profile(tmp_path):
+    """``StepTimer`` times and the trace mark the same boundaries, and
+    ``maybe_profile`` is the one switch that records a trace."""
+    from repro.obs import maybe_profile
+    timer = StepTimer()
+    with maybe_profile(None) as off:
+        with timer.span("train.step"):
+            pass
+    assert off is None
+    with maybe_profile(str(tmp_path / "prof")) as on:
+        for _ in range(2):
+            with timer.span("train.step"):
+                pass
+    assert on == str(tmp_path / "prof")
+    assert len(timer.samples("train.step")) == 3
+    assert len(_host_events(on, "train.step")) == 2
+
+
 # ------------------------------------------------------- lanes / plan -----
 def test_per_layer_interpret_override_resolves_lane():
     """Satellite: per-layer `interpret` rules resolve to distinct lanes,

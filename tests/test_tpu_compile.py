@@ -11,6 +11,8 @@ one process at a time may load the TPU library, and pytest-xdist workers
 each import every test file.
 """
 import functools
+import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -128,3 +130,37 @@ def test_dw_partials_one_sample_segments_compile(one_chip):
     args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
             for s in [(64, 784), (64, 784), (64, 100), (64, 100)]]
     jax.jit(fn).lower(*args).compile()
+
+
+#: What each launch at the MLP shape (M, K, N) = (64, 784, 100) records:
+#: output rows, columns and contraction depth as given, then as padded
+#: for the grid (rows and columns to 128-wide blocks; the contraction to
+#: 128-deep blocks, or to the 8-row tile when it is shorter).
+EXTENTS = {
+    "fwd": (64, 100, 784, 128, 128, 896),
+    "dx": (64, 784, 100, 128, 896, 104),
+    "dw": (784, 100, 64, 896, 128, 64),
+    "fused_fwd": (64, 100, 784, 128, 128, 896),
+    "dw_update": (784, 100, 64, 896, 128, 64),
+    "dw_partials": (784, 100, 64, 896, 128, 64),
+}
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_kernel_launch_carries_its_kind_and_extents(one_chip, kind):
+    """The compiled custom call names its kind (and, for a ⊞-MAC, its
+    extents) in ``kernel_metadata``, which a profiler trace shows in the
+    launch's event name.  (The get-tuple-elements that unpack its
+    outputs carry a copy; they run nothing on the device.)"""
+    fn, shapes = _kernel_case(kind, SPECS["lut20"], *SHAPES["mlp"])
+    args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+            for s in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    found = [json.loads(m) for m in re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*'
+        r"kernel_metadata=(\{[^{}]*\})", text)]
+    want = {"kind": kind}
+    if kind in EXTENTS:
+        want.update(zip(("r", "c", "ct", "rp", "cp", "ctp"),
+                        map(str, EXTENTS[kind])))
+    assert found == [want]
