@@ -12,13 +12,14 @@ What is tagged, and where:
   padded ones ``rp`` / ``cp`` / ``ctp``), ``fused_update``
   (``kernels/lns_matmul/update.py``) and ``boxsum``
   (``kernels/lns_boxsum/lns_boxsum.py``).
-* **Train-step entry points** — :func:`host_span` opens the host span
-  ``repro.train_step`` around the jitted call of
-  ``paper/mlp.py: LNSMLP.train_step`` and
-  ``distributed/lns_dp.py: LNSDataParallelMLP.train_step``: argument
-  handling, the batch's host-to-device copies, output allocation and the
-  launch nest under it on the ``/host:CPU`` plane, on the device trace's
-  clock.
+* **Train-step entry points** — ``paper/mlp.py: LNSMLP.train_step`` and
+  ``distributed/lns_dp.py: LNSDataParallelMLP.train_step`` (through
+  :func:`host_span`) open the host span ``repro.train_step`` around their
+  jitted call: argument handling, the batch's host-to-device copies,
+  output allocation and the launch nest under it on the ``/host:CPU``
+  plane, on the device trace's clock.  ``LNSMLP``'s span carries the
+  argument ``donated`` (1 where the step reused the buffers of the state
+  handed back to it).
 * **Launch loops** — ``launch/train.py`` and ``paper/training.py:
   run_experiment`` wrap each iteration in
   ``StepTraceAnnotation("repro.train", step_num=...)`` (xprof's per-step

@@ -32,6 +32,7 @@ import contextlib
 import dataclasses
 import functools
 import warnings
+import weakref
 from typing import Any
 
 import jax
@@ -39,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import metrics as _obs
-from ..obs.trace import host_span, phase_scope
+from ..obs.trace import phase_scope
 from ..resil import inject as _inj
 
 from ..core import (DELTA_BITSHIFT, DELTA_DEFAULT, DELTA_EXACT,
@@ -414,6 +415,12 @@ class LNSMLP:
         # train_step_metrics) — see repro.obs.metrics.
         self.metrics_levels = {p: self.runtimes[p].spec.metrics
                                for p in LAYER_PATHS}
+        # train_step's hand-back state: weak references to the leaves its
+        # last call returned (it keeps none alive), and how many of its
+        # calls donated them.
+        self._returned = ()
+        self.calls = 0
+        self.donated_calls = 0
 
     def lanes(self) -> dict:
         """Layer path → resolved execution lane, for metrics rows."""
@@ -665,8 +672,6 @@ class LNSMLP:
             return new_p, loss
         return new_p, new_m, loss
 
-    @host_span("repro.train_step")
-    @functools.partial(jax.jit, static_argnums=0)
     def train_step(self, params, xb, yb, momentum=None):
         """One step; returns (params, loss), or (params, momentum, loss)
         when a momentum pytree is passed (``cfg.momentum > 0``).
@@ -682,7 +687,44 @@ class LNSMLP:
         No collector is active here, so every telemetry gate is
         statically false: the jitted graph has no extra outputs and is
         the same graph as before the obs subsystem existed.
+
+        Passing back the params (and momentum) this model returned on its
+        last call reuses their buffers: the step donates them, and the
+        arrays passed are deleted.  Copy them first to keep them.  Any
+        other params (the ``init`` ones, a copy, a dict with one leaf
+        swapped) are kept, and the step allocates new outputs.  Both
+        graphs trace :meth:`_step_impl`, so the results are the same bit
+        for bit.  The call is one host span ``repro.train_step`` with the
+        argument ``donated`` 0 or 1.
         """
+        with jax.profiler.TraceAnnotation("repro.train_step") as span:
+            donate = self._handed_back(params, momentum)
+            span.set_metadata(donated=int(donate))
+            step = self._train_step_donate if donate \
+                else self._train_step_keep
+            out = step(params, xb, yb, momentum)
+            self._returned = tuple(
+                weakref.ref(a) for a in jax.tree_util.tree_leaves(out[:-1]))
+        self.calls += 1
+        self.donated_calls += donate
+        return out
+
+    def _handed_back(self, params, momentum) -> bool:
+        """Is every leaf of ``params`` and ``momentum`` the live array, in
+        its place, that the last :meth:`train_step` returned?"""
+        leaves = jax.tree_util.tree_leaves((params, momentum))
+        returned = self._returned
+        return len(leaves) == len(returned) and all(
+            r() is a and not a.is_deleted()
+            for r, a in zip(returned, leaves))
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def _train_step_keep(self, params, xb, yb, momentum=None):
+        return self._step_impl(params, xb, yb, momentum)
+
+    @functools.partial(jax.jit, static_argnums=0,
+                       donate_argnames=("params", "momentum"))
+    def _train_step_donate(self, params, xb, yb, momentum=None):
         return self._step_impl(params, xb, yb, momentum)
 
     @functools.partial(jax.jit, static_argnums=0)

@@ -164,3 +164,28 @@ def test_kernel_launch_carries_its_kind_and_extents(one_chip, kind):
         want.update(zip(("r", "c", "ct", "rp", "cp", "ctp"),
                         map(str, EXTENTS[kind])))
     assert found == [want]
+
+
+def test_paper_mlp_donating_step_aliases_its_params(one_chip):
+    """The paper MLP's donating train step (784-100-10, batch 5) compiles
+    for the chip with each of its 8 parameter planes written in place of
+    the one it was given; without the alias the runtime would allocate
+    new output buffers all the same.  The keeping step aliases none."""
+    from repro.paper.mlp import LNSMLP, MLPConfig
+    mlp = LNSMLP(MLPConfig(lr=0.01, weight_decay=0.01,
+                           spec="lns16-train-pallas,interpret=off"))
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(mlp.init, jax.random.PRNGKey(0)))
+    xb = jax.ShapeDtypeStruct((5, 784), jnp.float32, sharding=one_chip)
+    yb = jax.ShapeDtypeStruct((5,), jnp.int32, sharding=one_chip)
+
+    def aliases(step):
+        text = step.lower(mlp, params, xb, yb).compile().as_text()
+        return {(int(o), int(i)) for o, i in re.findall(
+            r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)", text)}
+
+    n = len(jax.tree_util.tree_leaves(params))
+    assert n == 8
+    assert aliases(LNSMLP._train_step_donate) == {(i, i) for i in range(n)}
+    assert aliases(LNSMLP._train_step_keep) == set()
