@@ -9,7 +9,8 @@ program and for what the check must catch, in one process on the chip.
 in place of the 16-bit one the configuration states; ``half_batch``,
 ``unchanged`` and ``no_exchange`` the faults a training cell can have
 (``no_exchange`` planted in the reference put in the program's place).
-Each run prints one JSON line with its seed, variant and numbers.  The
+Each run prints one JSON line with its seed, variant and numbers, and
+both sides' losses and per-leaf norms (``sides``).  The
 limits in ``bench/workloads/<cell>.json`` are set between the largest
 reading of sound runs and the smallest of the control and the faults.
 The benchmark's own runs never run this.
@@ -60,7 +61,7 @@ def main(argv=None) -> int:
                 "cell": args.workload, "variant": variant, "seed": seed,
                 "chips": cell["chips"], "correct": res["correct"],
                 "numbers": {k: c["value"] for k, c in res["checks"].items()},
-                "losses": res["sides"]["program"]["losses"],
+                "sides": res["sides"],
                 "seconds": time.perf_counter() - t}), flush=True)
     return 0
 

@@ -52,3 +52,13 @@ def test_names_and_files_keep_the_contract():
     for w in BENCHMARK["workloads"]:
         assert len(w["why"]) <= 200
     assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 65536
+
+
+def test_each_cell_reports_one_end_to_end_metric_besides_setup():
+    """``setup_s`` everywhere, and one rate per cell: the metric a cell's
+    per-layer metrics move."""
+    for w in BENCHMARK["workloads"]:
+        e2e = [m["name"] for m in BENCHMARK["end_to_end"]
+               if w["name"] in m.get("workloads", [w["name"]])]
+        assert "setup_s" in e2e, w["name"]
+        assert len(e2e) == 2, (w["name"], e2e)

@@ -61,6 +61,17 @@ def test_kernel_and_collective_names():
                              'custom-call(), custom_call_target='
                              '"tpu_custom_call"')
     assert not tr.MAC.search("%fusion.36 = s32[400] fusion(s32[20])")
+    # The data-parallel fold's wrapper is named ``_call`` too; its tag
+    # tells it apart (as recorded on four chips).
+    assert not tr.MAC.search(
+        '%_call.8 = (s32[1,78592]{1,0:T(1,128)S(1)}) custom-call(s32[8,'
+        '78592]{1,0:T(8,128)} %bitcast.1), custom_call_target="tpu_custom_'
+        'call", frontend_attributes={kernel_metadata={\n"kind":"boxsum"'
+        '\n}}')
+    assert tr.MAC.search(
+        '%_call.5 = (s32[128,128]) custom-call(), custom_call_target="tpu_'
+        'custom_call", frontend_attributes={kernel_metadata={\n"kind":'
+        '"dx",\n"r":"64"\n}}')
     assert tr.COLLECTIVE.search("%all-gather.3 = s32[4,784,128] "
                                 "all-gather(s32[1,784,128] %x)")
     assert tr.COLLECTIVE.search("%all-gather-start.1 = (s32[1]) "

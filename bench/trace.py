@@ -19,9 +19,12 @@ import re
 #: it (``kernels/lns_matmul/ops.py``): ``_call`` (forward, dX, dW),
 #: ``_call_fused_fwd``, ``_call_dw_update`` and ``_call_dw_partials``; the
 #: event's name is the HLO instruction, ``%_call.300 = (...)
-#: custom-call(...), custom_call_target="tpu_custom_call"``.
+#: custom-call(...), custom_call_target="tpu_custom_call"``.  The fold of
+#: the data-parallel combine (``kernels/lns_boxsum``) is launched from a
+#: wrapper named ``_call`` too; its launch is tagged ``"kind":"boxsum"``
+#: and left out.
 MAC = re.compile(r"^%_call(_fused_fwd|_dw_update|_dw_partials)?\.\d+ = "
-                 r".*tpu_custom_call")
+                 r'(?![\s\S]*"kind":"boxsum").*tpu_custom_call')
 #: Collectives between chips, by their HLO opcodes.
 COLLECTIVE = re.compile(r"^%(all-gather|all-reduce|reduce-scatter|"
                         r"collective-permute|all-to-all)[-.\w]* = ")
