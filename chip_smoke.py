@@ -3,7 +3,7 @@
     python chip_smoke.py              # one chip
     python chip_smoke.py --chips 4    # the deterministic ⊞-allreduce only
 
-One chip runs three phases through the normal entry points:
+One chip runs four phases through the normal entry points:
 
 * ``mlp``: the paper MLP (784-100-10, batch 64) trained ``STEPS`` steps
   by ``repro.paper.run_experiment`` on ``lns16-train-pallas`` and again on
@@ -14,6 +14,12 @@ One chip runs three phases through the normal entry points:
   ``lut640``, ``bitshift``), bit for bit against the emulated sequential
   MAC on 16 rows spread over the output; each kernel's second call is
   timed on the host clock.
+* ``kernels-gmm``: the grouped forward, dX and dW ⊞-MAC kernels at the
+  deepseek-v2-lite expert width (d_model 2048, d_expert 1408, 16 experts,
+  a bound of 6,144 rows with 1,536 routed: groups uneven, one empty, four
+  over a 128-row tile), bit for bit against one plain kernel launch per
+  expert on that expert's rows (lut20); each grouped kernel's second call
+  is timed on the host clock.
 * ``qwen3``: two train steps of qwen3-1.7b at its published widths, depth
   cut to ``LAYERS``, ``BATCH`` x ``SEQ`` tokens, through
   ``repro.train.make_train_step`` on
@@ -181,6 +187,81 @@ def phase_kernels_qwen3(clock):
                   "MAC")
 
 
+#: The kernels-gmm phase: rows of each of 16 held experts (uneven, one
+#: empty, four over a 128-row tile; 1,536 routed of a 6,144-row bound),
+#: and the widths.
+GMM_SIZES = (96, 0, 200, 13, 130, 96, 1, 232, 96, 96, 150, 40, 96, 96, 100,
+             94)
+GMM_ROWS, GMM_D, GMM_DE = 6144, 2048, 1408
+
+
+def phase_kernels_gmm(clock):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.core import LNS16, DELTA_DEFAULT
+    from repro.kernels.lns_matmul.grouped import (lns_gmm_dw_pallas,
+                                                  lns_gmm_dx_pallas,
+                                                  lns_gmm_pallas)
+    from repro.kernels.lns_matmul.lns_matmul import (lns_matmul_dw_pallas,
+                                                     lns_matmul_dx_pallas,
+                                                     lns_matmul_pallas)
+    rng = np.random.default_rng(SEED)
+    kw = dict(fmt=LNS16, spec=DELTA_DEFAULT)
+
+    def enc(*shape):
+        from repro.core import encode
+        a = encode(rng.normal(size=shape).astype(np.float32) * 0.05, LNS16)
+        return a.code, a.sign.astype(jnp.int32)
+
+    g, m, d, de = len(GMM_SIZES), GMM_ROWS, GMM_D, GMM_DE
+    sizes = jnp.asarray(GMM_SIZES, jnp.int32)
+    x, w, dy = enc(m, d), enc(g, d, de), enc(m, de)
+    grouped = {
+        "fwd": (jax.jit(lambda a, b, c, e, s: lns_gmm_pallas(a, b, c, e, s,
+                                                              **kw)),
+                x + w, lambda r, e: lns_matmul_pallas(
+                    x[0][r], x[1][r], w[0][e], w[1][e], **kw)),
+        "dx": (jax.jit(lambda a, b, c, e, s: lns_gmm_dx_pallas(a, b, c, e, s,
+                                                                **kw)),
+               dy + w, lambda r, e: lns_matmul_dx_pallas(
+                   dy[0][r], dy[1][r], w[0][e], w[1][e], **kw)),
+        "dw": (jax.jit(lambda a, b, c, e, s: lns_gmm_dw_pallas(a, b, c, e, s,
+                                                                **kw)),
+               x + dy, lambda r, e: lns_matmul_dw_pallas(
+                   x[0][r], x[1][r], dy[0][r], dy[1][r], **kw)),
+    }
+    ends = np.cumsum(GMM_SIZES)
+    for name, (fn, args, plain) in grouped.items():
+        c0 = clock.seconds
+        got = jax.block_until_ready(fn(*args, sizes))
+        compile_s = clock.seconds - c0
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args, sizes))
+        kernel_s = time.perf_counter() - t0
+        equal = True
+        for e, (lo, hi) in enumerate(zip(ends - GMM_SIZES, ends)):
+            if hi == lo:
+                if name == "dw":
+                    equal &= bool(np.all(np.asarray(got[0][e])
+                                         == LNS16.zero_code))
+                continue
+            want = plain(slice(int(lo), int(hi)), e)
+            have = ((got[0][e], got[1][e]) if name == "dw" else
+                    (got[0][lo:hi], got[1][lo:hi]))
+            equal &= all(np.array_equal(np.asarray(h), np.asarray(v))
+                         for h, v in zip(have, want))
+        if name != "dw":
+            equal &= bool(np.all(np.asarray(got[0][ends[-1]:])
+                                 == LNS16.zero_code))
+        say("kernels-gmm", op=name, groups=g, rows=f"{ends[-1]}/{m}",
+            shape=f"{d}x{de}" if name != "dx" else f"{de}x{d}",
+            grouped_eq_plain=equal, compile_s=round(compile_s, 3),
+            kernel_s=round(kernel_s, 4))
+        check(equal, f"grouped {name} differs from per-expert plain "
+              "launches")
+
+
 def phase_qwen3(clock):
     import jax
     import jax.numpy as jnp
@@ -275,7 +356,8 @@ def main(argv=None) -> int:
     say("device", **device, compile_cache=enable_compile_cache())
     clock = CompileClock()
     phases = ([phase_dp4] if args.chips == 4
-              else [phase_mlp, phase_kernels_qwen3, phase_qwen3])
+              else [phase_mlp, phase_kernels_qwen3, phase_kernels_gmm,
+                    phase_qwen3])
     failed = []
     for phase in phases:
         name = phase.__name__[len("phase_"):]

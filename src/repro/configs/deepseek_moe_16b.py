@@ -7,5 +7,6 @@ CONFIG = ModelConfig(
     n_heads=16, n_kv_heads=16, d_head=128, d_ff=11_264, vocab_size=102_400,
     norm_kind="rmsnorm",
     moe=MoEConfig(n_experts=64, top_k=6, n_shared=2, d_expert=1408,
-                  first_dense_layers=1),
+                  first_dense_layers=1, norm_topk_prob=False,
+                  balance_coef=0.001),
 )

@@ -34,6 +34,7 @@ import dataclasses
 import functools
 from typing import Any, Optional
 
+import jax
 import jax.numpy as jnp
 
 from .delta import (DELTA_BITSHIFT, DELTA_DEFAULT, DELTA_EXACT, DELTA_SOFTMAX,
@@ -659,6 +660,25 @@ class LNSRuntime:
                 out = jnp.matmul(self.q_act(x), self.q_param(w))
         observe(out)
         return out
+
+    def grouped_linear(self, x, w, sizes):
+        """Rows ``x`` (M, K) sorted by group against per-group ``w``
+        (G, K, N), ``sizes`` (G,) rows per group → (M, N); rows past
+        ``sum(sizes)`` give 0.
+
+        On the end-to-end LNS training path the grouped ⊞-MAC kernels
+        run forward and both cotangent products
+        (``kernels/lns_matmul/grouped.py``); every other spec runs the
+        grouped float matmul on :meth:`q_act` / :meth:`q_param` operands.
+        """
+        s = self.spec
+        if s.delta_spec is not None and s.quantize_grads:
+            from ..kernels.lns_matmul import lns_gmm_trainable
+            return lns_gmm_trainable(
+                x, w, sizes, numerics=s, block_m=self.block_m,
+                block_n=self.block_n, block_k=self.block_k)
+        return jax.lax.ragged_dot(self.q_act(x), self.q_param(w),
+                                  sizes.astype(jnp.int32))
 
     def linear_infer(self, x, w):
         """Forward-only :meth:`linear` for serving (decode / prefill).

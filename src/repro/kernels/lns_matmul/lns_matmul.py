@@ -294,17 +294,43 @@ def _apply_update_epilogue(w_c, w_s, m_c, m_s, g_c, g_s,
     return w_c, w_s, m_c, m_s
 
 
-def _mac_kernel(*refs, fmt: LNSFormat, spec: DeltaSpec, n_ct: int, b_ct: int,
-                partial_flush: bool = False,
-                fwd_epilogue: Optional[FwdEpilogue] = None,
-                update_epilogue: Optional[UpdateEpilogue] = None):
-    """Generic sequential ⊞-MAC over one contraction tile.
+def _mac_steps(ac_ref, as_ref, bc_ref, bs_ref, acc, b_ct: int, delta,
+               fmt: LNSFormat):
+    """The sequential ⊞-MAC over one contraction tile, from ``acc``.
 
     Both operands arrive contraction-major: A as a (b_ct, b_r) block, B as
     (b_ct, b_c).  Step ``i`` of the fori_loop reads row ``i`` of each
     through its ref (a dynamic sublane offset, which the chip's compiler
     accepts where a dynamic lane slice is refused), turns A's row into a
     (b_r, 1) column and ⊞-accumulates the (b_r, b_c) outer product.
+    Returns the ``(code, sign)`` accumulator planes.
+    """
+    zero = np.int32(fmt.zero_code)
+    b_r = ac_ref.shape[1]
+
+    def body(i, carry):
+        acc_c, acc_s = carry
+        # Contraction step i of this tile: (b_r, 1) ⊡ (1, b_c).
+        a_c = ac_ref[pl.ds(i, 1), :].reshape(b_r, 1)
+        a_s = as_ref[pl.ds(i, 1), :].reshape(b_r, 1)
+        b_c = bc_ref[pl.ds(i, 1), :]
+        b_s = bs_ref[pl.ds(i, 1), :]
+        pc = a_c + b_c
+        pz = (a_c == zero) | (b_c == zero)
+        pc = jnp.minimum(pc, fmt.code_max)
+        pc = jnp.where(pc < fmt.min_nonzero_code, zero, pc)
+        pc = jnp.where(pz, zero, pc)
+        ps = jnp.where(pz, 0, a_s ^ b_s)
+        return _boxplus_codes(acc_c, acc_s, pc, ps, delta, fmt)
+
+    return jax.lax.fori_loop(0, b_ct, body, acc)
+
+
+def _mac_kernel(*refs, fmt: LNSFormat, spec: DeltaSpec, n_ct: int, b_ct: int,
+                partial_flush: bool = False,
+                fwd_epilogue: Optional[FwdEpilogue] = None,
+                update_epilogue: Optional[UpdateEpilogue] = None):
+    """Generic sequential ⊞-MAC over one contraction tile (:func:`_mac_steps`).
 
     ``partial_flush=True`` turns the kernel into a *segment-partial* MAC:
     the accumulator is re-initialized at every contraction block and each
@@ -368,27 +394,10 @@ def _mac_kernel(*refs, fmt: LNSFormat, spec: DeltaSpec, n_ct: int, b_ct: int,
             accc_ref[...] = jnp.full_like(accc_ref, np.int32(fmt.zero_code))
             accs_ref[...] = jnp.zeros_like(accs_ref)
 
-    zero = np.int32(fmt.zero_code)
     delta = make_delta_fn(spec, fmt)
-    b_r = ac_ref.shape[1]
-
-    def body(i, carry):
-        acc_c, acc_s = carry
-        # Contraction step i of this tile: (b_r, 1) ⊡ (1, b_c).
-        a_c = ac_ref[pl.ds(i, 1), :].reshape(b_r, 1)
-        a_s = as_ref[pl.ds(i, 1), :].reshape(b_r, 1)
-        b_c = bc_ref[pl.ds(i, 1), :]
-        b_s = bs_ref[pl.ds(i, 1), :]
-        pc = a_c + b_c
-        pz = (a_c == zero) | (b_c == zero)
-        pc = jnp.minimum(pc, fmt.code_max)
-        pc = jnp.where(pc < fmt.min_nonzero_code, zero, pc)
-        pc = jnp.where(pz, zero, pc)
-        ps = jnp.where(pz, 0, a_s ^ b_s)
-        return _boxplus_codes(acc_c, acc_s, pc, ps, delta, fmt)
-
-    acc_c, acc_s = jax.lax.fori_loop(
-        0, b_ct, body, (accc_ref[...], accs_ref[...]))
+    acc_c, acc_s = _mac_steps(ac_ref, as_ref, bc_ref, bs_ref,
+                              (accc_ref[...], accs_ref[...]), b_ct, delta,
+                              fmt)
     accc_ref[...] = acc_c
     accs_ref[...] = acc_s
 

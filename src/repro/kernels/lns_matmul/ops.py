@@ -17,10 +17,12 @@ from ...core.delta import DeltaSpec
 from ...core.formats import LNSFormat
 from ...core.lns import LNSArray, LNSMatmulBackend, decode, encode
 from ...core.sgd import UpdateEpilogue
+from .grouped import lns_gmm_dw_pallas, lns_gmm_dx_pallas, lns_gmm_pallas
 from .lns_matmul import (FwdEpilogue, lns_matmul_dw_pallas,
                          lns_matmul_dw_partials_pallas,
                          lns_matmul_dw_update_pallas, lns_matmul_dx_pallas,
                          lns_matmul_fused_pallas, lns_matmul_pallas)
+from .ref import lns_gmm_dw_ref, lns_gmm_dx_ref, lns_gmm_ref
 from .update import lns_fused_update_pallas
 
 
@@ -314,3 +316,78 @@ def lns_matmul_trainable(x, w, *, fmt: LNSFormat | None = None,
     x2 = x.reshape((-1, x.shape[-1]))
     z = _trainable(x2, w, be)
     return z.reshape(lead + (w.shape[-1],))
+
+
+# ------------------------------------------------------------------------
+# Grouped ⊞-MAC (rows sorted by expert against per-expert weights)
+# ------------------------------------------------------------------------
+@partial(jax.jit, static_argnames=("kind", "fmt", "spec", "block_rows",
+                                   "block_n", "block_k", "interpret"))
+def _gmm_call(kind, a_code, a_sign, b_code, b_sign, sizes, fmt, spec,
+              block_rows, block_n, block_k, interpret):
+    fn = {"fwd": lns_gmm_pallas, "dx": lns_gmm_dx_pallas,
+          "dw": lns_gmm_dw_pallas}[kind]
+    return fn(a_code, a_sign.astype("int32"), b_code,
+              b_sign.astype("int32"), sizes, fmt=fmt, spec=spec,
+              block_rows=block_rows, block_n=block_n, block_k=block_k,
+              interpret=interpret)
+
+
+def _gmm(be: LNSMatmulBackend, kind: str, a: LNSArray, b: LNSArray, sizes):
+    """One grouped product on the backend's path: the kernels, or the
+    per-group emulated ⊞-MACs they are bit-identical to."""
+    if be.backend == "pallas":
+        code, sign = _gmm_call(kind, a.code, a.sign, b.code, b.sign, sizes,
+                               be.fmt, be.spec, be.block_m, be.block_n,
+                               be.block_k, be._interp())
+    else:
+        fn = {"fwd": lns_gmm_ref, "dx": lns_gmm_dx_ref,
+              "dw": lns_gmm_dw_ref}[kind]
+        code, sign = fn(a.code, a.sign.astype("int32"), b.code,
+                        b.sign.astype("int32"), sizes, fmt=be.fmt,
+                        spec=be.spec)
+    return LNSArray(code, sign.astype("int8"))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _grouped(x, w, sizes, be: LNSMatmulBackend):
+    return decode(_gmm(be, "fwd", encode(x, be.fmt), encode(w, be.fmt),
+                       sizes), be.fmt)
+
+
+def _grouped_fwd(x, w, sizes, be):
+    xq, wq = encode(x, be.fmt), encode(w, be.fmt)
+    return decode(_gmm(be, "fwd", xq, wq, sizes), be.fmt), (xq, wq, sizes)
+
+
+def _grouped_bwd(be, res, g):
+    xq, wq, sizes = res
+    dy = encode(g, be.fmt)
+    return (decode(_gmm(be, "dx", dy, wq, sizes), be.fmt),
+            decode(_gmm(be, "dw", xq, dy, sizes), be.fmt), None)
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def lns_gmm_trainable(x, w, sizes, *, fmt: LNSFormat | None = None,
+                      spec: DeltaSpec | None = None,
+                      backend: str | None = None, block_m: int = 128,
+                      block_n: int = 128, block_k: int = 128,
+                      interpret: bool | None = None, numerics=None,
+                      layer: str | None = None):
+    """Differentiable grouped ⊞-MAC: ``x`` (M, K) float rows sorted by
+    group, ``w`` (G, K, N) float, ``sizes`` (G,) int32 rows per group.
+
+    Row ``r`` of group ``g`` gets ``x[r] ⊞-MAC w[g]``; rows past
+    ``sum(sizes)`` get 0.  As :func:`lns_matmul_trainable`, the forward
+    and both cotangent products (dX per row against ``w[g]ᵀ``, dW per
+    group over its rows in the order given) run the ⊞-MAC path, here the
+    grouped kernels (``grouped.py``); ``block_m`` is the row tile.
+    """
+    fmt, spec, backend, interpret, _ = _resolve_numerics(
+        numerics, fmt, spec, backend, interpret, layer)
+    be = LNSMatmulBackend(fmt=fmt, spec=spec, backend=backend,
+                          block_m=block_m, block_n=block_n, block_k=block_k,
+                          interpret=interpret)
+    return _grouped(x, w, sizes.astype("int32"), be)

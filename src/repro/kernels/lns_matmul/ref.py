@@ -110,3 +110,53 @@ def lns_matmul_dw_update_ref(x_code, x_sign, dy_code, dy_sign, *,
     eng = DeltaEngine(spec, fmt)
     return apply_update_codes(w, LNSArray(gc, gs.astype("int8")), m,
                               epilogue, eng)
+
+
+def _group_masks(sizes, m: int):
+    """(G, M) bool: row r belongs to group g (rows sorted by group; rows
+    past ``sum(sizes)`` to none)."""
+    end = jnp.cumsum(sizes)
+    r = jnp.arange(m)
+    return (r[None, :] >= (end - sizes)[:, None]) & (r[None, :] < end[:, None])
+
+
+def _mask_rows(code, sign, mask, fmt):
+    return (jnp.where(mask[:, None], code, fmt.zero_code),
+            jnp.where(mask[:, None], sign, 0))
+
+
+def _gmm_rows(a_code, a_sign, w_code, w_sign, sizes, fmt, spec, t_b):
+    masks = _group_masks(sizes, a_code.shape[0])
+    out_c = out_s = None
+    for g in range(w_code.shape[0]):
+        c, s = _mm(*_mask_rows(a_code, a_sign, masks[g], fmt), w_code[g],
+                   w_sign[g], fmt, spec, t_b=t_b)
+        if out_c is None:
+            out_c, out_s = (jnp.full_like(c, fmt.zero_code),
+                            jnp.zeros_like(s))
+        out_c = jnp.where(masks[g][:, None], c, out_c)
+        out_s = jnp.where(masks[g][:, None], s, out_s)
+    return out_c, out_s
+
+
+def lns_gmm_ref(x_code, x_sign, w_code, w_sign, sizes, *, fmt: LNSFormat,
+                spec: DeltaSpec):
+    """Grouped forward oracle: each group's rows ⊞-MAC its weights."""
+    return _gmm_rows(x_code, x_sign, w_code, w_sign, sizes, fmt, spec, False)
+
+
+def lns_gmm_dx_ref(dy_code, dy_sign, w_code, w_sign, sizes, *,
+                   fmt: LNSFormat, spec: DeltaSpec):
+    """Grouped dX oracle: each group's rows ⊞-MAC its weights ᵀ."""
+    return _gmm_rows(dy_code, dy_sign, w_code, w_sign, sizes, fmt, spec,
+                     True)
+
+
+def lns_gmm_dw_ref(x_code, x_sign, dy_code, dy_sign, sizes, *,
+                   fmt: LNSFormat, spec: DeltaSpec):
+    """Grouped dW oracle: X_gᵀ ⊞-MAC dY_g over group g's rows in order
+    (other rows are the zero code, the ⊞ identity)."""
+    masks = _group_masks(sizes, x_code.shape[0])
+    outs = [_mm(*_mask_rows(x_code, x_sign, masks[g], fmt), dy_code,
+                dy_sign, fmt, spec, t_a=True) for g in range(sizes.shape[0])]
+    return (jnp.stack([c for c, _ in outs]), jnp.stack([s for _, s in outs]))

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from ..core.numerics import NumericsPolicy
 from .config import ModelConfig
-from .layers import apply_rope, rms_head_norm
+from .layers import apply_rope, rms_head_norm, softmax_mscale
 from .paged import paged_gather, paged_write_chunk, paged_write_token
 
 
@@ -63,8 +63,8 @@ def gqa_qkv(p, x, cfg: ModelConfig, pol: NumericsPolicy, positions):
     if cfg.qk_norm:
         q = rms_head_norm(q, p["q_norm"])
         k = rms_head_norm(k, p["k_norm"])
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+    k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     return q, k, v
 
 
@@ -253,7 +253,8 @@ def _mla_latents(p, x, cfg, pol, positions):
     dkv = pol.linear(x, p["w_dkv"])
     c_kv = rms_head_norm(dkv[..., :m.kv_lora_rank], p["kv_norm"])
     k_pe = dkv[..., m.kv_lora_rank:][:, :, None, :]   # single rope head
-    k_pe = apply_rope(k_pe, positions, cfg.rope_theta)[:, :, 0, :]
+    k_pe = apply_rope(k_pe, positions, cfg.rope_theta,
+                      cfg.rope_scaling)[:, :, 0, :]
     return c_kv, k_pe
 
 
@@ -264,7 +265,7 @@ def _mla_q(p, x, cfg, pol, positions):
     q = pol.linear(x, p["wq"]).reshape(
         b, s, h, m.nope_head_dim + m.rope_head_dim)
     q_nope, q_pe = q[..., :m.nope_head_dim], q[..., m.nope_head_dim:]
-    q_pe = apply_rope(q_pe, positions, cfg.rope_theta)
+    q_pe = apply_rope(q_pe, positions, cfg.rope_theta, cfg.rope_scaling)
     return q_nope, q_pe
 
 
@@ -285,7 +286,7 @@ def mla_attention(p, x, cfg: ModelConfig, pol: NumericsPolicy,
     q = _head_sharded(q, rt)
     k = _head_sharded(k, rt)
     v = _head_sharded(v, rt)
-    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5 * softmax_mscale(cfg)
     qg = q.reshape(b, s, h, 1, q.shape[-1])  # reuse grouped SDPA, G=1
     o = _banded_causal(qg, k, v, scale, cfg)
     o = o.reshape(b, s, h * m.v_head_dim)
@@ -312,7 +313,8 @@ def _mla_absorbed(p, x, cfg: ModelConfig, pol: NumericsPolicy, ck, kpe,
     q_lat = jnp.einsum("bqhn,lhn->bqhl", q_nope, w_uk)
     sc = jnp.einsum("bqhl,bsl->bhqs", q_lat, ck)
     sc = sc + jnp.einsum("bqhr,bsr->bhqs", q_pe, kpe)
-    sc = sc.astype(jnp.float32) * (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    sc = sc.astype(jnp.float32) * ((m.nope_head_dim + m.rope_head_dim)
+                                   ** -0.5 * softmax_mscale(cfg))
     sc = jnp.where(mask, sc, jnp.float32(-1e30))
     pr = jax.nn.softmax(sc, axis=-1).astype(x.dtype)
     ctx = jnp.einsum("bhqs,bsl->bqhl", pr, ck)

@@ -18,8 +18,11 @@ class MoEConfig:
     n_shared: int = 2            # always-on shared experts
     d_expert: int = 1408         # per-expert FFN hidden
     first_dense_layers: int = 1  # leading layers keep a dense FFN
-    capacity_factor: float = 1.25
-    router_dtype: str = "float32"
+    capacity_factor: float = 1.25  # all-to-all send buffer of the
+                                   # sequence-sharded mesh path
+    norm_topk_prob: bool = True  # renormalize the top-k gate weights
+    balance_coef: float = 0.001  # α of the sequence-wise expert-balance
+                                 # loss (DeepSeek-V2 §2.1.2)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -28,6 +31,22 @@ class MLAConfig:
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (Peng et al. 2023), as DeepSeek-V2 publishes it
+    under ``rope_scaling``: rotary frequencies blended between
+    interpolated (÷ ``factor``) and original across the dims whose
+    wavelengths fall between ``beta_fast`` and ``beta_slow`` rotations of
+    the original context, and the attention softmax scaled by
+    ``mscale(factor, mscale_all_dim)²``."""
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,10 +88,12 @@ class ModelConfig:
     qk_norm: bool = False
     causal: bool = True
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnConfig] = None
     attn_logit_softcap: float = 0.0
     block_style: str = "serial"  # serial | parallel (command-r)
     # norms / misc
     norm_kind: str = "rmsnorm"   # rmsnorm | layernorm | nonparam_ln
+    norm_eps: float = 1e-5       # eps of the block and final norms
     act: str = "silu"
     mlp_kind: str = "glu"        # glu | mlp
     tie_embeddings: bool = False

@@ -14,11 +14,14 @@ arithmetic becomes a first-class, per-layer mode for every architecture.
 """
 from __future__ import annotations
 
+import math
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
 from ..core.numerics import NumericsPolicy  # = core.spec.LNSRuntime
-from .config import ModelConfig
+from .config import ModelConfig, YarnConfig
 
 
 # ----------------------------------------------------------- norms -------
@@ -33,7 +36,8 @@ def init_norm(cfg: ModelConfig, dtype):
     raise ValueError(cfg.norm_kind)
 
 
-def apply_norm(p, x, cfg: ModelConfig, eps: float = 1e-5):
+def apply_norm(p, x, cfg: ModelConfig):
+    eps = cfg.norm_eps
     xf = x.astype(jnp.float32)
     if cfg.norm_kind == "rmsnorm":
         nrm = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
@@ -153,18 +157,59 @@ def lm_logits(p, x, pol: NumericsPolicy, cfg: ModelConfig):
 
 
 # ----------------------------------------------------------- rotary ------
-def rope_freqs(cfg: ModelConfig, d_rot: int):
-    return cfg.rope_theta ** (
-        -jnp.arange(0, d_rot, 2, dtype=jnp.float32) / d_rot)
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term ``0.1·mscale·ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (B, S, H, D) with D even; positions: (B, S) int32."""
+def softmax_mscale(cfg: ModelConfig) -> float:
+    """The factor YaRN puts on the attention softmax scale:
+    ``mscale(factor, mscale_all_dim)²`` (1 without ``rope_scaling``)."""
+    y = cfg.rope_scaling
+    if y is None or not y.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+
+
+def yarn_inv_freq(d: int, theta: float, y: YarnConfig):
+    """YaRN's inverse frequencies of a ``d``-dim rotary head, as DeepSeek-V2
+    computes them: interpolated (÷ factor) and original frequencies
+    blended by a linear ramp over the correction range — dims below it
+    keep the original, dims above it are fully interpolated."""
+    def dim_of(rot):
+        return (d * math.log(y.original_max_position_embeddings
+                             / (rot * 2 * math.pi))) / (2 * math.log(theta))
+
+    lo = max(math.floor(dim_of(y.beta_fast)), 0)
+    hi = min(math.ceil(dim_of(y.beta_slow)), d - 1)
+    if lo == hi:
+        hi += 0.001
+    pos = jnp.arange(0, d, 2, dtype=jnp.float32) / d
+    extra = 1.0 / (theta ** pos)
+    inter = 1.0 / (y.factor * theta ** pos)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - lo) / (hi - lo),
+                    0.0, 1.0)
+    mask = 1.0 - ramp
+    return inter * (1 - mask) + extra * mask
+
+
+def apply_rope(x, positions, theta: float,
+               scaling: Optional[YarnConfig] = None):
+    """x: (B, S, H, D) with D even; positions: (B, S) int32.  Pairs are
+    the two halves of each head; ``scaling`` adds YaRN."""
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if scaling is None:
+        freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    else:
+        freqs = yarn_inv_freq(d, theta, scaling)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B, S, D/2)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
     return out.astype(x.dtype)

@@ -52,10 +52,13 @@ class Runtime:
     data_axes: tuple = ("data",)
     model_axis: str = "model"
     sequence_parallel: bool = True
+    experts: Optional[tuple] = None   # (first, count) of the routed experts
+                                      # this device holds (MoERuntime)
 
     @property
     def moe_rt(self) -> MoERuntime:
-        return MoERuntime(self.mesh, self.data_axes, self.model_axis)
+        return MoERuntime(self.mesh, self.data_axes, self.model_axis,
+                          self.experts)
 
     def constrain(self, x, spec):
         if self.mesh is None:
@@ -286,10 +289,9 @@ def _moe_layer_fwd(lp, x, cfg, bp: BlockPols, rt, positions):
     a, cache = _attn_fwd(lp["attn"], _norm_sp(lp["norm1"], x, cfg, rt),
                          cfg, bp.attn, positions, rt)
     x = rt.constrain(x + _res(x, a), rt.sp_spec())
-    y, aux = moe_block(lp["moe"], _norm_sp(lp["norm2"], x, cfg, rt), cfg,
-                       bp.moe,
-                       rt.moe_rt if rt.mesh is not None else None)
-    return rt.constrain(x + _res(x, y), rt.sp_spec()), cache, aux
+    y, aux, stats = moe_block(lp["moe"], _norm_sp(lp["norm2"], x, cfg, rt),
+                              cfg, bp.moe, rt.moe_rt)
+    return rt.constrain(x + _res(x, y), rt.sp_spec()), cache, aux, stats
 
 
 def _ssm_block(lp, x, cfg, bp: BlockPols, rt):
@@ -335,7 +337,10 @@ def _embed_inputs(params, batch, cfg, plan, rt=None):
 
 def _backbone(params, x, cfg: ModelConfig, rt: Runtime, positions,
               want_caches: bool = True):
-    """Full-sequence pass through the layer stack → (x, caches, aux).
+    """Full-sequence pass through the layer stack → (x, caches, aux,
+    stats): aux is the MoE balance loss, stats the MoE layers' counters
+    stacked over layers (``moe/routed`` rows per held expert,
+    ``moe/dropped`` assignments left out; empty for other families).
 
     ``want_caches=False`` (training) drops the per-layer KV/state outputs
     inside the scan body — otherwise the stacked (L, B, S, ...) caches
@@ -345,7 +350,7 @@ def _backbone(params, x, cfg: ModelConfig, rt: Runtime, positions,
     plan = _model_plan(cfg)
     aux_total = jnp.float32(0.0)
     keep = (lambda c: c) if want_caches else (lambda c: None)
-    caches = {}
+    caches, stats = {}, {}
     fam = cfg.family
     if fam in ("dense", "vlm"):
         bp = _block_pols(plan, "layers", "attn", "mlp")
@@ -373,15 +378,16 @@ def _backbone(params, x, cfg: ModelConfig, rt: Runtime, positions,
             lambda h, lp: _moe_layer_fwd(lp, h, cfg, bp, rt, positions), cfg)
 
         def body(h, lp):
-            h, cache, aux = blk(h, lp)
-            return h, (keep(cache), aux)
+            h, cache, aux, st = blk(h, lp)
+            return h, (keep(cache), aux, st)
 
-        x, (kv, auxs) = _scan(body, x, params["layers"], cfg)
+        x, (kv, auxs, st) = _scan(body, x, params["layers"], cfg)
         caches["layers"] = kv
         if dense_caches and want_caches:
             caches["dense_layers"] = jax.tree.map(
                 lambda *xs: jnp.stack(xs), *dense_caches)
         aux_total = aux_total + jnp.sum(auxs)
+        stats = {f"moe/{k}": v for k, v in st.items()}
     elif fam == "ssm":
         bp = _block_pols(plan, "layers", "mamba")
         blk = _maybe_remat(lambda h, lp: _ssm_block(lp, h, cfg, bp, rt), cfg)
@@ -429,7 +435,7 @@ def _backbone(params, x, cfg: ModelConfig, rt: Runtime, positions,
             caches["tail_layers"] = tail_c
     else:
         raise ValueError(fam)
-    return x, caches, aux_total
+    return x, caches, aux_total, stats
 
 
 def _encoder(params, enc_in, cfg, rt):
@@ -497,8 +503,11 @@ def _cross_attention(lp, q_in, enc_out, cfg, pol, rt=None):
 
 
 # ------------------------------------------------------------- API -------
-def loss_fn(params, batch, cfg: ModelConfig, rt: Runtime = Runtime()):
-    """Mean next-token CE (+0.01·MoE aux).  batch: tokens, labels[, embeds]."""
+def loss_fn(params, batch, cfg: ModelConfig, rt: Runtime = Runtime(),
+            with_stats: bool = False):
+    """Mean next-token CE (+ the MoE balance loss).  batch: tokens,
+    labels[, embeds].  ``with_stats`` also returns the backbone's
+    counters: ``(loss, stats)``."""
     plan = _model_plan(cfg)
     emb_pol = plan.runtime_for("emb")
     if cfg.family in ("encdec", "audio"):
@@ -517,20 +526,20 @@ def loss_fn(params, batch, cfg: ModelConfig, rt: Runtime = Runtime()):
             jnp.arange(x.shape[1])[None], x.shape[:2])
         x, _ = _decoder(params, x, enc_out, cfg, rt, positions,
                         want_caches=False)
-        aux = jnp.float32(0.0)
+        aux, stats = jnp.float32(0.0), {}
     else:
         x = _embed_inputs(params, batch, cfg, plan, rt)
         positions = jnp.broadcast_to(
             jnp.arange(x.shape[1])[None], x.shape[:2])
-        x, _, aux = _backbone(params, x, cfg, rt, positions,
-                              want_caches=False)
+        x, _, aux, stats = _backbone(params, x, cfg, rt, positions,
+                                     want_caches=False)
     x = apply_norm(params["final_norm"], x, cfg)
     labels = batch["labels"]
     if x.shape[1] != labels.shape[1]:  # frontend prefix carries no loss
         x = x[:, x.shape[1] - labels.shape[1]:]
     loss = chunked_ce_loss(x, params["emb"], labels,
-                           plan.runtime_for("head"), cfg, rt=rt)
-    return loss + 0.01 * aux
+                           plan.runtime_for("head"), cfg, rt=rt) + aux
+    return (loss, stats) if with_stats else loss
 
 
 def prefill(params, batch, cfg: ModelConfig, rt: Runtime = Runtime()):
@@ -556,7 +565,7 @@ def prefill(params, batch, cfg: ModelConfig, rt: Runtime = Runtime()):
         x = _embed_inputs(params, batch, cfg, plan, rt)
         positions = jnp.broadcast_to(
             jnp.arange(x.shape[1])[None], x.shape[:2])
-        x, caches, _ = _backbone(params, x, cfg, rt, positions)
+        x, caches, _, _ = _backbone(params, x, cfg, rt, positions)
     x = apply_norm(params["final_norm"], x[:, -1:], cfg)
     return lm_logits(params["emb"], x, plan.runtime_for("head"), cfg), caches
 
@@ -643,9 +652,9 @@ def decode_step(params, tok, caches, pos, cfg: ModelConfig,
                                   apply_norm(lp["norm1"], h, cfg), cfg,
                                   bp.attn, c, pos)
                 h = h + _res(h, a)
-                y, _ = moe_block(lp["moe"], apply_norm(lp["norm2"], h, cfg),
-                                 cfg, bp.moe,
-                                 rt.moe_rt if rt.mesh is not None else None)
+                y, _, _ = moe_block(lp["moe"],
+                                    apply_norm(lp["norm2"], h, cfg), cfg,
+                                    bp.moe, rt.moe_rt)
                 return h + _res(h, y), c2
 
             x, kv = _scan(body, x, (params["layers"],
@@ -763,6 +772,9 @@ class _InferPol:
 
     def linear(self, x, w):
         return self.rt.linear_infer(x, w)
+
+    def grouped_linear(self, x, w, sizes):
+        return self.rt.grouped_linear(x, w, sizes)
 
     def q_param(self, w):
         return self.rt.q_param(w)
@@ -885,9 +897,8 @@ def decode_step_paged(params, tok, caches, bt, pos, active,
                                     apply_norm(lp["norm1"], h, cfg), cfg,
                                     bp.attn, c, bt, pos, active)
             h = h + _res(h, a)
-            y, _ = moe_block(lp["moe"], apply_norm(lp["norm2"], h, cfg),
-                             cfg, bp.moe,
-                             rt.moe_rt if rt.mesh is not None else None)
+            y, _, _ = moe_block(lp["moe"], apply_norm(lp["norm2"], h, cfg),
+                                cfg, bp.moe, rt.moe_rt)
             return h + _res(h, y), c2
 
         x, kv = _scan(body, x, (params["layers"], caches["layers"]), cfg)
@@ -961,9 +972,8 @@ def prefill_chunk(params, tok, caches, bt_row, pos_base, n_valid,
                                         cfg, bp.attn, c, bt_row, pos_base,
                                         n_valid)
             h = h + _res(h, a)
-            y, _ = moe_block(lp["moe"], apply_norm(lp["norm2"], h, cfg),
-                             cfg, bp.moe,
-                             rt.moe_rt if rt.mesh is not None else None)
+            y, _, _ = moe_block(lp["moe"], apply_norm(lp["norm2"], h, cfg),
+                                cfg, bp.moe, rt.moe_rt)
             return h + _res(h, y), c2
 
         x, kv = _scan(body, x, (params["layers"], caches["layers"]), cfg)

@@ -1,19 +1,38 @@
 """Mixture-of-Experts FFN: shared + fine-grained routed experts (DeepSeek).
 
-Two interchangeable implementations:
+An expert layer is told which of the ``n_experts`` routed experts it holds
+(``MoERuntime.experts``: the first id and how many; the weights' leading
+axis holds exactly those), routes every token over all of them, and
+computes the part of the result its own experts give (``expert_share``):
 
-* ``reference`` — dropless masked einsum over all experts.  O(N·E·d_ff):
-  exact, used for smoke tests / correctness oracles at tiny scale.
-* ``ep`` (production) — expert parallelism under ``jax.shard_map``:
-  activations enter **sequence-sharded over the model axis** (SP) and
-  batch-sharded over the data axes, so every device owns a distinct token
-  slice; local fp32 top-k routing → capacity-bounded **all-to-all** over
-  ``model`` (experts live E/tp per device) → local sort-based dispatch →
-  batched expert GEMMs → reverse all-to-all → weighted scatter-add combine.
-  The collectives are explicit in the HLO, which is what the roofline reads.
+* the router: float32 logits and softmax over all experts, greedy top-k,
+  the top-k weights renormalized only where the config says so
+  (``norm_topk_prob``);
+* the assignments that land on the held experts, sorted by expert and in
+  ascending token order within each, run as rows of one grouped matmul
+  per projection (``pol.grouped_linear``: the grouped ⊞-MAC on the LNS
+  training path), with no token dropped;
+* the outputs weighted by their gates in float32.
 
-Router runs in fp32; top-k weights renormalized (DeepSeek convention).
-A Switch-style load-balance aux loss is returned alongside.
+``moe_layer`` adds the shared experts once: on one device (no mesh) it is
+the whole layer, and a layer that holds a share of the experts gives that
+share's part, as one chip of an expert-parallel group would before its
+exchange.  With a mesh the same core runs on each shard's E/tp experts:
+
+* ``moe_ep_replicated`` — tokens replicated over the model axis (decode,
+  short sequences): each shard's part, then a ``psum``;
+* ``moe_ep`` — activations sequence-sharded over the model axis: a
+  capacity-bounded **all-to-all** carries each assignment to the shard
+  that holds its expert and back (assignments over a destination's
+  capacity are dropped and counted), then the gate-weighted combine.
+
+The balance loss is DeepSeek-V2's sequence-wise one (§2.1.2): per
+sequence, ``α Σ_i f_i P_i`` with ``f_i`` the share of the sequence's
+assignments to expert ``i`` times ``E / K`` and ``P_i`` its mean router
+probability, averaged over sequences; ``α`` is ``balance_coef``.
+Each block returns ``(y, aux, stats)``: ``stats["routed"]`` counts the
+rows each held expert computed, ``stats["dropped"]`` the assignments
+left out.
 """
 from __future__ import annotations
 
@@ -30,10 +49,16 @@ from .config import ModelConfig
 
 @dataclasses.dataclass(frozen=True)
 class MoERuntime:
-    """How to execute the MoE block (None mesh → reference impl)."""
+    """How to execute the MoE block.
+
+    ``experts`` is ``(first, count)``, the routed experts this device
+    holds (None: all of them); without a mesh the block computes their
+    part.  With a mesh each shard of the model axis holds E/tp experts.
+    """
     mesh: Optional[object] = None
     data_axes: tuple = ("data",)   # batch axes (may include 'pod')
     model_axis: str = "model"
+    experts: Optional[tuple] = None
 
 
 def init_moe(key, cfg: ModelConfig, dtype):
@@ -58,15 +83,30 @@ def init_moe(key, cfg: ModelConfig, dtype):
     return p
 
 
-def _router(p, xf, m):
-    logits = xf.astype(jnp.float32) @ p["router"]
+def _route(p, xf, m):
+    """Router over all experts: float32 logits (at full float32 precision)
+    and softmax, greedy top-k.  Returns gate weights and expert ids
+    (T, k), and the probabilities (T, E)."""
+    logits = jnp.matmul(xf.astype(jnp.float32), p["router"],
+                        precision=jax.lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)
     w, ids = jax.lax.top_k(probs, m.top_k)
-    w = w / jnp.maximum(jnp.sum(w, -1, keepdims=True), 1e-9)
-    # load-balance aux loss (Switch-style)
-    frac = jnp.mean(jax.nn.one_hot(ids[..., 0], m.n_experts), axis=0)
-    aux = m.n_experts * jnp.sum(frac * jnp.mean(probs, axis=0))
-    return w, ids, aux
+    if m.norm_topk_prob:
+        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+    return w, ids, probs
+
+
+def _balance_sums(ids, probs, n_seq: int, n_experts: int):
+    """Per sequence: assignments to each expert, and the sum of each
+    expert's router probability over the tokens; (n_seq, E) each."""
+    cnt = jnp.sum(jax.nn.one_hot(ids.reshape(n_seq, -1), n_experts,
+                                 dtype=jnp.float32), axis=1)
+    return cnt, jnp.sum(probs.reshape(n_seq, -1, n_experts), axis=1)
+
+
+def _balance_loss(cnt, psum, seq_len: int, m):
+    f = cnt / (seq_len * m.top_k / m.n_experts)
+    return m.balance_coef * jnp.mean(jnp.sum(f * (psum / seq_len), axis=-1))
 
 
 def _shared_ffn(p, x, cfg, pol):
@@ -75,12 +115,45 @@ def _shared_ffn(p, x, cfg, pol):
     return pol.linear(h, p["shared_down"])
 
 
-def _expert_ffn(w_gate, w_up, w_down, xe, pol):
-    """xe: (E, C, d) → (E, C, d) batched over experts."""
-    g = jax.nn.silu(jnp.einsum("ecd,edf->ecf", pol.q_act(xe),
-                               pol.q_param(w_gate)))
-    u = jnp.einsum("ecd,edf->ecf", pol.q_act(xe), pol.q_param(w_up))
-    return jnp.einsum("ecf,efd->ecd", pol.q_act(g * u), pol.q_param(w_down))
+def expert_share(p, xf, w, ids, first, pol: NumericsPolicy):
+    """The held experts' part of the routed output.
+
+    ``xf`` (T, d) tokens, ``w``/``ids`` (T, k) their gates and experts;
+    the weights hold experts ``first .. first + held - 1``.  Returns the
+    float32 (T, d) sum over each token's assignments to held experts of
+    gate × expert output, and the rows each held expert computed (held,).
+    """
+    held = p["w_gate"].shape[0]
+    t, k = ids.shape
+    local = ids.reshape(-1) - first
+    mine = (local >= 0) & (local < held)
+    key = jnp.where(mine, local, held)
+    # Stable: within an expert, assignments keep ascending token order,
+    # the order its dW contracts over.
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(jax.nn.one_hot(key, held, dtype=jnp.int32), axis=0)
+    rows = xf[order // k]
+    h = jax.nn.silu(pol.grouped_linear(rows, p["w_gate"], sizes)) \
+        * pol.grouped_linear(rows, p["w_up"], sizes)
+    y = pol.grouped_linear(h, p["w_down"], sizes)
+    y = y[jnp.argsort(order)].reshape(t, k, -1).astype(jnp.float32)
+    gate = jnp.where(mine.reshape(t, k), w, 0.0)
+    return jnp.sum(y * gate[..., None], axis=1), sizes
+
+
+def moe_layer(p, x, cfg: ModelConfig, pol: NumericsPolicy, first=0):
+    """The layer on one device: the held experts' part (all experts when
+    the weights hold all of them) plus the shared experts."""
+    m = cfg.moe
+    b, s, d = x.shape
+    xf = x.reshape(-1, d)
+    w, ids, probs = _route(p, xf, m)
+    y, sizes = expert_share(p, xf, w, ids, first, pol)
+    if m.n_shared:
+        y = y + _shared_ffn(p, xf, cfg, pol).astype(jnp.float32)
+    aux = _balance_loss(*_balance_sums(ids, probs, b, m.n_experts), s, m)
+    stats = {"routed": sizes, "dropped": jnp.zeros((), jnp.int32)}
+    return y.astype(x.dtype).reshape(b, s, d), aux, stats
 
 
 def _bucket_positions(keys, n_buckets):
@@ -95,24 +168,11 @@ def _bucket_positions(keys, n_buckets):
     return order, ks, pos
 
 
-# ------------------------------------------------------- reference -------
-def moe_reference(p, x, cfg: ModelConfig, pol: NumericsPolicy):
-    """Dropless masked computation over all experts (tiny scale only)."""
-    m = cfg.moe
-    b, s, d = x.shape
-    xf = x.reshape(-1, d)
-    w, ids, aux = _router(p, xf, m)
-    comb = jnp.zeros((xf.shape[0], m.n_experts), x.dtype)
-    comb = comb.at[jnp.arange(xf.shape[0])[:, None], ids].set(
-        w.astype(x.dtype))
-    h = jax.nn.silu(jnp.einsum("nd,edf->enf", pol.q_act(xf),
-                               pol.q_param(p["w_gate"])))
-    h = h * jnp.einsum("nd,edf->enf", pol.q_act(xf), pol.q_param(p["w_up"]))
-    y = jnp.einsum("enf,efd->end", pol.q_act(h), pol.q_param(p["w_down"]))
-    out = jnp.einsum("end,ne->nd", y, comb)
-    if m.n_shared:
-        out = out + _shared_ffn(p, xf, cfg, pol)
-    return out.reshape(b, s, d), aux
+def _expert_specs(p, rt: MoERuntime):
+    pspec = {k: P() for k in p}
+    for kname in ("w_gate", "w_up", "w_down"):
+        pspec[kname] = P(rt.model_axis, None, None)
+    return pspec
 
 
 # ------------------------------------------------- expert parallel -------
@@ -134,8 +194,12 @@ def moe_ep(p, x, cfg: ModelConfig, pol: NumericsPolicy, rt: MoERuntime):
         b, s, d = x_loc.shape
         xf = x_loc.reshape(-1, d)
         n = xf.shape[0]
-        w, ids, aux = _router(p_loc, xf, m)
-        aux = jax.lax.pmean(aux, all_axes)
+        w, ids, probs = _route(p_loc, xf, m)
+        cnt, psum = _balance_sums(ids, probs, b, m.n_experts)
+        aux = _balance_loss(jax.lax.psum(cnt, rt.model_axis),
+                            jax.lax.psum(psum, rt.model_axis), s * tp, m)
+        aux = jax.lax.pmean(aux, tuple(rt.data_axes)) if rt.data_axes \
+            else aux
         nk = n * m.top_k
         cap_send = int(-(-nk // tp) * m.capacity_factor)
         flat_ids = ids.reshape(-1)
@@ -155,45 +219,33 @@ def moe_ep(p, x, cfg: ModelConfig, pol: NumericsPolicy, rt: MoERuntime):
             send_x[:-1].reshape(tp, cap_send, d), rt.model_axis, 0, 0)
         recv_e = jax.lax.all_to_all(
             send_e[:-1].reshape(tp, cap_send), rt.model_axis, 0, 0)
-        recv_x = recv_x.reshape(tp * cap_send, d)
         shard = jax.lax.axis_index(rt.model_axis)
-        el = jnp.where(recv_e.reshape(-1) >= 0,
-                       recv_e.reshape(-1) - shard * e_loc, e_loc)
-
-        # local per-expert bucketing (invalid rows bucket to e_loc, dropped)
-        cap_e = int(-(-tp * cap_send // e_loc) * m.capacity_factor)
-        order2, el_s, pos2 = _bucket_positions(el, e_loc + 1)
-        ok2 = (el_s < e_loc) & (pos2 < cap_e)
-        slot2 = jnp.where(ok2, el_s * cap_e + pos2, e_loc * cap_e)
-        xe = jnp.zeros((e_loc * cap_e + 1, d), x_loc.dtype)
-        xe = xe.at[slot2].set(recv_x[order2], mode="drop")
-        ye = _expert_ffn(p_loc["w_gate"], p_loc["w_up"], p_loc["w_down"],
-                         xe[:-1].reshape(e_loc, cap_e, d), pol)
-        ye = ye.reshape(-1, d)
-        # back to recv order → reverse all-to-all → weighted combine
-        y_recv = jnp.zeros((tp * cap_send, d), x_loc.dtype)
-        y_recv = y_recv.at[order2].set(
-            jnp.where(ok2[:, None],
-                      ye[jnp.clip(slot2, 0, e_loc * cap_e - 1)], 0.0))
+        # Each received row is one assignment: the expert core with k = 1
+        # and gate 1 gives its expert's output (0 for an empty slot).
+        y_recv, sizes = expert_share(
+            p_loc, recv_x.reshape(tp * cap_send, d),
+            jnp.ones((tp * cap_send, 1), jnp.float32),
+            recv_e.reshape(-1, 1), shard * e_loc, pol)
         y_back = jax.lax.all_to_all(
             y_recv.reshape(tp, cap_send, d), rt.model_axis, 0, 0)
         y_flat = y_back.reshape(tp * cap_send, d)
         got = jnp.where(keep[:, None],
                         y_flat[jnp.clip(slot, 0, tp * cap_send - 1)], 0.0)
-        out = jnp.zeros_like(xf)
-        out = out.at[tok[order]].add(got * wgt[order][:, None]
-                                     .astype(x_loc.dtype))
+        out = jnp.zeros((n, d), jnp.float32)
+        out = out.at[tok[order]].add(got * wgt[order][:, None])
         if m.n_shared:
-            out = out + _shared_ffn(p_loc, xf, cfg, pol)
-        return out.reshape(b, s, d), aux
+            out = out + _shared_ffn(p_loc, xf, cfg, pol).astype(jnp.float32)
+        stats = {"routed": jax.lax.psum(sizes, tuple(rt.data_axes))
+                 if rt.data_axes else sizes,
+                 "dropped": jax.lax.psum(jnp.sum(~keep).astype(jnp.int32),
+                                         all_axes)}
+        return out.astype(x_loc.dtype).reshape(b, s, d), aux, stats
 
-    pspec = {k: P() for k in p}
-    for kname in ("w_gate", "w_up", "w_down"):
-        pspec[kname] = P(rt.model_axis, None, None)
     fn = jax.shard_map(
         local_fn, mesh=mesh,
-        in_specs=(pspec, x_spec),
-        out_specs=(x_spec, P()),
+        in_specs=(_expert_specs(p, rt), x_spec),
+        out_specs=(x_spec, P(), {"routed": P(rt.model_axis),
+                                 "dropped": P()}),
         check_vma=False)
     return fn(p, x)
 
@@ -201,61 +253,54 @@ def moe_ep(p, x, cfg: ModelConfig, pol: NumericsPolicy, rt: MoERuntime):
 def moe_ep_replicated(p, x, cfg: ModelConfig, pol: NumericsPolicy,
                       rt: MoERuntime):
     """EP without all-to-all, for token counts too small to sequence-shard
-    (decode: seq=1).  Tokens are replicated over the model axis; each shard
-    filters the assignments that target its local experts, computes, and
-    the routed outputs are psum-combined.  Shared experts are computed
-    redundantly (replicated) and added outside the psum.
+    (decode: seq=1).  Tokens are replicated over the model axis; each
+    shard computes its experts' part (``expert_share``, nothing dropped)
+    and the parts are psum-combined.  Shared experts are computed
+    redundantly (replicated) and added once, outside the psum.
     """
     m = cfg.moe
     mesh = rt.mesh
     tp = mesh.shape[rt.model_axis]
     e_loc = m.n_experts // tp
     x_spec = P(tuple(rt.data_axes) or None, None, None)
-    all_axes = tuple(rt.data_axes) + (rt.model_axis,)
 
     def local_fn(p_loc, x_loc):
         b, s, d = x_loc.shape
         xf = x_loc.reshape(-1, d)
-        n = xf.shape[0]
-        w, ids, aux = _router(p_loc, xf, m)
-        aux = jax.lax.pmean(aux, all_axes)
+        w, ids, probs = _route(p_loc, xf, m)
+        aux = _balance_loss(*_balance_sums(ids, probs, b, m.n_experts), s,
+                            m)
+        aux = jax.lax.pmean(aux, tuple(rt.data_axes)) if rt.data_axes \
+            else aux
         shard = jax.lax.axis_index(rt.model_axis)
-        el = ids - shard * e_loc                        # (n, k) local ids
-        mine = (el >= 0) & (el < e_loc)
-        flat_el = jnp.where(mine, el, e_loc).reshape(-1)
-        tok = jnp.repeat(jnp.arange(n), m.top_k)
-        wgt = (w * mine).reshape(-1)
-        cap = int(-(-n * m.top_k // tp) * m.capacity_factor)
-        order, el_s, pos = _bucket_positions(flat_el, e_loc + 1)
-        ok = (el_s < e_loc) & (pos < cap)
-        slot = jnp.where(ok, el_s * cap + pos, e_loc * cap)
-        xe = jnp.zeros((e_loc * cap + 1, d), x_loc.dtype)
-        xe = xe.at[slot].set(xf[tok[order]], mode="drop")
-        ye = _expert_ffn(p_loc["w_gate"], p_loc["w_up"], p_loc["w_down"],
-                         xe[:-1].reshape(e_loc, cap, d), pol).reshape(-1, d)
-        got = jnp.where(ok[:, None],
-                        ye[jnp.clip(slot, 0, e_loc * cap - 1)], 0.0)
-        out = jnp.zeros_like(xf)
-        out = out.at[tok[order]].add(
-            got * wgt[order][:, None].astype(x_loc.dtype))
-        out = jax.lax.psum(out, rt.model_axis)
+        y, sizes = expert_share(p_loc, xf, w, ids, shard * e_loc, pol)
+        y = jax.lax.psum(y, rt.model_axis)
         if m.n_shared:
-            out = out + _shared_ffn(p_loc, xf, cfg, pol)
-        return out.reshape(b, s, d), aux
+            y = y + _shared_ffn(p_loc, xf, cfg, pol).astype(jnp.float32)
+        stats = {"routed": jax.lax.psum(sizes, tuple(rt.data_axes))
+                 if rt.data_axes else sizes,
+                 "dropped": jnp.zeros((), jnp.int32)}
+        return y.astype(x_loc.dtype).reshape(b, s, d), aux, stats
 
-    pspec = {k: P() for k in p}
-    for kname in ("w_gate", "w_up", "w_down"):
-        pspec[kname] = P(rt.model_axis, None, None)
     fn = jax.shard_map(local_fn, mesh=mesh,
-                       in_specs=(pspec, x_spec),
-                       out_specs=(x_spec, P()), check_vma=False)
+                       in_specs=(_expert_specs(p, rt), x_spec),
+                       out_specs=(x_spec, P(), {"routed": P(rt.model_axis),
+                                                "dropped": P()}),
+                       check_vma=False)
     return fn(p, x)
 
 
 def moe_block(p, x, cfg: ModelConfig, pol: NumericsPolicy,
               rt: Optional[MoERuntime] = None):
+    """``(y, aux, stats)`` of one MoE layer on the runtime's devices."""
     if rt is None or rt.mesh is None:
-        return moe_reference(p, x, cfg, pol)
+        first, count = (rt.experts if rt is not None and rt.experts
+                        else (0, cfg.moe.n_experts))
+        if p["w_gate"].shape[0] != count:
+            raise ValueError(
+                f"the layer is told it holds {count} experts from {first}, "
+                f"its weights hold {p['w_gate'].shape[0]}")
+        return moe_layer(p, x, cfg, pol, first)
     tp = rt.mesh.shape[rt.model_axis]
     if x.shape[1] % tp != 0:     # decode / tiny sequences
         return moe_ep_replicated(p, x, cfg, pol, rt)

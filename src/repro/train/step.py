@@ -151,10 +151,19 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig,
             "run_experiment(..., data_parallel=...)); the LM train step "
             "reduces float gradients — use reduce.mode='float-psum'")
     _, opt_update = make_optimizer(opt_cfg)
+    # An MoE step also reports its expert layers' counters (rows routed to
+    # each held expert, assignments dropped) among its metrics.
+    with_stats = cfg.family == "moe"
 
     def grads_of(params, batch):
-        return jax.value_and_grad(lambda p: loss_fn(p, batch, cfg, rt))(
+        if with_stats:
+            (loss, stats), g = jax.value_and_grad(
+                lambda p: loss_fn(p, batch, cfg, rt, with_stats=True),
+                has_aux=True)(params)
+            return loss, g, stats
+        loss, g = jax.value_and_grad(lambda p: loss_fn(p, batch, cfg, rt))(
             params)
+        return loss, g, {}
 
     def step(state, batch):
         params = state["params"]
@@ -164,7 +173,7 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig,
 
             def acc_fn(carry, mb):
                 loss_a, g_a = carry
-                loss, g = grads_of(params, mb)
+                loss, g, _ = grads_of(params, mb)
                 return (loss_a + loss,
                         jax.tree.map(jnp.add, g_a, g)), None
 
@@ -176,11 +185,12 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig,
             inv = 1.0 / tc.microbatches
             loss = loss * inv
             grads = jax.tree.map(lambda g: g * inv, grads)
+            stats = {}
         else:
             # One value_and_grad: forward and backward share this scope.
             with phase_scope("grad"):
-                loss, grads = grads_of(params, batch)
-        metrics = {"loss": loss}
+                loss, grads, stats = grads_of(params, batch)
+        metrics = {"loss": loss, **stats}
         if tc.grad_clip:
             grads, gn = _clip(grads, tc.grad_clip)
             metrics["grad_norm"] = gn

@@ -26,6 +26,9 @@ from repro.kernels.lns_matmul.lns_matmul import (
     FwdEpilogue, lns_matmul_dw_partials_pallas, lns_matmul_dw_pallas,
     lns_matmul_dw_update_pallas, lns_matmul_dx_pallas,
     lns_matmul_fused_pallas, lns_matmul_pallas)
+from repro.kernels.lns_matmul.grouped import (lns_gmm_dw_pallas,
+                                              lns_gmm_dx_pallas,
+                                              lns_gmm_pallas)
 from repro.kernels.lns_matmul.update import lns_fused_update_pallas
 
 #: Every Δ kind the compiled lane takes.  lut640 unrolls ~400 breakpoints
@@ -189,3 +192,36 @@ def test_paper_mlp_donating_step_aliases_its_params(one_chip):
     assert n == 8
     assert aliases(LNSMLP._train_step_donate) == {(i, i) for i in range(n)}
     assert aliases(LNSMLP._train_step_keep) == set()
+
+
+#: deepseek-v2-lite's expert layer on one chip: 16 held experts, d_model
+#: 2048 and d_expert 1408, a bound of 6,144 routed rows (1,024 tokens,
+#: top-6); each kind's extents as given, then padded (rows to whole
+#: 128-row tiles per expert: ceil(6144 / 128) + 16 tiles).
+GMM_G, GMM_M, GMM_D, GMM_DE = 16, 6144, 2048, 1408
+GMM_CASES = {
+    "gmm_fwd": (lns_gmm_pallas, [(GMM_M, GMM_D), (GMM_G, GMM_D, GMM_DE)],
+                (GMM_M, GMM_DE, GMM_D, 8192, GMM_DE, GMM_D)),
+    "gmm_dx": (lns_gmm_dx_pallas, [(GMM_M, GMM_DE), (GMM_G, GMM_D, GMM_DE)],
+               (GMM_M, GMM_D, GMM_DE, 8192, GMM_D, GMM_DE)),
+    "gmm_dw": (lns_gmm_dw_pallas, [(GMM_M, GMM_D), (GMM_M, GMM_DE)],
+               (GMM_D, GMM_DE, GMM_M, GMM_D, GMM_DE, 8192)),
+}
+
+
+@pytest.mark.parametrize("kind", list(GMM_CASES))
+def test_grouped_kernel_compiles_for_v5e(one_chip, kind):
+    """The grouped ⊞-MAC launches at the cell's shapes compile for the
+    chip, and name their kind, group count and extents."""
+    fn, (a, b), ext = GMM_CASES[kind]
+    args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+            for s in (a, a, b, b, (GMM_G,))]
+    text = jax.jit(lambda ac, as_, bc, bs, sizes: fn(
+        ac, as_, bc, bs, sizes, fmt=LNS16, spec=DELTA_DEFAULT,
+        interpret=False)).lower(*args).compile().as_text()
+    found = [json.loads(m) for m in re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*'
+        r"kernel_metadata=(\{[^{}]*\})", text)]
+    want = {"kind": kind, "g": str(GMM_G)}
+    want.update(zip(("r", "c", "ct", "rp", "cp", "ctp"), map(str, ext)))
+    assert found == [want]
