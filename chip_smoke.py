@@ -3,17 +3,20 @@
     python chip_smoke.py              # one chip
     python chip_smoke.py --chips 4    # the deterministic ⊞-allreduce only
 
-One chip runs four phases through the normal entry points:
+One chip runs five phases through the normal entry points:
 
 * ``mlp``: the paper MLP (784-100-10, batch 64) trained ``STEPS`` steps
   by ``repro.paper.run_experiment`` on ``lns16-train-pallas`` and again on
   ``lns16-train-emulate``; the weight codes must agree bit for bit.
+* ``kernels-delta``: the compiled in-kernel Δ± of every Δ the compiled
+  lane takes (``lut20``, ``lut640``, ``bitshift``) at every d-code of
+  lns16 and lns12, for both signs and for signs mixed in a plane, equal
+  to ``DeltaEngine``.
 * ``kernels-qwen3``: the forward, dX and dW ⊞-MAC kernels at the
   qwen3-1.7b MLP width (d_model 2048, d_ff 6144) on the qwen3 phase's
-  ``BATCH * SEQ`` rows, for every Δ the compiled lane takes (``lut20``,
-  ``lut640``, ``bitshift``), bit for bit against the emulated sequential
-  MAC on 16 rows spread over the output; each kernel's second call is
-  timed on the host clock.
+  ``BATCH * SEQ`` rows, for every Δ the compiled lane takes, bit for bit
+  against the emulated sequential MAC on 16 rows spread over the output;
+  each kernel's second call is timed on the host clock.
 * ``kernels-gmm``: the grouped forward, dX and dW ⊞-MAC kernels at the
   deepseek-v2-lite expert width (d_model 2048, d_expert 1408, 16 experts,
   a bound of 6,144 rows with 1,536 routed: groups uneven, one empty, four
@@ -25,6 +28,9 @@ One chip runs four phases through the normal entry points:
   ``repro.train.make_train_step`` on
   ``lns16-train-pallas`` with the vocabulary head in float32; the losses
   must be finite and the first near the float32 model's.
+
+The kernel phases print the Δ path each launch names in its
+``kernel_metadata`` (``delta``).
 
 ``--chips 4`` runs only ``LNSDataParallelMLP`` at the paper MLP's width on
 a 4-device ``data`` mesh, whose weight codes must equal the one-device
@@ -39,6 +45,7 @@ in this one process.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import math
 import os
@@ -108,6 +115,26 @@ def same_codes(a: dict, b: dict) -> bool:
                                   np.asarray(b[k].sign)) for k in a)
 
 
+def launch_deltas(fn, *args) -> list:
+    """The ``delta`` of each kernel launch in ``fn``'s program."""
+    import jax
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append((eqn.params["metadata"] or {}).get("delta"))
+            for p in eqn.params.values():
+                for sub in p if isinstance(p, (tuple, list)) else (p,):
+                    if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                        walk(sub.jaxpr)
+                    elif isinstance(sub, jax.extend.core.Jaxpr):
+                        walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
 def phase_mlp(clock):
     from repro.paper import run_experiment
     runs = {}
@@ -134,6 +161,51 @@ def phase_mlp(clock):
                        runs["lns16-train-emulate"].params)
     say("mlp", weight_codes_pallas_eq_emulate=equal)
     check(equal, "pallas and emulate weight codes differ")
+
+
+def phase_kernels_delta(clock):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import pallas as pl
+    from repro.core import LNS12, LNS16
+    from repro.core.delta import DeltaEngine
+    from repro.core.lns import resolve_interpret
+    from repro.core.spec import DELTA_NAMES
+    from repro.kernels.lns_matmul.lns_matmul import delta_path, make_delta_fn
+
+    def kernel(d_ref, s_ref, o_ref, *, spec, fmt):
+        o_ref[...] = make_delta_fn(spec, fmt, d_ref.shape[0])(
+            d_ref[...], s_ref[...] == 0)
+
+    for fmt in (LNS16, LNS12):
+        n = fmt.code_max - fmt.code_min + 1
+        d = jnp.arange(n, dtype=jnp.int32).reshape(-1, 128)
+        blk = pl.BlockSpec((8, 128), lambda i: (i, 0))
+        for delta in KERNEL_DELTAS:
+            spec = DELTA_NAMES[delta]
+            eng = DeltaEngine(spec, fmt)
+            run = jax.jit(pl.pallas_call(
+                functools.partial(kernel, spec=spec, fmt=fmt),
+                grid=(d.shape[0] // 8,), in_specs=[blk, blk],
+                out_specs=blk,
+                out_shape=jax.ShapeDtypeStruct(d.shape, jnp.int32),
+                interpret=resolve_interpret(None)))
+            plus = np.asarray(eng.plus(d))
+            minus = np.asarray(eng.minus(d))
+            mixed = (d % 3).astype(jnp.int32)
+            equal = True
+            for sign, want in ((jnp.zeros_like(d), plus),
+                               (jnp.ones_like(d), minus),
+                               (mixed, np.where(np.asarray(mixed) == 0,
+                                                plus, minus))):
+                equal &= bool(np.array_equal(np.asarray(run(d, sign)),
+                                             want))
+            say("kernels-delta", fmt=fmt.name, delta=delta,
+                path=delta_path(spec, fmt), d_codes=n,
+                kernel_eq_engine=equal)
+            check(equal, f"{fmt.name} {delta}: in-kernel Δ differs from "
+                  "DeltaEngine")
 
 
 def phase_kernels_qwen3(clock):
@@ -180,7 +252,9 @@ def phase_kernels_qwen3(clock):
                                         np.asarray(want.code))
                          and np.array_equal(np.asarray(got.sign)[rows],
                                             np.asarray(want.sign)))
-            say("kernels-qwen3", delta=delta, op=name,
+            path = launch_deltas(
+                lambda *a: kernel(*a, fmt=LNS16, spec=spec), *args)
+            say("kernels-qwen3", delta=delta, op=name, path=path,
                 shape=f"{m}x{d}x{f}", pallas_eq_emulate=equal,
                 compile_s=round(compile_s, 3), kernel_s=round(kernel_s, 4))
             check(equal, f"{delta} {name} kernel differs from the emulated "
@@ -254,7 +328,8 @@ def phase_kernels_gmm(clock):
         if name != "dw":
             equal &= bool(np.all(np.asarray(got[0][ends[-1]:])
                                  == LNS16.zero_code))
-        say("kernels-gmm", op=name, groups=g, rows=f"{ends[-1]}/{m}",
+        say("kernels-gmm", op=name, path=launch_deltas(fn, *args, sizes),
+            groups=g, rows=f"{ends[-1]}/{m}",
             shape=f"{d}x{de}" if name != "dx" else f"{de}x{d}",
             grouped_eq_plain=equal, compile_s=round(compile_s, 3),
             kernel_s=round(kernel_s, 4))
@@ -356,8 +431,8 @@ def main(argv=None) -> int:
     say("device", **device, compile_cache=enable_compile_cache())
     clock = CompileClock()
     phases = ([phase_dp4] if args.chips == 4
-              else [phase_mlp, phase_kernels_qwen3, phase_kernels_gmm,
-                    phase_qwen3])
+              else [phase_mlp, phase_kernels_delta, phase_kernels_qwen3,
+                    phase_kernels_gmm, phase_qwen3])
     failed = []
     for phase in phases:
         name = phase.__name__[len("phase_"):]

@@ -25,8 +25,8 @@ from jax.experimental.pallas import tpu as pltpu
 from ...core.delta import DeltaSpec
 from ...core.formats import LNSFormat
 from ...core.lns import resolve_interpret
-from ..lns_matmul.lns_matmul import (_boxplus_codes, make_delta_fn,
-                                     tile, tiling)
+from ..lns_matmul.lns_matmul import (_boxplus_codes, delta_path,
+                                     make_delta_fn, tile, tiling)
 
 
 def _kernel(c_ref, s_ref, out_c_ref, out_s_ref, acc_c, acc_s, *,
@@ -38,7 +38,7 @@ def _kernel(c_ref, s_ref, out_c_ref, out_s_ref, acc_c, acc_s, *,
         acc_c[...] = jnp.full_like(acc_c, np.int32(fmt.zero_code))
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    delta = make_delta_fn(spec, fmt)
+    delta = make_delta_fn(spec, fmt, acc_c.shape[0])
 
     def body(i, carry):
         ac, asn = carry
@@ -85,6 +85,7 @@ def lns_boxsum_pallas(codes, signs, *, fmt: LNSFormat, spec: DeltaSpec,
         scratch_shapes=[pltpu.VMEM((1, block_m), jnp.int32),
                         pltpu.VMEM((1, block_m), jnp.int32)],
         interpret=interpret,
-        metadata={"kind": "boxsum"},
+        metadata={"kind": "boxsum",
+                  "delta": delta_path(spec, fmt)},
     )(codes, signs)
     return out_c[0, :m], out_s[0, :m]

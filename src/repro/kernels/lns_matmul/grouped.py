@@ -45,7 +45,8 @@ from jax.experimental.pallas import tpu as pltpu
 from ...core.delta import DeltaSpec
 from ...core.formats import LNSFormat
 from ...core.lns import resolve_interpret
-from .lns_matmul import _mac_steps, _pad2, make_delta_fn, tile, tiling
+from .lns_matmul import (_mac_steps, _pad2, delta_path, make_delta_fn, tile,
+                         tiling)
 
 
 def group_layout(sizes, m: int, block: int):
@@ -105,9 +106,10 @@ def _rows_kernel(tg_ref, nv_ref, ac_ref, as_ref, bc_ref, bs_ref, zc_ref,
 
     @pl.when(used)
     def _fold():
+        delta = make_delta_fn(spec, fmt, accc_ref.shape[0])
         acc_c, acc_s = _mac_steps(ac_ref, as_ref, bc_ref, bs_ref,
                                   (accc_ref[...], accs_ref[...]), b_ct,
-                                  make_delta_fn(spec, fmt), fmt)
+                                  delta, fmt)
         accc_ref[...] = acc_c
         accs_ref[...] = acc_s
 
@@ -139,9 +141,10 @@ def _dw_kernel(tg_ref, nv_ref, ac_ref, as_ref, bc_ref, bs_ref, zc_ref,
 
     @pl.when(used)
     def _fold():
+        delta = make_delta_fn(spec, fmt, accc_ref.shape[0])
         acc_c, acc_s = _mac_steps(ac_ref, as_ref, bc_ref, bs_ref,
                                   (accc_ref[...], accs_ref[...]), b_ct,
-                                  make_delta_fn(spec, fmt), fmt)
+                                  delta, fmt)
         accc_ref[...] = acc_c
         accs_ref[...] = acc_s
 
@@ -151,8 +154,9 @@ def _dw_kernel(tg_ref, nv_ref, ac_ref, as_ref, bc_ref, bs_ref, zc_ref,
         zs_ref[...] = accs_ref[...]
 
 
-def _metadata(kind, **ext):
-    return {"kind": kind, **{k: str(v) for k, v in ext.items()}}
+def _metadata(kind, delta, **ext):
+    return {"kind": kind, "delta": delta,
+            **{k: str(v) for k, v in ext.items()}}
 
 
 def _launch_rows(at_code, at_sign, w_code, w_sign, tile_group, n_used, *,
@@ -205,8 +209,8 @@ def _launch_rows(at_code, at_sign, w_code, w_sign, tile_group, n_used, *,
                             pltpu.VMEM((block_rows, block_c), jnp.int32)]),
         out_shape=[shape, shape],
         interpret=interpret,
-        metadata=_metadata(kind, g=g, r=m, c=c, ct=ct, rp=rp, cp=c + pad_c,
-                           ctp=ct + pad_ct),
+        metadata=_metadata(kind, delta_path(spec, fmt), g=g, r=m, c=c,
+                           ct=ct, rp=rp, cp=c + pad_c, ctp=ct + pad_ct),
     )(tile_group, n_used, at_code, at_sign, w_code, w_sign)
     return tuple(o[:, :c] for o in out)
 
@@ -309,7 +313,7 @@ def lns_gmm_dw_pallas(x_code, x_sign, dy_code, dy_sign, sizes, *,
                             pltpu.VMEM((block_k, block_n), jnp.int32)]),
         out_shape=[shape, shape],
         interpret=interpret,
-        metadata=_metadata("gmm_dw", g=g, r=k, c=n, ct=m, rp=k + pad_k,
-                           cp=n + pad_n, ctp=rp),
+        metadata=_metadata("gmm_dw", delta_path(spec, fmt), g=g, r=k, c=n,
+                           ct=m, rp=k + pad_k, cp=n + pad_n, ctp=rp),
     )(tg, nv, xc, xs, dc, ds)
     return tuple(o[:, :k, :n] for o in out)

@@ -2,9 +2,11 @@
 
 TPU adaptation of the paper's multiplication-free MAC: the MXU cannot be
 used (there is no multiply to feed it); instead the max+Δ accumulation is
-vectorized on the VPU over output tiles held in VMEM.  The LUT Δ± is a
-compare-select over the table's breakpoints with the values baked in as
-constants (``make_delta_fn``), bit-identical to ``DeltaEngine``.  The
+vectorized on the VPU over output tiles held in VMEM.  The LUT Δ± reads
+one 128-lane table row with one in-vreg lane gather at the index
+``(d + r/2) >> log2 r``, or, for a table that does not fit the row, runs
+a compare-select over the table's breakpoints (``make_delta_fn``,
+``delta_path``); both are bit-identical to ``DeltaEngine``.  The
 contraction dimension is walked *sequentially* — the innermost grid axis
 revisits the output tile, carrying the accumulator in VMEM scratch — which
 reproduces the paper's sequential MAC ordering bit-exactly (see ref.py).
@@ -109,8 +111,8 @@ def _lut_steps(eng: DeltaEngine):
 def _delta_lut(d, same_sign, steps, underflow):
     """LUT Δ± by compare-select over the table's breakpoints.
 
-    Bit-identical to ``DeltaEngine.plus`` / ``minus``; needs no gather,
-    which the chip's compiler refuses on a 1-D table.
+    Bit-identical to ``DeltaEngine.plus`` / ``minus``.  The path for a
+    table that does not fit :func:`_lut_row`: it needs no gather.
     """
     p0, m0, steps = steps
     dp = jnp.full(d.shape, p0, jnp.int32)
@@ -123,6 +125,69 @@ def _delta_lut(d, same_sign, steps, underflow):
             dm = jnp.where(ge, np.int32(m), dm)
     dm = jnp.where(d == 0, underflow, dm)
     return jnp.where(same_sign, dp, dm)
+
+
+#: The lane of the gathered LUT row where Δ- starts; Δ+ starts at lane 0.
+_MINUS_LANE = 64
+
+
+def delta_path(spec: DeltaSpec, fmt: LNSFormat) -> str:
+    """How a kernel evaluates Δ± for one (Δ, format).
+
+    ``"lut-gather"`` (:func:`_delta_gather`) for a LUT whose resolution
+    is a power of two in code units and whose Δ+ and Δ- tables, each with
+    a 0 past d_max, fit 64 lanes; ``"lut-chain"`` (:func:`_delta_lut`)
+    for any other LUT; ``"bitshift"`` and ``"exact"`` name themselves.
+    The launches record it in their ``kernel_metadata`` as ``delta``.
+    """
+    if spec.kind != "lut":
+        return spec.kind
+    r = DeltaEngine(spec, fmt).r_code
+    if r & (r - 1) == 0 and spec.table_size + 1 <= _MINUS_LANE:
+        return "lut-gather"
+    return "lut-chain"
+
+
+def _lut_row(eng: DeltaEngine, rows: int):
+    """The LUT as a (rows, 128) plane, every row Δ+ at lanes 0..n-1 and
+    Δ- at lanes 64..64+n-1, 0 elsewhere (Δ := 0 past d_max).  Built from
+    an iota, since a kernel cannot capture an array constant."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANE), 1)
+    row = jnp.zeros((1, LANE), jnp.int32)
+    for base, tab in ((0, eng._tab_plus), (_MINUS_LANE, eng._tab_minus)):
+        for j, v in enumerate(tab):
+            if v:
+                row = jnp.where(lane == base + j, np.int32(v), row)
+    return jnp.broadcast_to(row, (rows, LANE))
+
+
+def _delta_gather(d, same_sign, table, shift: int, half: int, n: int):
+    """LUT Δ± by one table index and one in-vreg lane gather.
+
+    ``k = min((d + r/2) >> log2 r, n)`` is ``DeltaEngine``'s nearest
+    sample, with lane ``n`` (0) past the table; Δ- reads 64 lanes on.
+    ``minus[0]`` is the underflow sentinel, so d == 0 needs no select:
+    bit-identical to ``DeltaEngine.plus`` / ``minus``.  A plane wider
+    than a vreg is gathered 128 lanes at a time; a 1-D one as one row.
+    """
+    if d.ndim == 1:
+        return _delta_gather(d[None], same_sign, table, shift, half, n)[0]
+    k = jnp.minimum((d + half) >> shift, n)
+    idx = jnp.where(same_sign, k, k + _MINUS_LANE)
+    parts = [_lane_gather(table, idx[:, i:i + LANE])
+             for i in range(0, idx.shape[1], LANE)]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+def _lane_gather(table, idx):
+    """``table[i, idx[i, j]]`` over (rows, 128) planes: the row-batched
+    gather the chip's compiler takes as one lane permute a vreg
+    (``jnp.take_along_axis`` gives other dimension numbers for one row)."""
+    dnums = jax.lax.GatherDimensionNumbers(
+        offset_dims=(), collapsed_slice_dims=(1,), start_index_map=(1,),
+        operand_batching_dims=(0,), start_indices_batching_dims=(0,))
+    return jax.lax.gather(table, idx[..., None], dnums, slice_sizes=(1, 1),
+                          mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
 
 
 def _delta_exact(d, same_sign, scale, underflow):
@@ -166,20 +231,30 @@ def _boxplus_codes(ac, asn, bc, bsn, delta_fn, fmt: LNSFormat):
     return code, sign
 
 
-def make_delta_fn(spec: DeltaSpec, fmt: LNSFormat):
-    """In-kernel Δ±(d, same_sign) on integer d-codes for one (Δ, format).
+def make_delta_fn(spec: DeltaSpec, fmt: LNSFormat, rows: int = 1):
+    """In-kernel Δ±(d, same_sign) on integer d-codes of ``rows`` rows (a
+    1-D plane is one) for one (Δ, format), by the path :func:`delta_path`
+    picks.
 
-    Every kind is plain elementwise integer (or float, for ``exact``) work
-    with its constants baked in at trace time: no table operand.
+    Every kind has its constants baked in at trace time: no table
+    operand.  On the gather path the table is built here, so a kernel
+    calls this before its ⊞ loop and the table is built once a grid step.
     """
     eng = DeltaEngine(spec, fmt)
     underflow = np.int32(eng.underflow)
-    if spec.kind == "bitshift":
+    path = delta_path(spec, fmt)
+    if path == "bitshift":
         return lambda d, same: _delta_bitshift(d, same, qf=fmt.qf,
                                                underflow=underflow)
-    if spec.kind == "exact":
+    if path == "exact":
         return lambda d, same: _delta_exact(d, same, scale=fmt.scale,
                                             underflow=underflow)
+    if path == "lut-gather":
+        table = _lut_row(eng, rows)
+        shift = eng.r_code.bit_length() - 1
+        return lambda d, same: _delta_gather(d, same, table, shift,
+                                             eng.r_code // 2,
+                                             spec.table_size)
     steps = _lut_steps(eng)
     return lambda d, same: _delta_lut(d, same, steps, underflow)
 
@@ -394,7 +469,7 @@ def _mac_kernel(*refs, fmt: LNSFormat, spec: DeltaSpec, n_ct: int, b_ct: int,
             accc_ref[...] = jnp.full_like(accc_ref, np.int32(fmt.zero_code))
             accs_ref[...] = jnp.zeros_like(accs_ref)
 
-    delta = make_delta_fn(spec, fmt)
+    delta = make_delta_fn(spec, fmt, accc_ref.shape[0])
     acc_c, acc_s = _mac_steps(ac_ref, as_ref, bc_ref, bs_ref,
                               (accc_ref[...], accs_ref[...]), b_ct, delta,
                               fmt)
@@ -462,7 +537,8 @@ def _launch_mac(at_code, at_sign, b_code, b_sign, *, kind: str,
     all cropped output planes in kernel order.
 
     ``kind`` names the launch in the profiler's trace: the custom call
-    carries ``kernel_metadata`` with it and the launch's extents, output
+    carries ``kernel_metadata`` with it, the Δ path (``delta``, one of
+    :func:`delta_path`) and the launch's extents, output
     rows ``r``, columns ``c`` and contraction depth ``ct`` as given and
     ``rp``/``cp``/``ctp`` as padded for the grid, so a trace reader can
     tell forward from backward and useful ⊞-MACs from padding.
@@ -557,8 +633,10 @@ def _launch_mac(at_code, at_sign, b_code, b_sign, *, kind: str,
             pltpu.VMEM((block_r, block_c), jnp.int32),
         ],
         interpret=interpret,
-        metadata={"kind": kind, **{k: str(v) for k, v in dict(
-            r=r, c=c, ct=ct, rp=rp, cp=cp, ctp=ctp).items()}},
+        metadata={"kind": kind,
+                  "delta": delta_path(spec, fmt),
+                  **{k: str(v) for k, v in dict(
+                      r=r, c=c, ct=ct, rp=rp, cp=cp, ctp=ctp).items()}},
     )(at_code, at_sign, b_code, b_sign, *extra_in)
     if partial_flush:
         return tuple(o[:, :r, :c] for o in outs)

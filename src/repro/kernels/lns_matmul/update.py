@@ -31,8 +31,8 @@ from ...core.delta import DeltaSpec
 from ...core.formats import LNSFormat
 from ...core.lns import resolve_interpret
 from ...core.sgd import UpdateEpilogue
-from .lns_matmul import (LANE, _apply_update_epilogue, make_delta_fn,
-                         tile, tiling)
+from .lns_matmul import (LANE, _apply_update_epilogue, delta_path,
+                         make_delta_fn, tile, tiling)
 
 
 def _update_kernel(*refs, fmt: LNSFormat, spec: DeltaSpec,
@@ -55,7 +55,8 @@ def _update_kernel(*refs, fmt: LNSFormat, spec: DeltaSpec,
         wc_ref[...], ws_ref[...],
         mc_ref[...] if has_mom else None,
         ms_ref[...] if has_mom else None,
-        gc_ref[...], gs_ref[...], epilogue, make_delta_fn(spec, fmt), fmt)
+        gc_ref[...], gs_ref[...], epilogue,
+        make_delta_fn(spec, fmt, gc_ref.shape[0]), fmt)
     owc_ref[...] = w_c
     ows_ref[...] = w_s
     if has_mom:
@@ -111,6 +112,7 @@ def lns_fused_update_pallas(w_code, w_sign, g_code, g_sign, *,
         out_shape=[jax.ShapeDtypeStruct((rows_p, LANE), jnp.int32)
                    for _ in range(n_out)],
         interpret=interpret,
-        metadata={"kind": "fused_update"},
+        metadata={"kind": "fused_update",
+                  "delta": delta_path(spec, fmt)},
     )(*ins)
     return tuple(o.reshape(-1)[:n].reshape(shape) for o in outs)

@@ -11,7 +11,9 @@ What is tagged, and where:
   which adds the launch's logical extents ``r`` / ``c`` / ``ct`` and the
   padded ones ``rp`` / ``cp`` / ``ctp``), ``fused_update``
   (``kernels/lns_matmul/update.py``) and ``boxsum``
-  (``kernels/lns_boxsum/lns_boxsum.py``).
+  (``kernels/lns_boxsum/lns_boxsum.py``).  Every launch that evaluates
+  Δ± also names how, under ``delta``: ``lut-gather``, ``lut-chain``,
+  ``bitshift`` or ``exact`` (``lns_matmul.delta_path``).
 * **Train-step entry points** — ``paper/mlp.py: LNSMLP.train_step`` and
   ``distributed/lns_dp.py: LNSDataParallelMLP.train_step`` (through
   :func:`host_span`) open the host span ``repro.train_step`` around their

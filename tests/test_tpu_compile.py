@@ -32,7 +32,8 @@ from repro.kernels.lns_matmul.grouped import (lns_gmm_dw_pallas,
 from repro.kernels.lns_matmul.update import lns_fused_update_pallas
 
 #: Every Δ kind the compiled lane takes.  lut640 unrolls ~400 breakpoints
-#: of compare-select per ⊞ (lut20: 20), the largest kernel body there is.
+#: of compare-select per ⊞, the largest kernel body there is; lut20 reads
+#: its table with one lane gather.
 SPECS = {"lut20": DELTA_DEFAULT, "lut640": DELTA_SOFTMAX,
          "bitshift": DELTA_BITSHIFT}
 
@@ -149,6 +150,16 @@ EXTENTS = {
 }
 
 
+def _launch_metadata(one_chip, fn, shapes):
+    """The ``kernel_metadata`` of each launch in ``fn``'s compiled HLO."""
+    args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+            for s in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return [json.loads(m) for m in re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*'
+        r"kernel_metadata=(\{[^{}]*\})", text)]
+
+
 @pytest.mark.parametrize("kind", KINDS)
 def test_kernel_launch_carries_its_kind_and_extents(one_chip, kind):
     """The compiled custom call names its kind (and, for a ⊞-MAC, its
@@ -156,17 +167,22 @@ def test_kernel_launch_carries_its_kind_and_extents(one_chip, kind):
     launch's event name.  (The get-tuple-elements that unpack its
     outputs carry a copy; they run nothing on the device.)"""
     fn, shapes = _kernel_case(kind, SPECS["lut20"], *SHAPES["mlp"])
-    args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
-            for s in shapes]
-    text = jax.jit(fn).lower(*args).compile().as_text()
-    found = [json.loads(m) for m in re.findall(
-        r'custom_call_target="tpu_custom_call"[^\n]*'
-        r"kernel_metadata=(\{[^{}]*\})", text)]
-    want = {"kind": kind}
+    want = {"kind": kind, "delta": "lut-gather"}
     if kind in EXTENTS:
         want.update(zip(("r", "c", "ct", "rp", "cp", "ctp"),
                         map(str, EXTENTS[kind])))
-    assert found == [want]
+    assert _launch_metadata(one_chip, fn, shapes) == [want]
+
+
+@pytest.mark.parametrize("spec,path", [("lut20", "lut-gather"),
+                                       ("lut640", "lut-chain"),
+                                       ("bitshift", "bitshift")])
+def test_kernel_launch_names_its_delta_path(one_chip, spec, path):
+    """A compiled launch names the way it evaluates Δ±: lut20's table
+    fits one lane gather, lut640's keeps the compare-select chain."""
+    fn, shapes = _kernel_case("fwd", SPECS[spec], *SHAPES["mlp"])
+    assert [md["delta"] for md in _launch_metadata(one_chip, fn,
+                                                   shapes)] == [path]
 
 
 def test_paper_mlp_donating_step_aliases_its_params(one_chip):
@@ -214,14 +230,8 @@ def test_grouped_kernel_compiles_for_v5e(one_chip, kind):
     """The grouped ⊞-MAC launches at the cell's shapes compile for the
     chip, and name their kind, group count and extents."""
     fn, (a, b), ext = GMM_CASES[kind]
-    args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
-            for s in (a, a, b, b, (GMM_G,))]
-    text = jax.jit(lambda ac, as_, bc, bs, sizes: fn(
-        ac, as_, bc, bs, sizes, fmt=LNS16, spec=DELTA_DEFAULT,
-        interpret=False)).lower(*args).compile().as_text()
-    found = [json.loads(m) for m in re.findall(
-        r'custom_call_target="tpu_custom_call"[^\n]*'
-        r"kernel_metadata=(\{[^{}]*\})", text)]
-    want = {"kind": kind, "g": str(GMM_G)}
+    want = {"kind": kind, "delta": "lut-gather", "g": str(GMM_G)}
     want.update(zip(("r", "c", "ct", "rp", "cp", "ctp"), map(str, ext)))
-    assert found == [want]
+    assert _launch_metadata(one_chip, lambda ac, as_, bc, bs, sizes: fn(
+        ac, as_, bc, bs, sizes, fmt=LNS16, spec=DELTA_DEFAULT,
+        interpret=False), (a, a, b, b, (GMM_G,))) == [want]
