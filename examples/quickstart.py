@@ -60,11 +60,11 @@ for be_name in ("emulate", "pallas"):
     print(f"backward dX on {be_name:7s}: first code = {int(dx.code[0, 0])}")
 
 # jax.grad flows through the same path via the custom_vjp boundary — the
-# kernels package accepts the spec directly:
+# kernels take the backend the runtime resolved:
 import jax
-g = jax.grad(lambda a: lns_matmul_trainable(
-    a, B, numerics="lns16-train-pallas,delta=lut640", block_m=8,
-    block_n=8, block_k=16).sum())(A)
+rt = NumericsSpec.parse("lns16-train-pallas,delta=lut640").runtime(
+    block_m=8, block_n=8, block_k=16)
+g = jax.grad(lambda a: lns_matmul_trainable(a, B, rt.matmul).sum())(A)
 print(f"jax.grad through the Pallas ⊞-MAC: gA.shape = {g.shape}")
 
 print("\n=== 4. End-to-end log-domain training (paper Sec. 4-5) ===")
@@ -224,8 +224,9 @@ print(f"numerics (fused-infer dispatch): {engine.matmul_path}")
 
 print("\n=== 8. Watching your numerics: the obs telemetry subsystem ===")
 # Telemetry is observer-only by contract: counters are pure reads of op
-# inputs/outputs, collected as extra int32 outputs of a SEPARATE jitted
-# entry point (train_step_metrics).  The plain train_step never pushes a
+# inputs/outputs, collected as extra int32 outputs of the model's second
+# step entry point, train_step_metrics (which never donates, and arms a
+# FaultPlan keyed by step= — §10).  The plain train_step never pushes a
 # collector, so its graph is byte-for-byte the uninstrumented one, and
 # metrics-on weight codes are bit-identical to metrics-off (pinned in
 # tests/test_obs.py).  Per-layer opt-in via the plan's `metrics` axis:

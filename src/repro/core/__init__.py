@@ -31,15 +31,14 @@ from .formats import (FORMATS, FXP12, FXP16, LNS12, LNS16, LNS21,
                       FixedPointFormat, LNSFormat, required_log_width)
 from .initializers import (encode_init, he_sigma, log_density_normal,
                            log_normal_init)
-from .lns import (MATMUL_BACKENDS, LNSArray, LNSMatmulBackend,
-                  convert_format, decode, encode, from_parts,
-                  quantization_bound, scalar, zeros)
+from .lns import (BLOCK_MODES, MATMUL_BACKENDS, LNSArray, LNSMatmulBackend,
+                  convert_format, decode, encode, from_parts, parse_blocks,
+                  quantization_bound, resolve_blocks_arg, scalar, zeros)
 from .numerics import POLICIES, NumericsPolicy, get_plan, get_policy
 from .plan import NumericsPlan, PlanRule, plan_diff
 from .qat import lns_dot_dispatch, lns_dot_exact, lns_quantize_ste
-from .spec import (ALIASES, BLOCK_MODES, INTERPRET_MODES, REDUCE_MODES,
-                   REDUCE_SCHEDULES, LNSRuntime, NumericsSpec, ReduceSpec,
-                   parse_blocks, resolve_blocks_arg)
+from .spec import (ALIASES, INTERPRET_MODES, REDUCE_MODES, REDUCE_SCHEDULES,
+                   LNSRuntime, NumericsSpec, ReduceSpec)
 from .sgd import (LogSGDConfig, UpdateEpilogue, apply_update,
                   apply_update_codes, init_momentum)
 from .softmax import ce_grad_init, ce_loss_readout, log_softmax_lns

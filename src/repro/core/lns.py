@@ -189,6 +189,46 @@ def _cached_engine(spec, fmt: LNSFormat):
     return _ENGINE_CACHE[key]
 
 
+#: The ``blocks`` axis: "default" (caller-/runtime-chosen tile sizes),
+#: "auto" (per-(spec, op, shape) autotuner — kernels/autotune.py), or an
+#: explicit "MxNxK" (block_m × block_n × block_k).
+BLOCK_MODES = ("default", "auto", "<M>x<N>x<K>")
+
+
+def parse_blocks(text: str):
+    """Decode an explicit ``MxNxK`` blocks value → (block_m, block_n,
+    block_k); raises with the valid forms for anything else."""
+    parts = text.split("x")
+    if len(parts) == 3:
+        try:
+            bm, bn, bk = (int(p) for p in parts)
+            if bm > 0 and bn > 0 and bk > 0:
+                return bm, bn, bk
+        except ValueError:
+            pass
+    raise ValueError(f"invalid blocks={text!r}; valid values: "
+                     f"{', '.join(BLOCK_MODES)}")
+
+
+def resolve_blocks_arg(blocks: str, block_m: int, block_n: int,
+                       block_k: int):
+    """Fold a spec's ``blocks`` axis onto caller-supplied tile sizes.
+
+    Returns ``(block_m, block_n, block_k, mode)`` where ``mode`` is what
+    :class:`LNSMatmulBackend` stores: ``"auto"`` defers to the autotuner
+    per op+shape at launch; an explicit ``MxNxK`` overrides the caller's
+    sizes and ``"default"`` keeps them.  The one decode point shared by
+    ``LNSRuntime``, the ⊞-reduce kernel's entry point and the
+    data-parallel reduce.
+    """
+    if blocks == "auto":
+        return block_m, block_n, block_k, "auto"
+    if blocks != "default":
+        bm, bn, bk = parse_blocks(blocks)
+        return bm, bn, bk, "default"
+    return block_m, block_n, block_k, "default"
+
+
 @dataclasses.dataclass(frozen=True)
 class LNSMatmulBackend:
     """Config-selected implementation of the ⊞-MAC matmul + its backward.
@@ -240,7 +280,7 @@ class LNSMatmulBackend:
             raise ValueError(
                 f"unknown blocks mode {self.blocks!r}; expected 'default' "
                 f"or 'auto' (explicit MxNxK strings are resolved by "
-                f"core.spec.resolve_blocks_arg before construction)")
+                f"resolve_blocks_arg before construction)")
 
     def _interp(self) -> bool:
         return resolve_interpret(self.interpret)

@@ -40,7 +40,8 @@ import jax.numpy as jnp
 from .delta import (DELTA_BITSHIFT, DELTA_DEFAULT, DELTA_EXACT, DELTA_SOFTMAX,
                     DeltaSpec)
 from .formats import FORMATS, LNS12, LNS16, LNSFormat
-from .lns import MATMUL_BACKENDS, LNSMatmulBackend, _cached_engine
+from .lns import (MATMUL_BACKENDS, LNSMatmulBackend, _cached_engine,
+                  parse_blocks, resolve_blocks_arg)
 
 #: Valid values of every enum-ish axis (single source of truth; the
 #: distributed package imports REDUCE_MODES from here).
@@ -57,43 +58,6 @@ INTERPRET_MODES = ("auto", "on", "off")
 METRICS_MODES = ("off", "counters", "full")
 QUANTIZE_AXES = ("params", "acts", "grads")
 COMPUTE_DTYPES = ("float32", "bfloat16", "float16")
-#: The ``blocks`` axis: "default" (caller-/runtime-chosen tile sizes),
-#: "auto" (per-(spec, op, shape) autotuner — kernels/autotune.py), or an
-#: explicit "MxNxK" (block_m × block_n × block_k).
-BLOCK_MODES = ("default", "auto", "<M>x<N>x<K>")
-
-
-def parse_blocks(text: str):
-    """Decode an explicit ``MxNxK`` blocks value → (block_m, block_n,
-    block_k); raises with the valid forms for anything else."""
-    parts = text.split("x")
-    if len(parts) == 3:
-        try:
-            bm, bn, bk = (int(p) for p in parts)
-            if bm > 0 and bn > 0 and bk > 0:
-                return bm, bn, bk
-        except ValueError:
-            pass
-    raise _bad_value("blocks", text, BLOCK_MODES)
-
-
-def resolve_blocks_arg(blocks: str, block_m: int, block_n: int,
-                       block_k: int):
-    """Fold a spec's ``blocks`` axis onto caller-supplied tile sizes.
-
-    Returns ``(block_m, block_n, block_k, mode)`` where ``mode`` is what
-    the :class:`~repro.core.lns.LNSMatmulBackend` stores: ``"auto"``
-    defers to the autotuner per op+shape at launch; an explicit ``MxNxK``
-    overrides the caller's sizes and ``"default"`` keeps them.  The one
-    decode point shared by ``LNSRuntime`` and the kernels' entry points.
-    """
-    if blocks == "auto":
-        return block_m, block_n, block_k, "auto"
-    if blocks != "default":
-        bm, bn, bk = parse_blocks(blocks)
-        return bm, bn, bk, "default"
-    return block_m, block_n, block_k, "default"
-
 #: Named Δ specs (the serializable vocabulary; arbitrary LUTs round-trip
 #: through the generic ``lut:<d_max>:<r>`` form).
 DELTA_NAMES = {
@@ -495,40 +459,6 @@ def _alias_reverse() -> dict:
     return {spec: name for name, spec in ALIASES.items()}
 
 
-def resolve_kernel_args(numerics, *, fmt=None, spec=None, backend=None,
-                        interpret=None, blocks=None, op: str = "kernel",
-                        layer: "str | None" = None):
-    """Fill a kernel entry point's config pieces from a NumericsSpec.
-
-    Shared by both kernels packages' dispatch (``lns_matmul_trainable``,
-    ``lns_boxsum_kernel``): explicit arguments win over the spec; missing
-    fmt/Δ raise naming ``op``.  Returns ``(fmt, spec, backend, interpret,
-    blocks)`` — callers that have no backend/blocks axis ignore those
-    slots (``blocks`` is the spec's tiling axis string: "default",
-    "auto", or explicit "MxNxK"; see :func:`resolve_blocks_arg`).
-
-    ``numerics`` may also be a :class:`~repro.core.plan.NumericsPlan` (or
-    plan string with per-layer rules); ``layer`` selects which layer
-    path's resolved spec configures this kernel call (default: the plan's
-    default spec).
-    """
-    if numerics is not None:
-        from .plan import NumericsPlan  # local: plan.py imports this module
-        pl = NumericsPlan.parse(numerics)
-        ns = pl.resolve(layer) if layer is not None else pl.default
-        fmt = fmt if fmt is not None else ns.fmt
-        spec = spec if spec is not None else ns.delta_spec
-        backend = backend if backend is not None else ns.backend
-        interpret = interpret if interpret is not None else ns.interpret_flag
-        blocks = blocks if blocks is not None else ns.blocks
-    if fmt is None or spec is None:
-        raise ValueError(
-            f"{op} needs fmt + spec (pass them explicitly or via "
-            f"numerics=<NumericsSpec/spec string> with fmt and delta set)")
-    return fmt, spec, backend, interpret, \
-        (blocks if blocks is not None else "default")
-
-
 # ------------------------------------------------------------------------
 # LNSRuntime — the spec resolved once
 # ------------------------------------------------------------------------
@@ -645,9 +575,7 @@ class LNSRuntime:
                     # lazy import keeps core importable without the
                     # kernels package.
                     from ..kernels.lns_matmul import lns_matmul_trainable
-                    out = lns_matmul_trainable(
-                        x, w, numerics=s, block_m=self.block_m,
-                        block_n=self.block_n, block_k=self.block_k)
+                    out = lns_matmul_trainable(x, w, self.matmul)
                 elif s.backend != "emulate":
                     # Forward-only on the dispatcher (Pallas kernels off
                     # the emulation): the batched-serving path.
@@ -674,9 +602,7 @@ class LNSRuntime:
         s = self.spec
         if s.delta_spec is not None and s.quantize_grads:
             from ..kernels.lns_matmul import lns_gmm_trainable
-            return lns_gmm_trainable(
-                x, w, sizes, numerics=s, block_m=self.block_m,
-                block_n=self.block_n, block_k=self.block_k)
+            return lns_gmm_trainable(x, w, sizes, self.matmul)
         return jax.lax.ragged_dot(self.q_act(x), self.q_param(w),
                                   sizes.astype(jnp.int32))
 

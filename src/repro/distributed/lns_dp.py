@@ -42,6 +42,7 @@ from ..core.plan import NumericsPlan
 from ..core.spec import ReduceSpec
 from ..obs import metrics as _obs
 from ..obs.trace import host_span, phase_scope
+from ..paper.mlp import LNSMLP, MLPConfig, PARAM_LAYER
 from ..resil import inject as _inj
 from .lns_reduce import (combine_partials, deterministic_boxplus_allreduce,
                          float_psum_allreduce)
@@ -169,7 +170,6 @@ class LNSDataParallelMLP:
     """
 
     def __init__(self, cfg, dp: DPConfig):
-        from ..paper.mlp import LNSMLP
         self.cfg = cfg
         self.dp = dp
         self.inner = LNSMLP(cfg)
@@ -204,7 +204,6 @@ class LNSDataParallelMLP:
         # the global slot recovered from lax.axis_index — the outer step
         # tracer must not cross into the per-device trace (the same
         # tracer-leak discipline that suspends obs collection below).
-        from ..paper.mlp import PARAM_LAYER
         fplan = _inj.active_plan()
         params = _inj.inject_param_codes(params,
                                          param_fmts=inner.param_fmts,
@@ -286,36 +285,10 @@ class LNSDataParallelMLP:
         jitted graph unchanged from the pre-obs subsystem."""
         return self._step_impl(params, xb, yb, momentum)
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def train_step_metrics(self, params, xb, yb, momentum=None):
-        """:meth:`train_step` + numerics taps → ``(step_outputs, taps)``.
-
-        Per-leaf combined-gradient health (``dp_grad.*``) plus the update
-        epilogue taps from ``inner.apply_updates``; in-shard_map compute
-        reports nothing (collection is suspended there by construction).
-        Step outputs are bit-identical to :meth:`train_step`.
-        """
-        with _obs.collecting() as col:
-            out = self._step_impl(params, xb, yb, momentum)
-            return out, col.taps()
-
-    @functools.partial(jax.jit, static_argnums=0)
-    def train_step_faults(self, params, xb, yb, step, momentum=None):
-        """DP step with the config's :class:`FaultPlan` armed (traced
-        ``step`` keys the per-step faults; activation faults inside the
-        mapped per-device bodies stay suspended — see ``_step_impl``)."""
-        with _inj.injecting(self.fault_plan, step):
-            return self._step_impl(params, xb, yb, momentum)
-
-    @functools.partial(jax.jit, static_argnums=0)
-    def train_step_faults_metrics(self, params, xb, yb, step,
-                                  momentum=None):
-        """:meth:`train_step_faults` + numerics taps (the guardrail
-        entry point)."""
-        with _inj.injecting(self.fault_plan, step):
-            with _obs.collecting() as col:
-                out = self._step_impl(params, xb, yb, momentum)
-                return out, col.taps()
+    # Per-leaf combined-gradient health (``dp_grad.*``) plus the update
+    # epilogue taps from ``inner.apply_updates``; in-shard_map compute
+    # reports nothing (collection is suspended there by construction).
+    train_step_metrics = LNSMLP.train_step_metrics
 
 
 def reference_train_step(inner, params, xb, yb, *, grad_segments: int,
@@ -370,8 +343,6 @@ def run_device_count_invariance_check(device_counts=(1, 2, 4), *,
     subprocess with ``--xla_force_host_platform_device_count`` otherwise)
     and by ``examples/train_data_parallel.py``.
     """
-    from ..paper.mlp import LNSMLP, MLPConfig
-
     legacy = {k: v for k, v in (("backend", matmul_backend),
                                 ("reduce.mode", reduce_mode),
                                 ("reduce.grad_segments", grad_segments))

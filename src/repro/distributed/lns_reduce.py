@@ -78,7 +78,7 @@ def dp_combine_blocks(n_elements: int, segments: int, eng: DeltaEngine, *,
             "boxsum", (n_elements, 1, segments), fmt=eng.fmt,
             spec=eng.spec, interpret=interpret)
         return bm, bk
-    from ..core.spec import resolve_blocks_arg
+    from ..core.lns import resolve_blocks_arg
     bm, _, bk, _ = resolve_blocks_arg(
         blocks, min(256, n_elements), 1, segments)
     return bm, bk

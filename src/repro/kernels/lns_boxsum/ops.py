@@ -7,7 +7,7 @@ import jax
 
 from ...core.delta import DeltaSpec
 from ...core.formats import LNSFormat
-from ...core.lns import LNSArray, resolve_interpret
+from ...core.lns import LNSArray, resolve_blocks_arg, resolve_interpret
 from .lns_boxsum import lns_boxsum_pallas
 
 
@@ -19,42 +19,30 @@ def _call(codes, signs, fmt, spec, block_m, block_k, interpret):
                              interpret=interpret)
 
 
-def lns_boxsum_kernel(x: LNSArray, *, fmt: LNSFormat | None = None,
-                      spec: DeltaSpec | None = None,
+def lns_boxsum_kernel(x: LNSArray, *, fmt: LNSFormat, spec: DeltaSpec,
                       block_m: int = 128, block_k: int = 128,
-                      interpret: bool | None = None, blocks: str = "default",
-                      numerics=None, layer: str | None = None) -> LNSArray:
+                      interpret: bool | None = None,
+                      blocks: str = "default") -> LNSArray:
     """⊞-reduce an (M, K) LNSArray over axis 1 (the softmax Σ⊞).
 
-    ``fmt`` / ``spec`` / ``interpret`` may instead come from one
-    ``numerics``: a :class:`~repro.core.spec.NumericsSpec` or per-layer
-    :class:`~repro.core.plan.NumericsPlan` (or a parseable spec/plan
-    string) — with a plan, ``layer`` picks which layer path's resolved
-    spec applies (default: the plan's default spec); explicit pieces win.
-    ``interpret`` left unset by both resolves from the platform
+    ``interpret=None`` resolves from the platform
     (``core.lns.resolve_interpret``: compiled on a TPU only).
 
     ``blocks`` is the spec's tiling axis: ``"auto"`` resolves
     (block_m, block_k) through the autotuner cache per shape
     (``kernels/autotune.py``, op ``"boxsum"``); an explicit ``"MxNxK"``
     pins block_m×block_k from its M/K slots; ``"default"`` keeps the
-    keyword tile sizes.  A ``numerics`` spec's own ``blocks`` axis is
-    honored the same way.
+    keyword tile sizes.
     """
-    from ...core.spec import resolve_blocks_arg, resolve_kernel_args
-    fmt, spec, _, interpret, spec_blocks = resolve_kernel_args(
-        numerics, fmt=fmt, spec=spec, interpret=interpret,
-        blocks=(None if blocks == "default" else blocks),
-        op="lns_boxsum_kernel", layer=layer)
     interpret = resolve_interpret(interpret)
-    if spec_blocks == "auto":
+    if blocks == "auto":
         from .. import autotune
         block_m, _, block_k = autotune.lookup(
             "boxsum", (x.shape[0], 1, x.shape[1]), fmt=fmt, spec=spec,
             interpret=interpret)
     else:
         block_m, _, block_k, _ = resolve_blocks_arg(
-            spec_blocks, block_m, 1, block_k)
+            blocks, block_m, 1, block_k)
     code, sign = _call(x.code, x.sign, fmt, spec, block_m, block_k,
                        interpret)
     return LNSArray(code, sign.astype("int8"))

@@ -5,7 +5,8 @@ differentiable ``lns_matmul_trainable`` op.
 the log-domain arithmetic: the primal and both cotangent matmuls run the
 ⊞-MAC path (emulated or Pallas, per :class:`~repro.core.lns.LNSMatmulBackend`),
 so ``jax.grad`` through a model using it trains on the same hardware-shaped
-datapath as the paper's hand backprop.
+datapath as the paper's hand backprop.  The entry points take the backend
+their caller resolved (``LNSRuntime.matmul``) or its pieces, never a spec.
 """
 from __future__ import annotations
 
@@ -233,26 +234,6 @@ def lns_fused_update_kernel(w: LNSArray, g: LNSArray, *,
 # ------------------------------------------------------------------------
 # Differentiable op: LNS forward AND backward under jax.grad
 # ------------------------------------------------------------------------
-def _resolve_numerics(numerics, fmt, spec, backend, interpret, layer=None):
-    """Fill the ⊞-MAC config pieces from a NumericsSpec, explicit args win.
-
-    ``numerics`` may be a spec or a per-layer
-    :class:`~repro.core.plan.NumericsPlan`; ``layer`` selects the layer
-    path to resolve under a plan.  ``backend`` defaults to ``"pallas"``
-    when neither an explicit value nor a spec supplies one (this is the
-    kernels package, after all); ``interpret=None`` keeps the backend's
-    call-time auto-resolution unless the spec pins it on/off.  The fifth
-    return is the spec's ``blocks`` axis ("default"/"auto"/"MxNxK").
-    """
-    from ...core.spec import resolve_kernel_args
-    fmt, spec, backend, interpret, blocks = resolve_kernel_args(
-        numerics, fmt=fmt, spec=spec, backend=backend, interpret=interpret,
-        op="lns_matmul_trainable", layer=layer)
-    return fmt, spec, (backend if backend is not None else "pallas"), \
-        interpret, blocks
-
-
-
 @partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _trainable(x, w, be: LNSMatmulBackend):
     z = be.matmul(encode(x, be.fmt), encode(w, be.fmt))
@@ -279,39 +260,20 @@ def _trainable_bwd(be, res, g):
 _trainable.defvjp(_trainable_fwd, _trainable_bwd)
 
 
-def lns_matmul_trainable(x, w, *, fmt: LNSFormat | None = None,
-                         spec: DeltaSpec | None = None,
-                         backend: str | None = None,
-                         block_m: int = 128, block_n: int = 128,
-                         block_k: int = 128,
-                         interpret: bool | None = None,
-                         numerics=None, layer: str | None = None):
+def lns_matmul_trainable(x, w, be: LNSMatmulBackend):
     """Differentiable float-view matmul on the log-domain MAC path.
 
     ``x``: (..., K) float, ``w``: (K, N) float.  Forward encodes both
-    operands to LNS, runs the ⊞-MAC matmul on the selected backend, and
-    decodes; the VJP encodes the cotangent and runs the *transposed* ⊞-MACs
+    operands to LNS, runs the ⊞-MAC matmul on ``be``, and decodes; the
+    VJP encodes the cotangent and runs the *transposed* ⊞-MACs
     (dX = dY ⊞ Wᵀ, dW = Xᵀ ⊞ dY) on the same path — no float matmul in
     either direction.  Every later scaling PR (sharded training, batched
     serving on the kernel path) composes with this boundary.
 
-    The arithmetic is configured either by the explicit ``fmt`` / ``spec``
-    / ``backend`` / ``interpret`` pieces or, preferably, by one
-    ``numerics``: a :class:`~repro.core.spec.NumericsSpec` or per-layer
-    :class:`~repro.core.plan.NumericsPlan` (or a parseable spec/plan
-    string) supplying all four — with a plan, ``layer`` picks the layer
-    path whose resolved spec applies, e.g.
-    ``lns_matmul_trainable(x, w, numerics=plan, layer="hidden")``;
-    explicit pieces win over the spec.
+    ``be`` is the backend a numerics spec resolves to, e.g.
+    ``plan.runtime_for("hidden").matmul``, or one built from the pieces
+    (``LNSMatmulBackend(fmt=..., spec=..., backend="pallas")``).
     """
-    fmt, spec, backend, interpret, blocks = _resolve_numerics(
-        numerics, fmt, spec, backend, interpret, layer)
-    from ...core.spec import resolve_blocks_arg
-    block_m, block_n, block_k, blocks_mode = resolve_blocks_arg(
-        blocks, block_m, block_n, block_k)
-    be = LNSMatmulBackend(fmt=fmt, spec=spec, backend=backend,
-                          block_m=block_m, block_n=block_n, block_k=block_k,
-                          blocks=blocks_mode, interpret=interpret)
     lead = x.shape[:-1]
     x2 = x.reshape((-1, x.shape[-1]))
     z = _trainable(x2, w, be)
@@ -370,24 +332,14 @@ def _grouped_bwd(be, res, g):
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def lns_gmm_trainable(x, w, sizes, *, fmt: LNSFormat | None = None,
-                      spec: DeltaSpec | None = None,
-                      backend: str | None = None, block_m: int = 128,
-                      block_n: int = 128, block_k: int = 128,
-                      interpret: bool | None = None, numerics=None,
-                      layer: str | None = None):
+def lns_gmm_trainable(x, w, sizes, be: LNSMatmulBackend):
     """Differentiable grouped ⊞-MAC: ``x`` (M, K) float rows sorted by
     group, ``w`` (G, K, N) float, ``sizes`` (G,) int32 rows per group.
 
     Row ``r`` of group ``g`` gets ``x[r] ⊞-MAC w[g]``; rows past
     ``sum(sizes)`` get 0.  As :func:`lns_matmul_trainable`, the forward
     and both cotangent products (dX per row against ``w[g]ᵀ``, dW per
-    group over its rows in the order given) run the ⊞-MAC path, here the
-    grouped kernels (``grouped.py``); ``block_m`` is the row tile.
+    group over its rows in the order given) run on ``be``, here the
+    grouped kernels (``grouped.py``); ``be.block_m`` is the row tile.
     """
-    fmt, spec, backend, interpret, _ = _resolve_numerics(
-        numerics, fmt, spec, backend, interpret, layer)
-    be = LNSMatmulBackend(fmt=fmt, spec=spec, backend=backend,
-                          block_m=block_m, block_n=block_n, block_k=block_k,
-                          interpret=interpret)
     return _grouped(x, w, sizes.astype("int32"), be)

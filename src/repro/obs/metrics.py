@@ -5,10 +5,10 @@ the *inputs or outputs* of an op with pure reads (comparisons + integer
 sums) — the op's own arithmetic is never touched, so telemetry can never
 change results.  Counters are traced int32 scalars accumulated on a
 trace-time collector stack and returned as an extra output of a
-metrics-enabled jitted entry point (e.g. ``LNSMLP.train_step_metrics``).
-The plain entry points never push a collector, so with collection off the
-jitted graphs are byte-for-byte the ones this module never saw — a true
-no-op, not a disabled branch.
+metrics-enabled jitted entry point (the MLP models' ``train_step_metrics``).
+The plain ``train_step`` never pushes a collector, so with collection off
+the jitted graphs are byte-for-byte the ones this module never saw — a
+true no-op, not a disabled branch.
 
 Tap sites are **scope-gated**: instrumented core ops (``encode`` /
 ``convert_format`` / the fused-epilogue dispatch) only record when an

@@ -405,11 +405,6 @@ class LNSMLP:
         self.param_engines = {k: self.engs[l]
                               for k, l in PARAM_LAYER.items()}
         self.param_fmts = {k: self.fmts[l] for k, l in PARAM_LAYER.items()}
-        # Legacy single-runtime aliases (input-side/hidden layer).
-        self.fmt = self.fmts["hidden"]
-        self.eng = self.engs["hidden"]
-        self.runtime = self.runtimes["hidden"]
-        self.mm = self.runtime.matmul
         # Telemetry eligibility per layer (the plan's `metrics` axis); the
         # master switch is which entry point runs (train_step vs
         # train_step_metrics) — see repro.obs.metrics.
@@ -688,21 +683,25 @@ class LNSMLP:
         statically false: the jitted graph has no extra outputs and is
         the same graph as before the obs subsystem existed.
 
-        Passing back the params (and momentum) this model returned on its
-        last call reuses their buffers: the step donates them, and the
-        arrays passed are deleted.  Copy them first to keep them.  Any
-        other params (the ``init`` ones, a copy, a dict with one leaf
-        swapped) are kept, and the step allocates new outputs.  Both
-        graphs trace :meth:`_step_impl`, so the results are the same bit
-        for bit.  The call is one host span ``repro.train_step`` with the
-        argument ``donated`` 0 or 1.
+        One program runs, and it donates the params (and momentum) it is
+        given.  Passing back the ones this model returned on its last
+        call reuses their buffers, and the arrays passed are deleted:
+        copy them first to keep them.  Any other params (the ``init``
+        ones, a copy, a dict with one leaf swapped) are kept: the step
+        copies them on the device and donates the copy.  The call is one
+        host span ``repro.train_step`` with the argument ``donated`` 0
+        or 1.
         """
         with jax.profiler.TraceAnnotation("repro.train_step") as span:
             donate = self._handed_back(params, momentum)
             span.set_metadata(donated=int(donate))
-            step = self._train_step_donate if donate \
-                else self._train_step_keep
-            out = step(params, xb, yb, momentum)
+            if not donate:
+                # A real copy, made by the runtime with no program to
+                # compile: a jitted identity may return the caller's own
+                # buffer, which the donation would delete.
+                params, momentum = jax.device_put((params, momentum),
+                                                  may_alias=False)
+            out = self._train_step(params, xb, yb, momentum)
             self._returned = tuple(
                 weakref.ref(a) for a in jax.tree_util.tree_leaves(out[:-1]))
         self.calls += 1
@@ -718,46 +717,35 @@ class LNSMLP:
             r() is a and not a.is_deleted()
             for r, a in zip(returned, leaves))
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def _train_step_keep(self, params, xb, yb, momentum=None):
-        return self._step_impl(params, xb, yb, momentum)
-
     @functools.partial(jax.jit, static_argnums=0,
                        donate_argnames=("params", "momentum"))
-    def _train_step_donate(self, params, xb, yb, momentum=None):
+    def _train_step(self, params, xb, yb, momentum=None):
         return self._step_impl(params, xb, yb, momentum)
 
     @functools.partial(jax.jit, static_argnums=0)
-    def train_step_metrics(self, params, xb, yb, momentum=None):
+    def train_step_metrics(self, params, xb, yb, momentum=None, *,
+                           step=None):
         """:meth:`train_step` with numerics telemetry: returns
         ``(step_outputs, taps)`` where ``step_outputs`` is exactly what
         ``train_step`` returns — bit-identical codes, the counters are
         pure reads — and ``taps`` maps ``"layer/op/counter"`` to int32
         counts (feed to ``MetricsRegistry.merge_numerics_taps`` with
         :meth:`lanes`).  Layers whose spec says ``metrics=off`` stay
-        silent; ``metrics=full`` adds the Δ-LUT ``dhist`` shadow pass."""
-        with _obs.collecting() as col:
-            out = self._step_impl(params, xb, yb, momentum)
-            return out, col.taps()
+        silent; ``metrics=full`` adds the Δ-LUT ``dhist`` shadow pass.
+        Never donates: the caller's arrays survive the call.
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def train_step_faults(self, params, xb, yb, step, momentum=None):
-        """:meth:`train_step` with the config's :class:`FaultPlan` armed.
-
-        ``step`` is a traced int32: per-step fault keying (and the plan's
-        ``[start, stop)`` window) is data, not trace state, so one jitted
-        graph serves every step.  With ``cfg.faults=None`` this is the
-        plain step plus an unused ``step`` input — same arithmetic graph.
+        A model built with a :class:`~repro.resil.inject.FaultPlan` runs
+        with it armed, keyed by ``step`` (a traced int32: the plan's
+        ``[start, stop)`` window is data, so one graph serves every
+        step), and raises ``ValueError`` without one.  The taps are
+        computed *after* injection, so detectors see what the next layer
+        sees.  Without a plan ``step`` is ignored.  The data-parallel
+        model shares this method.
         """
-        with _inj.injecting(self.fault_plan, step):
-            return self._step_impl(params, xb, yb, momentum)
-
-    @functools.partial(jax.jit, static_argnums=0)
-    def train_step_faults_metrics(self, params, xb, yb, step,
-                                  momentum=None):
-        """:meth:`train_step_faults` + numerics taps — the guardrail
-        entry point: detectors read taps computed *after* injection, so
-        the drills can measure detection latency in steps."""
+        if self.fault_plan is not None and step is None:
+            raise ValueError(
+                "this model carries a FaultPlan: train_step_metrics needs "
+                "step= (a traced int32) to key its faults")
         with _inj.injecting(self.fault_plan, step):
             with _obs.collecting() as col:
                 out = self._step_impl(params, xb, yb, momentum)

@@ -168,10 +168,10 @@ def _inner(model):
 class GuardedTrainer:
     """Host-side training loop wrapper: snapshot → step → detect → act.
 
-    Drives the model's metrics entry point (``train_step_faults_metrics``
-    when the model carries a :class:`~repro.resil.inject.FaultPlan`,
-    ``train_step_metrics`` otherwise), feeds the taps + loss readout to
-    :func:`detect`, and applies the configured recovery:
+    Drives the model's ``train_step_metrics`` (keyed by the step number,
+    which arms the model's :class:`~repro.resil.inject.FaultPlan` when it
+    carries one), feeds the taps + loss readout to :func:`detect`, and
+    applies the configured recovery:
 
     * loss alerts (nonfinite / spike) → **rollback** to the most recent
       snapshot (which is this step's *pre*-state at
@@ -207,13 +207,9 @@ class GuardedTrainer:
         if self.step_no % g.snapshot_every == 0:
             self.ring.push(self.step_no, self.params, self.momentum)
         model = self.model
-        if getattr(model, "fault_plan", None) is not None:
-            out, taps = model.train_step_faults_metrics(
-                self.params, xb, yb, jnp.int32(self.step_no),
-                self.momentum)
-        else:
-            out, taps = model.train_step_metrics(
-                self.params, xb, yb, self.momentum)
+        out, taps = model.train_step_metrics(
+            self.params, xb, yb, self.momentum,
+            step=jnp.int32(self.step_no))
         if self.momentum is None:
             new_params, loss = out
             new_mom = None

@@ -253,18 +253,22 @@ def test_boxsum_kernel_blocks_auto(rng, tuner_dir, monkeypatch):
 
 
 def test_trainable_op_accepts_blocks_spec(rng, tuner_dir, monkeypatch):
-    """lns_matmul_trainable honors the spec's blocks axis end-to-end."""
+    """lns_matmul_trainable honors the spec's blocks axis end-to-end,
+    through the backend the spec's runtime resolves."""
     monkeypatch.setenv("LNS_AUTOTUNE_DISABLE", "1")
     import jax
     from repro.kernels.lns_matmul import lns_matmul_trainable
+
+    def be(text):
+        return NumericsSpec.parse(text).runtime().matmul
+
     X = rng.normal(size=(6, 12)).astype(np.float32)
     W = rng.normal(size=(12, 4)).astype(np.float32)
-    za = lns_matmul_trainable(
-        X, W, numerics="lns16-train-pallas,blocks=auto")
-    zd = lns_matmul_trainable(X, W, numerics="lns16-train-pallas")
+    za = lns_matmul_trainable(X, W, be("lns16-train-pallas,blocks=auto"))
+    zd = lns_matmul_trainable(X, W, be("lns16-train-pallas"))
     np.testing.assert_array_equal(np.asarray(za), np.asarray(zd))
     g = jax.grad(lambda x, w: lns_matmul_trainable(
-        x, w, numerics="lns16-train-pallas,blocks=16x8x32").sum())(X, W)
+        x, w, be("lns16-train-pallas,blocks=16x8x32")).sum())(X, W)
     assert np.isfinite(np.asarray(g)).all()
 
 

@@ -118,9 +118,9 @@ def test_trainable_op_grads_track_float(rng):
     W = rng.normal(size=(12, 4)).astype(np.float32)
 
     def loss(x, w):
-        return lns_matmul_trainable(x, w, fmt=LNS16, spec=DELTA_SOFTMAX,
-                                    backend="pallas", block_m=8, block_n=8,
-                                    block_k=8).sum()
+        return lns_matmul_trainable(x, w, LNSMatmulBackend(
+            fmt=LNS16, spec=DELTA_SOFTMAX, backend="pallas", block_m=8,
+            block_n=8, block_k=8)).sum()
 
     gx, gw = jax.grad(loss, argnums=(0, 1))(X, W)
     ones = np.ones((6, 4), np.float32)

@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, reduced
-from repro.core import LNS16, encode
+from repro.core import LNS16, LNSMatmulBackend, encode
 from repro.core.plan import NumericsPlan
 from repro.core.spec import DELTA_NAMES
 from repro.kernels.lns_matmul import lns_gmm_trainable
@@ -98,9 +98,9 @@ def test_grouped_trainable_kernels_equal_emulation():
     sizes = jnp.asarray(SIZES, jnp.int32)
 
     def run(backend):
-        f = lambda x, w: lns_gmm_trainable(
-            x, w, sizes, fmt=LNS16, spec=LUT20, backend=backend,
-            block_m=8, block_n=8, block_k=8)
+        be = LNSMatmulBackend(fmt=LNS16, spec=LUT20, backend=backend,
+                              block_m=8, block_n=8, block_k=8)
+        f = lambda x, w: lns_gmm_trainable(x, w, sizes, be)
         y, vjp = jax.vjp(f, x, w)
         return (y,) + vjp(g)
 

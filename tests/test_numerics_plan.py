@@ -229,18 +229,18 @@ def test_mlp_momentum_threads_state(rng):
 
 # ------------------------------------------------------------ layer 4 ---
 def test_kernels_accept_plan_and_layer(rng):
+    """A layer's runtime under a plan runs its own spec's arithmetic."""
     from repro.kernels.lns_matmul import lns_matmul_trainable
     X = rng.normal(size=(4, 10)).astype(np.float32)
     W = rng.normal(size=(10, 3)).astype(np.float32)
-    z_plan = lns_matmul_trainable(X, W, numerics=MIXED, layer="hidden",
-                                  block_m=8, block_n=8, block_k=8)
-    z_12 = lns_matmul_trainable(X, W, numerics="lns16-train-pallas,"
-                                "fmt=lns12", block_m=8, block_n=8,
-                                block_k=8)
+    plan = NumericsPlan.parse(MIXED)
+    z_plan = lns_matmul_trainable(
+        X, W, plan.runtime_for("hidden", 8, 8, 8).matmul)
+    z_12 = lns_matmul_trainable(X, W, NumericsSpec.parse(
+        "lns16-train-pallas,fmt=lns12").runtime(8, 8, 8).matmul)
     np.testing.assert_array_equal(np.asarray(z_plan), np.asarray(z_12))
     # default layer = the plan's default spec (lns16 here) → differs
-    z_def = lns_matmul_trainable(X, W, numerics=MIXED, block_m=8,
-                                 block_n=8, block_k=8)
+    z_def = lns_matmul_trainable(X, W, plan.runtime(8, 8, 8).matmul)
     assert not np.array_equal(np.asarray(z_plan), np.asarray(z_def))
 
 

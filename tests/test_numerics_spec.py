@@ -166,26 +166,31 @@ def test_dp_plan_derives_from_spec():
 
 
 def test_kernels_accept_numerics_spec(rng):
+    """The kernels run what a spec resolves to: the runtime's backend is
+    the one built from the pieces, and gives the same results."""
+    from repro.core import LNSMatmulBackend, encode
     from repro.kernels.lns_boxsum import lns_boxsum_kernel
     from repro.kernels.lns_matmul import lns_matmul_trainable
-    from repro.core import encode
     X = rng.normal(size=(4, 10)).astype(np.float32)
     W = rng.normal(size=(10, 3)).astype(np.float32)
-    z_spec = lns_matmul_trainable(X, W, numerics="lns16-train-pallas",
-                                  block_m=8, block_n=8, block_k=8)
-    z_expl = lns_matmul_trainable(X, W, fmt=LNS16, spec=DELTA_DEFAULT,
-                                  backend="pallas", block_m=8, block_n=8,
-                                  block_k=8)
+    be = NumericsSpec.parse("lns16-train-pallas").runtime(8, 8, 8).matmul
+    be_expl = LNSMatmulBackend(fmt=LNS16, spec=DELTA_DEFAULT,
+                               backend="pallas", block_m=8, block_n=8,
+                               block_k=8)
+    assert be == be_expl
+    z_spec = lns_matmul_trainable(X, W, be)
+    z_expl = lns_matmul_trainable(X, W, be_expl)
     np.testing.assert_array_equal(np.asarray(z_spec), np.asarray(z_expl))
     x = encode(rng.normal(size=(6, 5)).astype(np.float32), LNS16)
-    b_spec = lns_boxsum_kernel(x, numerics="lns16-exact", block_m=8,
-                               block_k=5)
+    ns = NumericsSpec.parse("lns16-exact")
+    b_spec = lns_boxsum_kernel(x, fmt=ns.fmt, spec=ns.delta_spec,
+                               block_m=8, block_k=5)
     b_expl = lns_boxsum_kernel(x, fmt=LNS16, spec=DELTA_DEFAULT, block_m=8,
                                block_k=5)
     np.testing.assert_array_equal(np.asarray(b_spec.code),
                                   np.asarray(b_expl.code))
     with pytest.raises(ValueError, match="fmt"):
-        lns_matmul_trainable(X, W, numerics="bf16")
+        NumericsSpec.parse("bf16").runtime().matmul
 
 
 # ------------------------------------------------------------ layer 3 ---

@@ -68,8 +68,8 @@ def _train_faults(m, steps=3, seed=0):
     params = m.init(jax.random.PRNGKey(1))
     mom = m.init_momentum(params)
     for i, (xb, yb) in enumerate(_batches(steps, seed)):
-        params, mom, _ = m.train_step_faults(params, xb, yb,
-                                             jnp.int32(i), mom)
+        (params, mom, _), _ = m.train_step_metrics(params, xb, yb, mom,
+                                                   step=jnp.int32(i))
     return params, mom
 
 
@@ -153,8 +153,8 @@ def test_noop_graph_identical(backend):
 
 @pytest.mark.parametrize("backend", ["emulate", "pallas"])
 def test_train_parity_no_plan(backend):
-    """cfg.faults=None: the faults entry point trains bit-identically to
-    the plain step (the extra step arg is unused)."""
+    """cfg.faults=None: the metrics step keyed by ``step`` trains
+    bit-identically to the plain step (the step arg is unused)."""
     spec = f"lns16-train-{backend};hidden=fmt:lns12"
     p0, m0 = _train_plain(LNSMLP(_mlp_cfg(spec)))
     p1, m1 = _train_faults(LNSMLP(_mlp_cfg(spec)))
@@ -215,10 +215,23 @@ def test_window_gates_injection():
     mom = clean.init_momentum(params)
     xb, yb = _batches(1)[0]
     pc, _, _ = clean.train_step(params, xb, yb, mom)
-    p0, _, _ = faulted.train_step_faults(params, xb, yb, jnp.int32(0), mom)
-    p1, _, _ = faulted.train_step_faults(params, xb, yb, jnp.int32(1), mom)
+    (p0, _, _), _ = faulted.train_step_metrics(params, xb, yb, mom,
+                                               step=jnp.int32(0))
+    (p1, _, _), _ = faulted.train_step_metrics(params, xb, yb, mom,
+                                               step=jnp.int32(1))
     _assert_codes_equal(p0, pc)  # step 0 < start: untouched
     assert any(not np.array_equal(p1[k].code, pc[k].code) for k in pc)
+
+
+def test_fault_plan_without_step_raises():
+    """A model that carries a FaultPlan cannot run its metrics step
+    unkeyed: the faults would have no step to fire on."""
+    m = LNSMLP(_mlp_cfg("lns16-train-emulate",
+                        "seed=5,start=1;hidden=flip_w:0.3"))
+    params = m.init(jax.random.PRNGKey(1))
+    xb, yb = _batches(1)[0]
+    with pytest.raises(ValueError, match="step="):
+        m.train_step_metrics(params, xb, yb, m.init_momentum(params))
 
 
 def test_sat_lanes_pin_to_code_max():

@@ -10,8 +10,11 @@ CPU (where JAX honours donation):
   ``train_step_metrics`` (which never donates).
 * **What is donated** — the arrays handed back are deleted; the ``init``
   arrays, state passed again, and a state with one leaf swapped are not.
+* **One program** — the step compiles once, whether or not the state
+  was handed back: what is kept is copied on the device and the copy
+  donated.
 * **The span** — each call is one ``repro.train_step`` host span whose
-  ``donated`` argument says which graph ran.
+  ``donated`` argument says whether the caller's arrays were donated.
 """
 import glob
 import os
@@ -70,6 +73,7 @@ def test_handed_back_state_is_donated_and_trains_the_same(backend,
         p, m = state if m0 is not None else (state[0], None)
         want.append(_host((p, m, loss)))
 
+    compiled = LNSMLP._train_step._cache_size()
     p, m = p0, m0
     for i, (xb, yb) in enumerate(batches):
         handed = _leaves(p, m)
@@ -79,6 +83,7 @@ def test_handed_back_state_is_donated_and_trains_the_same(backend,
         assert all(a.is_deleted() == (i > 0) for a in handed), i
     assert (mlp.calls, mlp.donated_calls) == (4, 3)
     assert not any(a.is_deleted() for a in _leaves(p0, m0))
+    assert LNSMLP._train_step._cache_size() == compiled + 1
 
 
 def test_state_passed_again_is_kept():
@@ -106,7 +111,7 @@ def test_state_with_a_leaf_swapped_is_kept(where):
     p, m, _ = mlp.train_step(p, xb, yb, m)
     tree = p if where == "params" else m
     tree["b2"] = LNSArray(jnp.copy(tree["b2"].code), tree["b2"].sign)
-    want = _host(mlp._train_step_keep(p, xb2, yb2, m))
+    want = _host(mlp.train_step_metrics(p, xb2, yb2, m)[0])
     handed = _leaves(p, m)
     got = _host(mlp.train_step(p, xb2, yb2, m))
     assert mlp.donated_calls == 0
@@ -119,7 +124,7 @@ def test_train_step_span_says_whether_it_donated(tmp_path):
     mlp = _mlp()
     batches = _batches(3)
     p = mlp.init(jax.random.PRNGKey(0))
-    for xb, yb in batches[:2]:  # both graphs compile outside the trace
+    for xb, yb in batches[:2]:  # the step compiles outside the trace
         p, _ = mlp.train_step(p, xb, yb)
     p = mlp.init(jax.random.PRNGKey(0))
     opts = jax.profiler.ProfileOptions()

@@ -186,10 +186,10 @@ def test_kernel_launch_names_its_delta_path(one_chip, spec, path):
 
 
 def test_paper_mlp_donating_step_aliases_its_params(one_chip):
-    """The paper MLP's donating train step (784-100-10, batch 5) compiles
-    for the chip with each of its 8 parameter planes written in place of
-    the one it was given; without the alias the runtime would allocate
-    new output buffers all the same.  The keeping step aliases none."""
+    """The paper MLP's one train-step program (784-100-10, batch 5)
+    compiles for the chip with each of its 8 parameter planes written in
+    place of the one it was given; without the alias the runtime would
+    allocate new output buffers all the same."""
     from repro.paper.mlp import LNSMLP, MLPConfig
     mlp = LNSMLP(MLPConfig(lr=0.01, weight_decay=0.01,
                            spec="lns16-train-pallas,interpret=off"))
@@ -206,8 +206,7 @@ def test_paper_mlp_donating_step_aliases_its_params(one_chip):
 
     n = len(jax.tree_util.tree_leaves(params))
     assert n == 8
-    assert aliases(LNSMLP._train_step_donate) == {(i, i) for i in range(n)}
-    assert aliases(LNSMLP._train_step_keep) == set()
+    assert aliases(LNSMLP._train_step) == {(i, i) for i in range(n)}
 
 
 #: deepseek-v2-lite's expert layer on one chip: 16 held experts, d_model
